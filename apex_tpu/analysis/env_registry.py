@@ -135,13 +135,7 @@ ENV_REGISTRY: Dict[str, EnvVar] = dict([
     _v("APEX_TPU_TERMINATION_FILE", "apex_tpu.utils.checkpoint",
        "docs/static_analysis.md",
        "AutoResume: scheduler's checkpoint-and-requeue request file"),
-    # ---- probe / harness ---------------------------------------------
-    _v("APEX_TPU_PROBE_TIMEOUT", "apex_tpu.utils.probe",
-       "docs/static_analysis.md",
-       "backend-probe subprocess timeout override (seconds)"),
-    _v("APEX_TPU_PROBE_CACHE_TTL", "apex_tpu.utils.probe",
-       "docs/static_analysis.md",
-       "backend-probe result cache TTL (seconds)"),
+    # ---- test / harness ----------------------------------------------
     _v("APEX_TPU_SKIP_FLAKY_TEST", "apex_tpu.testing.common_utils",
        "docs/static_analysis.md",
        "skip tests marked flaky (reference-parity harness knob)"),
@@ -154,9 +148,6 @@ ENV_REGISTRY: Dict[str, EnvVar] = dict([
     _v("APEX_TPU_DRYRUN_CHILD", "__graft_entry__",
        "docs/static_analysis.md",
        "internal: marks a re-exec'd virtual-CPU dryrun child"),
-    _v("APEX_TPU_DRYRUN_CACHE_DIR", "__graft_entry__",
-       "docs/static_analysis.md",
-       "opt-in persistent XLA compilation cache for the dryrun gate"),
 ])
 
 

@@ -79,6 +79,12 @@ COLLECTIVE_KINDS: Dict[str, str] = {
     "reduce_scatter": "psum_scatter",
 }
 
+# jax 0.9 emits the collectives whose result is typed axis-invariant
+# under their own primitive names; the census files them with their
+# family
+_PRIM_ALIASES = {"psum_invariant": "psum",
+                 "all_gather_invariant": "all_gather"}
+
 # anything serialized: inside an overlap region only ppermute rings may
 # appear (the whole point of the ring decomposition)
 MONOLITHIC_PRIMS = ("psum", "all_gather", "all_to_all", "reduce_scatter",
@@ -147,7 +153,7 @@ def collective_census(jaxpr) -> Dict[str, int]:
     """Count of every collective primitive equation in the trace."""
     out: Dict[str, int] = {}
     for eqn in iter_eqns(jaxpr):
-        name = eqn.primitive.name
+        name = _PRIM_ALIASES.get(eqn.primitive.name, eqn.primitive.name)
         if name in COLLECTIVE_KINDS:
             out[name] = out.get(name, 0) + 1
     return out
@@ -156,27 +162,6 @@ def collective_census(jaxpr) -> Dict[str, int]:
 # ---------------------------------------------------------------------------
 # counter plumbing
 # ---------------------------------------------------------------------------
-
-
-def _compat_shims() -> None:
-    """The tests/conftest.py jax<0.9 shim trio (no-ops on the target
-    toolchain) — the auditor must run standalone from tools/lint.py on
-    pinned containers, outside pytest and the dryrun gate, which carry
-    their own copies."""
-    import functools
-
-    import jax
-
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        jax.shard_map = functools.partial(_shard_map, check_rep=False)
-    if not hasattr(jax, "typeof"):
-        jax.typeof = lambda x: jax.core.get_aval(x)
-    if not hasattr(jax.lax, "axis_size"):
-        jax.lax.axis_size = lambda name: jax.lax.psum(1, name)
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        jax.sharding.get_abstract_mesh = lambda: None
 
 
 def _registry():
@@ -273,14 +258,21 @@ def check_overlap_region(census: Dict[str, int]) -> List[str]:
     return findings
 
 
-def _user_frames(eqn) -> List[str]:
-    try:
-        import jax._src.source_info_util as siu
+# how many of an equation's innermost non-jax frames attribute it: the
+# function that emitted it and its nearest callers.  The whole stack
+# would explain anything (every trace sits under some "loss", and a test
+# runner's "<lambda>" frames read as "lamb").
+_ATTRIBUTION_DEPTH = 4
 
-        return [f"{fr.file_name}:{fr.function_name}"
-                for fr in siu.user_frames(eqn.source_info)]
-    except Exception:
-        return []
+
+def _user_frames(eqn) -> List[str]:
+    import itertools
+
+    import jax._src.source_info_util as siu
+
+    frames = siu.user_frames(eqn.source_info.traceback)
+    return [f"{fr.file_name}:{fr.function_name}"
+            for fr in itertools.islice(frames, _ATTRIBUTION_DEPTH)]
 
 
 def check_upcasts(jaxpr,
@@ -399,7 +391,6 @@ def audit_overlap_trace(fn: Callable, *args) -> AuditReport:
     """Trace ``fn`` — assumed to run entirely inside an overlap region
     — and apply the monolithic-collective census check.  The unit test
     plants a ``lax.psum`` here and asserts the finding."""
-    _compat_shims()
     import jax
 
     from apex_tpu.ops.collective_matmul import overlap_scope
@@ -693,7 +684,6 @@ def _emit_audit_counters(reg, name: str, census: Dict[str, int],
 
 def audit_entry(name: str) -> AuditReport:
     """Build, trace and check one entry point."""
-    _compat_shims()
     import jax
 
     spec = ENTRY_POINTS[name]()

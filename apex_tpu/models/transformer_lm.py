@@ -897,7 +897,7 @@ def transformer_backbone(params: dict, hidden, cfg: TransformerConfig,
     # inside shard_map the per-layer aux inherits the hidden's varying
     # axes (e.g. 'pp' in a pipeline stage) — the scan carry must start
     # with the same type
-    for axis in getattr(jax.typeof(hidden), "vma", ()) or ():
+    for axis in jax.typeof(hidden).vma:
         from apex_tpu.utils.collectives import pvary as _pvary_
 
         aux0 = _pvary_(aux0, axis)

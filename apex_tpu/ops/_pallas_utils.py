@@ -12,17 +12,33 @@ from apex_tpu.utils.registry import on_tpu
 
 LANES = 128
 
-__all__ = ["LANES", "pallas_ok", "pad_rows", "out_struct"]
+__all__ = ["LANES", "pallas_ok", "pad_rows", "out_struct",
+           "param_cotangent"]
 
 
 def out_struct(shape, dtype, like) -> jax.ShapeDtypeStruct:
     """ShapeDtypeStruct for a pallas_call output, propagating the mesh-axis
     variance (vma) of ``like`` — required when the kernel runs inside a
     ``jax.shard_map`` with its default ``check_vma=True``."""
-    vma = getattr(jax.typeof(like), "vma", None)
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def param_cotangent(ct, primal):
+    """A hand-written ``custom_vjp`` backward's cotangent for ``primal``,
+    typed like it: summed over the manual (``shard_map``) axes the
+    cotangent varies on and the primal does not.  A parameter replicated
+    over a data-parallel axis gets the SUM of its shards' contributions
+    — what autodiff derives for an XLA composition (the transpose of the
+    implicit ``pvary``) and a custom backward must do itself, or
+    ``check_vma`` refuses the rule.  No-op outside ``shard_map`` and for
+    ``None``."""
+    if ct is None:
+        return None
+    extra = tuple(sorted(jax.typeof(ct).vma - jax.typeof(primal).vma))
+    return jax.lax.psum(ct, extra) if extra else ct
 
 
 def pallas_ok(op_name: str, last_dim: int, dtype) -> bool:

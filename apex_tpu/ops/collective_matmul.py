@@ -38,8 +38,14 @@ directions are busy and wall-clock latency halves while total hop count
 stays n−1.
 
 All functions run on *local shards inside* ``jax.shard_map`` (or pmap)
-with ``axis_name`` bound.  The ``overlap_*``/``gspmd_*`` helpers wrap them
-in a shard_map island for use from GSPMD-annotated code (the pattern of
+with ``axis_name`` bound.  Their outputs are typed like the monolithic
+collective they decompose under ``shard_map``'s varying-axes checking:
+a ring all-gather (and ``matmul_all_reduce``, which ends in one) returns
+a value every rank holds identically but TYPED varying, exactly like
+``jax.lax.all_gather`` — ``ppermute`` hops cannot prove replication, so
+a caller returning it through a replicated ``out_specs`` reduces it
+first (``pmean`` over identical copies is the identity).  The
+``overlap_*``/``gspmd_*`` helpers wrap them in a shard_map island for use from GSPMD-annotated code (the pattern of
 ``transformer_lm._cp_core_attention``), returning ``None`` whenever the
 ring path does not apply (no mesh, axis absent or size 1, indivisible
 dims) so callers fall back to the monolithic path.
@@ -124,13 +130,6 @@ def overlap_enabled(flag: Optional[bool] = None) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def _axis_size(axis_name) -> int:
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)   # folds to a python int pre-0.9
-
-
 def _note_ring(n: int, msg_nbytes: int) -> None:
     """Trace-time ring accounting: one call, n−1 hops, (n−1)·msg bytes."""
     reg = _telemetry.registry()
@@ -186,7 +185,7 @@ def _ring_visit(x, axis_name, visit):
     traced index; the local shard is visited first, at hop 0).  n−1 hops;
     hop t+1's ppermute depends only on the buffer, not on ``visit``'s
     consumption of it, so transfer t+1 overlaps compute t."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     x = _pvary(x, axis_name)
     visit(my, x)
@@ -257,7 +256,7 @@ def _check_dims(x, w, dim, what):
 
 
 def _agmm_impl(x, w, axis_name, gather_dim, out_dtype):
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     m = x.shape[gather_dim]
     out_shape = (x.shape[:gather_dim] + (n * m,)
                  + x.shape[gather_dim + 1:-1] + (w.shape[1],))
@@ -284,7 +283,7 @@ def _agmm_fwd(x, w, axis_name, gather_dim):
 
 def _agmm_bwd(axis_name, gather_dim, res, g):
     x, w = res
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     m = x.shape[gather_dim]
     # dx = reduce_scatter(g @ w^T) along gather_dim — the dual ring
     dx = _mmrs_impl(g, w.T.astype(g.dtype), axis_name, gather_dim,
@@ -329,7 +328,7 @@ def all_gather_matmul(x: jax.Array, w: jax.Array, axis_name: str, *,
 
 
 def _mmrs_impl(x, w, axis_name, scatter_dim, out_dtype):
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     M = x.shape[scatter_dim]
     if M % n:
         raise ValueError(
@@ -472,7 +471,7 @@ def ring_all_gather(x: jax.Array, axis_name: str, *,
     so no custom VJP is needed.
     """
     m = x.shape[dim]
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     out_shape = x.shape[:dim] + (n * m,) + x.shape[dim + 1:]
     box = [_zeros_like_vma(out_shape, x.dtype, x)]
 
@@ -488,7 +487,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str, *,
                         dim: int = 0) -> jax.Array:
     """``psum_scatter(x, dim, tiled=True)`` decomposed into n−1
     ``ppermute`` hops with a rotating accumulator (sum semantics)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     M = x.shape[dim]
     if M % n:
         raise ValueError(
@@ -510,10 +509,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str, *,
 
 
 def _abstract_mesh():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except AttributeError:   # jax < 0.9
-        return None
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return None
     return mesh

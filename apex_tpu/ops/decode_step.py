@@ -186,13 +186,13 @@ def _fused_kernel(scale, bs, g, rep, d2, quant, has_rope, *refs):
         l_s[:] = jnp.zeros_like(l_s)
         q = q_ref[0].astype(jnp.float32)          # [nh, dh]
         if has_rope:
-            cos = cos_ref[0]                      # [d2] (f32 input)
+            cos = cos_ref[0]                      # [1, d2] (f32 input)
             sin = sin_ref[0]
             t32 = q[:, :d2]
             half = d2 // 2
             rot = jnp.concatenate([-t32[:, half:], t32[:, :half]],
                                   axis=-1)
-            rq = t32 * cos[None, :] + rot * sin[None, :]
+            rq = t32 * cos + rot * sin
             if d2 < dh:
                 rq = jnp.concatenate([rq, q[:, d2:]], axis=-1)
             # the unfused path rounds the roped query to the compute
@@ -240,17 +240,19 @@ def _fused_kernel(scale, bs, g, rep, d2, quant, has_rope, *refs):
     def _finalize():
         l = l_s[:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        ctx = acc[:] / safe_l                     # [nh, dh] f32
         # replay the unfused path's dtype edges (ctx and W both pass
         # through the compute dtype at the historical matmul site)
-        ctx = ctx.astype(o_ref.dtype).astype(jnp.float32)
-        w = w_ref[:].astype(o_ref.dtype).astype(jnp.float32)
-        # per-head [1, dh] @ [dh, h_out] batched over heads, summed —
-        # the flat [1, nh*dh] GEMM without reshaping the accumulator
-        out = jax.lax.dot_general(
-            ctx, w, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)   # [nh, h_out]
-        o_ref[0] = jnp.sum(out, axis=0).astype(o_ref.dtype)
+        acc[:] = (acc[:] / safe_l).astype(o_ref.dtype).astype(jnp.float32)
+        # the flat [1, nh*dh] @ [nh*dh, h_out] GEMM as one 2-D
+        # [1, dh] @ [dh, h_out] dot per head, summed: Mosaic takes no
+        # dot whose lhs lacks a non-contracting dim, and flattening the
+        # [nh, dh] accumulator into one row would cross lane tiles
+        out = jnp.zeros((1, o_ref.shape[-1]), jnp.float32)
+        for h in range(nh):
+            w = w_ref[h].astype(o_ref.dtype).astype(jnp.float32)
+            out = out + jax.lax.dot(
+                acc[h:h + 1, :], w, preferred_element_type=jnp.float32)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _fused_pallas(q, k_pool, v_pool, block_tables, lengths, w_proj,
@@ -279,8 +281,11 @@ def _fused_pallas(q, k_pool, v_pool, block_tables, lengths, w_proj,
     sc_spec = pl.BlockSpec(
         (1, bs, g),
         lambda i, j, tbl_ref, len_ref: (tbl_ref[i, j], 0, 0))
+    # per-sequence rows (rope angles in, projected row out) ride a unit
+    # MIDDLE axis ([b, 1, n]): Mosaic only takes a unit second-last
+    # block dim when it equals the array's
     row_spec = pl.BlockSpec(
-        (1, d2), lambda i, j, tbl_ref, len_ref: (i, 0))
+        (1, 1, d2), lambda i, j, tbl_ref, len_ref: (i, 0, 0))
     in_specs = [
         pl.BlockSpec((1, nh, dh),
                      lambda i, j, tbl_ref, len_ref: (i, 0, 0)),
@@ -288,8 +293,8 @@ def _fused_pallas(q, k_pool, v_pool, block_tables, lengths, w_proj,
     inputs = [q]
     if has_rope:
         in_specs.extend([row_spec, row_spec])
-        inputs.extend([rope_cos.astype(jnp.float32),
-                       rope_sin.astype(jnp.float32)])
+        inputs.extend([rope_cos.astype(jnp.float32)[:, None, :],
+                       rope_sin.astype(jnp.float32)[:, None, :]])
     in_specs.append(kv_spec)
     inputs.append(k_pool)
     if quant:
@@ -310,7 +315,7 @@ def _fused_pallas(q, k_pool, v_pool, block_tables, lengths, w_proj,
         grid=(b, mb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, h_out), lambda i, j, tbl_ref, len_ref: (i, 0)),
+            (1, 1, h_out), lambda i, j, tbl_ref, len_ref: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((nh, _LANES), jnp.float32),   # running max
             pltpu.VMEM((nh, _LANES), jnp.float32),   # running normalizer
@@ -322,9 +327,9 @@ def _fused_pallas(q, k_pool, v_pool, block_tables, lengths, w_proj,
         functools.partial(_fused_kernel, scale, bs, g, rep, d2, quant,
                           has_rope),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h_out), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, h_out), q.dtype),
         interpret=interpret,
-    )(tbl, lens, *inputs)
+    )(tbl, lens, *inputs)[:, 0]
 
 
 def fused_decode_layer(

@@ -221,7 +221,7 @@ def _dq_kernel(n_rows, bm, *refs):
     rows = i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
     xm = jnp.where(rows < n_rows, x_ref[:].astype(jnp.float32), 0.0)
     part = jax.lax.dot(xm, w_ref[:].astype(jnp.float32),
-                       preferred_element_type=jnp.float32) * s_ref[:]
+                       preferred_element_type=jnp.float32) * s_ref[0]
 
     @pl.when(s == 0)
     def _init():
@@ -248,13 +248,15 @@ def _dq_pallas(x, wire, scale, kb, interpret):
         in_specs=[
             pl.BlockSpec((bm, kb), lambda i, s: (i, s)),
             pl.BlockSpec((kb, p), lambda i, s: (s, 0)),
-            pl.BlockSpec((1, p), lambda i, s: (s, 0)),
+            # scale rows ride a unit MIDDLE axis: Mosaic only takes
+            # a unit second-last block dim when it equals the array's
+            pl.BlockSpec((1, 1, p), lambda i, s: (s, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, p), lambda i, s: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, p), jnp.float32)],
         interpret=interpret,
-    )(x, wire, scale)
+    )(x, wire, scale[:, None, :])
 
 
 def _dq_impl(x2, wire2, scale2, kb, backend):

@@ -66,7 +66,7 @@ def _unify_vma(*arrays):
     for a in arrays:
         if a is None:
             continue
-        vmas.append(set(getattr(jax.typeof(a), "vma", ()) or ()))
+        vmas.append(set(jax.typeof(a).vma))
     union = set().union(*vmas) if vmas else set()
     if not union:
         return arrays
@@ -555,7 +555,7 @@ def _bwd_fused_kernel(scale, causal, sq_real, sk_real, block_q, skp,
     all fall out of the same pass — where the split dq + dkv kernels
     recompute p twice and traverse HBM twice.  This is the class the
     reference serves with its small-seqlen fmha variants
-    (fmha_api.cpp:358 `_nl` kernels); VERDICT r3 #4."""
+    (fmha_api.cpp:358 `_nl` kernels)."""
     if dropout_p > 0.0:
         seed_ref, refs = refs[0], refs[1:]
     if has_seg:
@@ -849,9 +849,10 @@ def _from_bh(x3, b, n):
 
 
 def _blocks(sq, sk):
-    """Block sizes tuned on v5e (round-3 sweep, BASELINE.md kernel
-    ledger): at sk>=1024 the 1024x1024 score tile amortizes per-grid-step
-    overhead and beats the old 256x512 default ~1.5x (fwd s1024 causal:
+    """Block sizes from a round-3 sweep on a v5e (not measured on
+    today's code): at sk>=1024 the 1024x1024 score tile amortizes
+    per-grid-step overhead and beat the old 256x512 default ~1.5x (fwd
+    s1024 causal:
     946us vs 1494us; s2048: 644us vs 964us); short sequences keep the
     small tiles (256x512 best at s512).  1024x2048 fails to compile
     (VMEM), so 1024 caps both dims."""
@@ -1053,7 +1054,7 @@ def flash_attention(
     # buffers (jax 0.9 check) — run the XLA composition instead.  On
     # real TPU the kernel runs under shard_map as normal (same choice as
     # distributed_fused_adam's CPU path).
-    if not on_tpu() and getattr(jax.typeof(q), "vma", ()):
+    if not on_tpu() and jax.typeof(q).vma:
         generic = True
     if generic:
         return mha_reference(

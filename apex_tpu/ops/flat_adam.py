@@ -9,9 +9,9 @@ the rsqrt, the weight-decay add) into one HBM pass on its own.
 
 A Pallas tile-streaming kernel lived here through round 4
 (``adam_kernel_flat``, swept via ``APEX_TPU_ADAM_BLOCK_ROWS``).  The
-round-5 on-chip sweep was its win-or-delete gate (BASELINE.md): 88M
-fp32, rows=512 → 1.82×, rows=1024 → 1.85× the XLA fused update, and
-rows≥2048 failed to compile — so the kernel and its knob were deleted
+round-5 on-chip sweep was its win-or-delete gate (not measured on
+today's code): 88M fp32, rows=512 → 1.82×, rows=1024 → 1.85× the XLA
+fused update, and rows≥2048 failed to compile — so the kernel and its knob were deleted
 and every optimizer keeps the XLA flat path.
 
 ``adam_kernel_flat`` remains the flat-buffer entry point (the

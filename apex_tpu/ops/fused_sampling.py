@@ -28,8 +28,8 @@ Two execution paths, routed like ``flash_attention`` /
   rows are exact.
 
 ``APEX_TPU_FUSED_SAMPLING=kernel|reference|auto`` overrides the route
-(malformed values warn by name and fall back to ``auto``, the env
-convention of ``utils/probe.py``); an explicit ``backend=`` argument
+(malformed values warn by name and fall back to ``auto``); an
+explicit ``backend=`` argument
 raises on malformed values like the paged-attention gate.  ``auto``
 picks the kernel on TPU or under ``APEX_TPU_PALLAS_INTERPRET=1`` (the
 8-virtual-device CI path) and the reference elsewhere.
@@ -180,7 +180,9 @@ def _uniform_bits(col_u32, row, s0, s1):
     # such multiple is fp32-representable, so u can never round UP to
     # 1.0 and blow the double log into +inf); clamp the bottom so it
     # never sees exactly 0 either
-    u = (x >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    # (the 24 bits go through int32: Mosaic has no uint32 -> f32 cast)
+    u24 = jax.lax.bitcast_convert_type(x >> 8, jnp.int32)
+    u = u24.astype(jnp.float32) * (1.0 / (1 << 24))
     return jnp.maximum(u, 1.0 / (1 << 24))
 
 
@@ -192,7 +194,7 @@ def _sampling_kernel(top_k, top_p, n_valid, *refs):
     no sort, no second HBM pass, one int32 out."""
     seed_ref, temp_ref, x_ref, o_ref = refs
     i = pl.program_id(0)
-    x = x_ref[...].astype(jnp.float32)                    # (1, V)
+    x = x_ref[0].astype(jnp.float32)                      # (1, V)
     V = x.shape[1]
     col = jax.lax.broadcasted_iota(jnp.int32, (1, V), 1)
     valid = col < n_valid          # vocab limit + lane padding together
@@ -256,7 +258,7 @@ def _sampling_kernel(top_k, top_p, n_valid, *refs):
     ms = jnp.max(z)
     sampled = jnp.min(jnp.where(z == ms, col, V))
     out = jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
-    o_ref[...] = jnp.full((1, _LANES), out, jnp.int32)
+    o_ref[0] = jnp.full((1, _LANES), out, jnp.int32)
 
 
 def _key_words(key) -> jax.Array:
@@ -281,23 +283,26 @@ def _fused_pallas(logits, key, temps, top_k, top_p, vocab_limit,
     call = pl.pallas_call(
         functools.partial(_sampling_kernel, top_k, top_p, n_valid),
         grid_spec=_grid_spec(b, logits.shape[1]),
-        out_shape=jax.ShapeDtypeStruct((b, _LANES), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, _LANES), jnp.int32),
         interpret=interpret,
     )
-    out = call(_key_words(key), temps.astype(jnp.float32), logits)
-    return out[:, 0]
+    out = call(_key_words(key), temps.astype(jnp.float32),
+               logits[:, None, :])
+    return out[:, 0, 0]
 
 
 def _grid_spec(b, v_padded):
     from jax.experimental.pallas import tpu as pltpu
 
+    # rows ride a unit MIDDLE axis ([b, 1, v]): Mosaic only takes a
+    # unit second-last block dim when it equals the array's
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[pl.BlockSpec(
-            (1, v_padded), lambda i, seed_ref, temp_ref: (i, 0))],
+            (1, 1, v_padded), lambda i, seed_ref, temp_ref: (i, 0, 0))],
         out_specs=pl.BlockSpec(
-            (1, _LANES), lambda i, seed_ref, temp_ref: (i, 0)),
+            (1, 1, _LANES), lambda i, seed_ref, temp_ref: (i, 0, 0)),
     )
 
 
@@ -305,9 +310,8 @@ def _route(backend: Optional[str]) -> str:
     if backend is None:
         backend = os.environ.get("APEX_TPU_FUSED_SAMPLING", "auto")
         if backend not in ("auto", "kernel", "reference"):
-            # env values warn BY NAME and fall back (utils/probe.py
-            # convention): a typo'd deployment var must not take the
-            # whole decode path down
+            # env values warn BY NAME and fall back: a typo'd
+            # deployment var must not take the whole decode path down
             from apex_tpu.utils.logging import get_logger
 
             get_logger("ops").warning(

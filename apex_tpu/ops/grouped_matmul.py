@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops._pallas_utils import out_struct
+from apex_tpu.ops._pallas_utils import out_struct, param_cotangent
 from apex_tpu.utils.registry import on_tpu
 
 __all__ = ["grouped_matmul", "grouped_matmul_quantized",
@@ -292,7 +292,7 @@ def _gmm_bwd(backend, res, g):
     x, w, offsets = res
     dx = _gmm_impl(g, w.swapaxes(1, 2).astype(g.dtype), offsets,
                    backend).astype(x.dtype)
-    dw = _grouped_dw(x, g, offsets).astype(w.dtype)
+    dw = param_cotangent(_grouped_dw(x, g, offsets).astype(w.dtype), w)
     d_off = np.zeros(offsets.shape, jax.dtypes.float0)
     return dx, dw, d_off
 

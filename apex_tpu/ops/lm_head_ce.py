@@ -27,6 +27,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.ops._pallas_utils import param_cotangent
+from apex_tpu.utils.collectives import match_vma
+
 __all__ = ["lm_head_cross_entropy"]
 
 
@@ -107,10 +110,15 @@ def _fused_ce_bwd(smoothing, chunk, res, g):
             "cv,ch->vh", dlogits, hc, preferred_element_type=jnp.float32)
         return dhead_acc, dh
 
-    dhead, dhs = jax.lax.scan(
-        one, jnp.zeros((v, h), jnp.float32), (hid, lab, lse, gv))
+    # inside shard_map the accumulator varies like what lands in it
+    # (the tokens' shards); the replicated head's cotangent is then
+    # summed over those axes once, after the scan
+    acc0 = match_vma(jnp.zeros((v, h), jnp.float32),
+                     sorted(jax.typeof(hid).vma | jax.typeof(gv).vma))
+    dhead, dhs = jax.lax.scan(one, acc0, (hid, lab, lse, gv))
     dhidden = dhs.reshape(nc * chunk, h)[:n].astype(hidden.dtype)
-    return dhidden, dhead.astype(head.dtype), None
+    return (dhidden, param_cotangent(dhead.astype(head.dtype), head),
+            None)
 
 
 _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)

@@ -178,9 +178,9 @@ def _scaled_softmax_fwd(x, mask, scale, causal):
     # Under differentiation the XLA composition wins outright: the bwd is
     # pure elementwise+reduce that XLA fuses across the fwd/bwd boundary,
     # and an opaque Pallas fwd call in the middle forces the y tensor
-    # through HBM twice (measured 1.96x the XLA chain at 512^2 causal —
-    # BASELINE.md round-3 ledger; VERDICT r3 #4).  The Pallas row kernel
-    # stays the primal (fwd-only) path, where it measures 0.65x.
+    # through HBM twice (an early v5e sweep: 1.96x the XLA chain at
+    # 512^2 causal; not measured on today's code).  The Pallas row
+    # kernel stays the primal (fwd-only) path, where it measured 0.65x.
     # APEX_TPU_SOFTMAX=pallas forces the kernel here too.
     import os
 

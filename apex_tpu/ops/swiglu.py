@@ -20,6 +20,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.ops._pallas_utils import param_cotangent
+
 __all__ = ["fused_bias_swiglu", "fused_bias_swiglu_paired", "bias_swiglu_ref"]
 
 
@@ -59,7 +61,8 @@ def _bwd(res, g):
     dbias = None
     if bias is not None:
         reduce_axes = tuple(range(dx.ndim - 1))
-        dbias = jnp.sum(dx, axis=reduce_axes).astype(bias.dtype)
+        dbias = param_cotangent(
+            jnp.sum(dx, axis=reduce_axes).astype(bias.dtype), bias)
     return dx.astype(x.dtype), dbias
 
 
@@ -100,7 +103,8 @@ def _paired_bwd(res, g):
     dbias = None
     if bias is not None:
         reduce_axes = tuple(range(dy.ndim - bias.ndim))
-        dbias = jnp.sum(dy, axis=reduce_axes).astype(bias.dtype)
+        dbias = param_cotangent(
+            jnp.sum(dy, axis=reduce_axes).astype(bias.dtype), bias)
     return dy.astype(y.dtype), dbias
 
 

@@ -204,8 +204,8 @@ def _ring_blocks(s_local):
 
     Block choice minimizes padded work per ring step: cost ~ padded^2 /
     tile_throughput(b), with relative tile throughputs from the round-3
-    v5e sweep (fwd s1024: 256-blocks 1494us, 512 1186us, 1024 946us —
-    BASELINE.md kernel ledger).  A flat >=1024 cap would pad e.g.
+    v5e sweep (fwd s1024: 256-blocks 1494us, 512 1186us, 1024 946us;
+    not measured on today's code).  A flat >=1024 cap would pad e.g.
     s_local=1280 to 2048 (2.56x the score elements) and lose more to
     padding than the bigger tile wins."""
     rel = {256: 1.0, 512: 1.26, 1024: 1.58}

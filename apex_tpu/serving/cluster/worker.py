@@ -553,13 +553,12 @@ def _build_model(args):
 def main(argv=None) -> int:
     import argparse
 
-    # standalone process on a jax<0.9 container: same shim as bench.py
     import jax
-
-    if not hasattr(jax, "typeof"):
-        jax.typeof = lambda x: jax.core.get_aval(x)
     import jax.numpy as jnp
 
+    from apex_tpu.utils.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="Run one cluster serving worker (prefill or "
                     "decode pool member).")

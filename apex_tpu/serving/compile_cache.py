@@ -181,8 +181,14 @@ class CompileCache:
         t0 = time.perf_counter()
         try:
             with open(self._entry_path(key), "rb") as f:
-                payload, in_tree, out_tree = pickle.load(f)
-            fn = _se.deserialize_and_load(payload, in_tree, out_tree)
+                payload, in_tree, out_tree, device_ids = pickle.load(f)
+            # load onto the devices the executable was compiled for —
+            # left to its default the loader spreads it over every
+            # device of the backend
+            by_id = {d.id: d for d in jax.devices()}
+            fn = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception:
             # missing = cold; anything else = torn/corrupt/incompatible
             # bytes — either way the answer is "compile it", not a crash
@@ -195,7 +201,9 @@ class CompileCache:
               key_parts: Optional[dict]) -> None:
         try:
             payload, in_tree, out_tree = _se.serialize(compiled)
-            blob = pickle.dumps((payload, in_tree, out_tree))
+            device_ids = [
+                d.id for d in compiled.runtime_executable().local_devices()]
+            blob = pickle.dumps((payload, in_tree, out_tree, device_ids))
         except Exception:
             return     # unserializable executable: memo-only this run
         self._atomic_write(self._entry_path(key), blob)

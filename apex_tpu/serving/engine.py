@@ -306,7 +306,7 @@ def _resolve_chunk_tokens(value: Optional[int]) -> Optional[int]:
     """The chunked-prefill knob: ``APEX_TPU_CHUNK_TOKENS`` beats the
     caller's ``chunk_tokens=`` (positive int = chunk size, ``off``/``0``
     = force monolithic); malformed values warn BY NAME and fall back to
-    the caller's value — the PR-5 probe-timeout override discipline."""
+    the caller's value."""
     raw = os.environ.get("APEX_TPU_CHUNK_TOKENS")
     if raw is not None:
         if raw.strip().lower() in ("off", "0"):

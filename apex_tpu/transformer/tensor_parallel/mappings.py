@@ -15,10 +15,18 @@ Functions pairing a forward collective with its transpose in backward:
 | (copy's sequence-parallel dual is the * case: to_model_parallel_region
 |  =False makes the backward a plain split)                                |
 
-Implemented as custom-VJP functions over ``jax.lax`` collectives, usable
-inside ``shard_map`` on the 'tp' axis. jax≥0.9 varying-axes typing is kept
+Implemented over ``jax.lax`` collectives (custom VJPs where the table's
+backward is not what autodiff derives), usable inside ``shard_map`` on
+the 'tp' axis.  The varying-axes (vma) typing of ``shard_map`` is kept
 consistent: identities that move a value into per-shard compute insert
-``pvary``; reductions produce axis-invariant values.
+``pvary``; reductions produce axis-invariant values; and a gathered
+value is typed VARYING like ``jax.lax.all_gather``'s — every rank holds
+the same bytes, but the type system cannot prove it, so a caller that
+returns one through a replicated ``out_specs`` reduces it first
+(``pmean`` over identical copies is the identity).  The two scatters
+are plain ``split(pvary(x))``: autodiff's transpose of that — each
+rank's cotangent placed at its rows, summed over ranks — IS the table's
+all-gather, typed invariant like the input it is the cotangent of.
 
 The two sequence-parallel mappings with a collective on *both* sides of
 the table take an ``overlap_comm`` tri-state (explicit bool, or ``None``
@@ -106,20 +114,8 @@ reduce_from_tensor_model_parallel_region.defvjp(_reduce_fwd, _reduce_bwd)
 # ---- scatter/gather along the LAST dim (mappings.py:170,196) --------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def scatter_to_tensor_model_parallel_region(x, axis=TP_AXIS):
     return _split_along(_pvary(x, axis), -1, axis)
-
-
-def _scatter_fwd(x, axis):
-    return _split_along(_pvary(x, axis), -1, axis), None
-
-
-def _scatter_bwd(axis, _, g):
-    return (jax.lax.all_gather(g, axis, axis=g.ndim - 1, tiled=True),)
-
-
-scatter_to_tensor_model_parallel_region.defvjp(_scatter_fwd, _scatter_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -141,20 +137,8 @@ gather_from_tensor_model_parallel_region.defvjp(_gather_fwd, _gather_bwd)
 # ---- sequence-parallel: FIRST dim (mappings.py:55,95,114,223,245) ---------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def scatter_to_sequence_parallel_region(x, axis=TP_AXIS):
     return _split_along(_pvary(x, axis), 0, axis)
-
-
-def _sp_scatter_fwd(x, axis):
-    return _split_along(_pvary(x, axis), 0, axis), None
-
-
-def _sp_scatter_bwd(axis, _, g):
-    return (jax.lax.all_gather(g, axis, axis=0, tiled=True),)
-
-
-scatter_to_sequence_parallel_region.defvjp(_sp_scatter_fwd, _sp_scatter_bwd)
 
 
 def _seq_all_gather(x, axis, overlap_comm):
@@ -207,10 +191,7 @@ gather_from_sequence_parallel_region.defvjp(_sp_gather_fwd, _sp_gather_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def reduce_scatter_to_sequence_parallel_region(x, axis=TP_AXIS,
-                                               overlap_comm=None):
-    """fwd: sum-scatter along dim 0; bwd: all-gather.  ``overlap_comm``
-    (tri-state) decomposes both into ppermute ring hops."""
+def _sp_reduce_scatter(x, axis, overlap_comm):
     return _seq_reduce_scatter(x, axis, overlap_comm)
 
 
@@ -219,7 +200,18 @@ def _sp_rs_fwd(x, axis, overlap_comm):
 
 
 def _sp_rs_bwd(axis, overlap_comm, _, g):
-    return (_pvary(_seq_all_gather(g, axis, overlap_comm), axis),)
+    return (_seq_all_gather(g, axis, overlap_comm),)
 
 
-reduce_scatter_to_sequence_parallel_region.defvjp(_sp_rs_fwd, _sp_rs_bwd)
+_sp_reduce_scatter.defvjp(_sp_rs_fwd, _sp_rs_bwd)
+
+
+def reduce_scatter_to_sequence_parallel_region(x, axis=TP_AXIS,
+                                               overlap_comm=None):
+    """fwd: sum-scatter along dim 0; bwd: all-gather.  ``overlap_comm``
+    (tri-state) decomposes both into ppermute ring hops.  The input is
+    per-rank partial sums, so it enters as varying: an axis-invariant
+    ``x`` (every rank contributing the same value) is ``pvary``-ed
+    here, outside the custom VJP, and its cotangent then sums over the
+    ranks as autodiff's transpose of that cast."""
+    return _sp_reduce_scatter(_pvary(x, axis), axis, overlap_comm)

@@ -93,7 +93,7 @@ def pvary(tree, axis_name: str):
 def vma_of(x) -> tuple:
     """The manual axes ``x`` is typed as varying over (empty outside
     shard_map / for untyped tracers)."""
-    return tuple(getattr(jax.typeof(x), "vma", ()) or ())
+    return tuple(jax.typeof(x).vma)
 
 
 def match_vma(tree, axes):
@@ -115,12 +115,7 @@ def match_vma(tree, axes):
 
 def is_varying(x, axis_name: str) -> bool:
     """True if ``x`` still differs across shards of ``axis_name``."""
-    try:
-        return axis_name in jax.typeof(x).vma
-    except AttributeError:
-        # Outside shard_map / older tracer: assume varying (legacy pmap
-        # semantics) — callers get an explicit collective.
-        return True
+    return axis_name in jax.typeof(x).vma
 
 
 def grad_sum(tree: Any, axis_name: str) -> Any:

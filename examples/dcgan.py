@@ -24,6 +24,7 @@ import numpy as np
 
 from apex_tpu.amp.frontend import make_train_step
 from apex_tpu.optimizers import fused_adam
+from apex_tpu.utils.jax_cache import enable_compile_cache
 
 NZ = 64          # latent dim
 NGF = NDF = 32   # feature widths
@@ -68,6 +69,7 @@ def synthetic_images(batch, seed=0):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--steps", type=int, default=20)

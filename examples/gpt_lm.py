@@ -43,6 +43,7 @@ from apex_tpu.models.gpt import make_gpt_train_step
 from apex_tpu.optimizers import fused_adam
 from apex_tpu.checkpoint import (
     RecoveryManager, latest_step, restore_sharded, save_sharded)
+from apex_tpu.utils.jax_cache import enable_compile_cache
 
 VOCAB = 384          # 256 byte values, padded for tp divisibility
 
@@ -58,6 +59,7 @@ def batches(data: np.ndarray, batch: int, seq: int, seed: int = 0):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--data", required=True, help="UTF-8 text file")
     ap.add_argument("--steps", type=int, default=200)

@@ -47,6 +47,7 @@ from apex_tpu.utils.checkpoint import (
     restore_checkpoint,
     save_checkpoint,
 )
+from apex_tpu.utils.jax_cache import enable_compile_cache
 
 
 def synthetic_batches(batch, hw=224, classes=1000, seed=0):
@@ -111,6 +112,7 @@ def lr_schedule(base_lr, step, steps_per_epoch):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=128)
     ap.add_argument("--steps", type=int, default=60)

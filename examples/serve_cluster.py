@@ -4,9 +4,11 @@ The two-process topology on one host: a prefill worker and a decode
 worker spawn as their OWN OS processes, a router in this process
 admits requests by SLO class, dispatches prefill → ships the KV cache
 over a localhost socket → injects it into the decode pool, and checks
-the result against the single-process engine.  CPU-runnable::
+the result against the single-process engine.  It pins itself and its
+workers to the CPU (a chip belongs to one process, and this one computes
+the reference itself)::
 
-    JAX_PLATFORMS=cpu python examples/serve_cluster.py --requests 12
+    python examples/serve_cluster.py --requests 12
 
 What it prints per request: SLO class, router-measured TTFT / e2e, the
 KV handoff bytes, and at the end the token-identity verdict vs the
@@ -30,15 +32,14 @@ import argparse
 import time
 
 import jax
-
-if not hasattr(jax, "typeof"):     # jax<0.9 containers, as bench.py
-    jax.typeof = lambda x: jax.core.get_aval(x)
-
 import jax.numpy as jnp
 import numpy as np
 
 
 def main():
+    from apex_tpu.utils.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--requests", type=int, default=9)
     ap.add_argument("--wire-dtype", default="raw",
@@ -52,6 +53,15 @@ def main():
                     help="stream router cluster.* metrics to this "
                          "JSONL file")
     args = ap.parse_args()
+
+    # three processes on one host, and this one computes the reference:
+    # a chip belongs to one process, so all of them are pinned to the
+    # CPU before backend init (the workers inherit os.environ)
+    import os
+
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+    print("== one-host topology demo: every process pinned to the CPU ==")
 
     if args.telemetry:
         from apex_tpu import observability as obs

@@ -26,9 +26,11 @@ import numpy as np
 from apex_tpu.models.config import gpt_tiny
 from apex_tpu.models.transformer_lm import init_gpt_params
 from apex_tpu.serving import ServingEngine
+from apex_tpu.utils.jax_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--slots", type=int, default=4)
     p.add_argument("--requests", type=int, default=12)

@@ -17,9 +17,11 @@ import numpy as np
 from apex_tpu import amp
 from apex_tpu.optimizers import fused_adam
 from apex_tpu.parallel.mesh import create_mesh, replicate, shard_batch
+from apex_tpu.utils.jax_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     N, D_in, D_hidden, D_out = 64, 1024, 256, 16
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(N, D_in), jnp.float32)

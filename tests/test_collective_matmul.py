@@ -51,6 +51,14 @@ def _rand(rng, shape, dtype):
     return jnp.asarray(rng.randn(*shape), dtype)
 
 
+def _replicated(fn):
+    """A gathered value is held identically by every rank but typed
+    varying (ppermute hops — and jax.lax.all_gather — cannot prove
+    replication), so to leave shard_map through ``out_specs=P()`` it is
+    reduced first: pmean over identical copies is the identity."""
+    return lambda *a: jax.lax.pmean(fn(*a), "tp")
+
+
 class TestRingPrimitives:
     """ring_all_gather / ring_reduce_scatter vs the monolithic lax ops."""
 
@@ -63,12 +71,14 @@ class TestRingPrimitives:
 
         def ring(x_):
             return shard_map(
-                functools.partial(cm.ring_all_gather, axis_name="tp"),
+                _replicated(
+                    functools.partial(cm.ring_all_gather, axis_name="tp")),
                 mesh=mesh, in_specs=P("tp"), out_specs=P())(x_)
 
         def mono(x_):
             return shard_map(
-                lambda v: jax.lax.all_gather(v, "tp", axis=0, tiled=True),
+                _replicated(lambda v: jax.lax.all_gather(
+                    v, "tp", axis=0, tiled=True)),
                 mesh=mesh, in_specs=P("tp"), out_specs=P())(x_)
 
         np.testing.assert_allclose(np.asarray(ring(x)), np.asarray(mono(x)),
@@ -216,7 +226,8 @@ class TestMatmulReduceScatter:
         mesh = _mesh(n)
 
         ring = shard_map(
-            functools.partial(cm.matmul_all_reduce, axis_name="tp"),
+            _replicated(
+                functools.partial(cm.matmul_all_reduce, axis_name="tp")),
             mesh=mesh, in_specs=(P(None, None, "tp"), P("tp")),
             out_specs=P())
 
@@ -270,7 +281,7 @@ class TestRingTelemetry:
         p0 = reg.counter("collectives.ppermute.calls").value
         shard_map(
             functools.partial(cm.ring_all_gather, axis_name="tp"),
-            mesh=mesh, in_specs=P("tp"), out_specs=P())(x)
+            mesh=mesh, in_specs=P("tp"), out_specs=P("tp"))(x)
         # n−1 hops, each through the counted ppermute wrapper
         assert (reg.counter("collectives.ppermute.calls").value - p0
                 == n - 1)
@@ -325,8 +336,9 @@ class TestMappingsOverlap:
                 @functools.partial(shard_map, mesh=mesh, in_specs=P("tp"),
                                    out_specs=P())
                 def fwd(x_):
-                    return tp.gather_from_sequence_parallel_region(
-                        x_, True, "tp", overlap)
+                    return jax.lax.pmean(
+                        tp.gather_from_sequence_parallel_region(
+                            x_, True, "tp", overlap), "tp")
 
                 return fwd(x), grads(x)
 

@@ -236,8 +236,6 @@ class TestEngineRoundTrip:
 _FRESH = r"""
 import hashlib, json, sys
 import jax
-if not hasattr(jax, "typeof"):
-    jax.typeof = lambda x: jax.core.get_aval(x)
 import jax.numpy as jnp
 import numpy as np
 from apex_tpu.models.config import TransformerConfig

@@ -214,12 +214,10 @@ def test_bench_decode_run_produces_valid_trace(tmp_path, monkeypatch,
     APEX_TPU_TELEMETRY_TRACE set produces a schema-valid trace
     containing span, step, and serving-request rows, and a BENCH JSON
     line carrying the runtime (compile/hbm) block.  Runs bench.main()
-    in-process so the conftest jax-compat shims apply (a subprocess on
-    a jax<0.9 container would lose the mesh/typeof shims the decode
-    rows need)."""
+    in-process, on the conftest's CPU, with ``--cpu-smoke``."""
     trace_path = tmp_path / "bench_trace.json"
     monkeypatch.setenv("APEX_TPU_TELEMETRY_TRACE", str(trace_path))
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--decode"])
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--decode", "--cpu-smoke"])
     spec = importlib.util.spec_from_file_location(
         "bench_under_test", os.path.join(REPO, "bench.py"))
     bench_mod = importlib.util.module_from_spec(spec)

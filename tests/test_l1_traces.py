@@ -202,7 +202,7 @@ class TestL1TracesDistributed:
     def test_context_parallel_trace_matches_golden(self, mode):
         """Context parallelism is not allowed to bend the optimizer
         trajectory: 12 steps under dp=2 x sp=4 must track the stored
-        single-device golden (VERDICT r4 #5e)."""
+        single-device golden."""
         if len(jax.devices()) < 8:
             pytest.skip("needs the 8-device mesh")
         with open(GOLDEN) as f:
@@ -217,7 +217,7 @@ class TestL1TracesDistributed:
 
 
 class TestL1TracesGQA:
-    """The GQA path gets its own golden (VERDICT r4 #5e): the group-major
+    """The GQA path gets its own golden: the group-major
     layout landed in round 5 and future refactors must not bend its
     numerics.  Same regen protocol: `python tests/test_l1_traces.py
     --regen` rewrites both goldens."""

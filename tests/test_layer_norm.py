@@ -145,10 +145,9 @@ def test_pallas_interpret_matches_ref(monkeypatch):
 
 @pytest.mark.parametrize("mode", ["pallas"])
 def test_pallas_bwd_kernel_opt_in(monkeypatch, mode):
-    """The Pallas revisit backward became the default in round 5 (it wins
-    the on-chip fwd+bwd chain, 0.725x the XLA mix — BASELINE.md kernel
-    ledger); the round-4 pallas_split variant was deleted (Mosaic rejects
-    its partials block spec).  Exercise the kernel against the XLA
+    """The Pallas revisit backward is the default (an earlier on-chip
+    sweep favoured it; not measured on today's code); the pallas_split
+    variant was deleted (Mosaic rejects its partials block spec).  Exercise the kernel against the XLA
     composition so it cannot rot."""
     monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.setenv("APEX_TPU_LN_BWD", mode)

@@ -285,7 +285,7 @@ class TestPipeline:
 
 
 class TestPipelineMasksAndDropout:
-    """VERDICT r1 item 7: padding masks + dropout through the pipeline
+    """Padding masks + dropout through the pipeline
     packet (BERT-style models under PP)."""
 
     @pytest.mark.slow   # dryrun pipeline feature phase runs the same mask packet

@@ -20,12 +20,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from apex_tpu.parallel.mesh import create_mesh
 from apex_tpu.transformer.moe import init_moe_params, switch_moe_mlp
 
-# the GSPMD ambient-mesh surface (abstract meshes + set_mesh) needs the
-# jax>=0.9 toolchain; the explicit-mesh island below runs everywhere the
-# conftest shard_map shim does
-_HAS_GSPMD = (hasattr(jax.sharding, "get_abstract_mesh")
-              and hasattr(jax, "set_mesh"))
-
 
 def _data(b=2, s=16, h=32, seed=0):
     rs = np.random.RandomState(seed)
@@ -497,8 +491,6 @@ class TestRaggedEPIsland:
             a = np.asarray(g, np.float32)
             assert np.isfinite(a).all() and np.abs(a).sum() > 0, name
 
-    @pytest.mark.skipif(not _HAS_GSPMD,
-                        reason="needs the jax>=0.9 GSPMD surface")
     def test_ambient_mesh_activates_island(self):
         """Under jax.set_mesh the island self-activates from the
         abstract mesh — no explicit ep_mesh plumbing needed."""

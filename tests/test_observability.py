@@ -392,12 +392,18 @@ class TestOptimizerNormTelemetry:
 
 class TestCollectivesTelemetry:
     def test_pmap_psum_counts_calls_and_bytes(self):
+        from jax.sharding import Mesh, PartitionSpec as P
+
         from apex_tpu.utils.collectives import grad_sum
 
         reg = obs.configure()
         n = jax.local_device_count()
         x = jnp.arange(float(n * 4)).reshape(n, 4)
-        out = jax.pmap(lambda v: grad_sum(v, "dp"), axis_name="dp")(x)
+        # shard_map, not pmap: the helpers read shard_map's varying-axes
+        # typing, which jax.pmap does not carry
+        mesh = Mesh(np.asarray(jax.devices()), ("dp",))
+        out = jax.shard_map(lambda v: grad_sum(v, "dp"), mesh=mesh,
+                            in_specs=P("dp"), out_specs=P())(x)
         np.testing.assert_allclose(
             np.asarray(out)[0], np.asarray(x).sum(0))
         # trace-time accounting: one psum emitted for the one f32[4] leaf
@@ -405,12 +411,16 @@ class TestCollectivesTelemetry:
         assert reg.counter("collectives.psum.bytes").value >= 4 * 4
 
     def test_flag_or_counts_pmax(self):
+        from jax.sharding import Mesh, PartitionSpec as P
+
         from apex_tpu.utils.collectives import flag_or
 
         reg = obs.configure()
         n = jax.local_device_count()
         flags = jnp.zeros((n,), bool).at[0].set(True)
-        out = jax.pmap(lambda f: flag_or(f, "dp"), axis_name="dp")(flags)
+        mesh = Mesh(np.asarray(jax.devices()), ("dp",))
+        out = jax.shard_map(lambda f: flag_or(f, "dp"), mesh=mesh,
+                            in_specs=P("dp"), out_specs=P())(flags)
         assert bool(np.asarray(out).all())
         assert reg.counter("collectives.pmax.calls").value >= 1
 
@@ -458,7 +468,7 @@ class TestCollectivesTelemetry:
         h0 = reg.counter("collectives.ring.hops").value
         jax.shard_map(
             functools.partial(cm.ring_all_gather, axis_name="tp"),
-            mesh=mesh, in_specs=P("tp"), out_specs=P())(
+            mesh=mesh, in_specs=P("tp"), out_specs=P("tp"))(
                 jnp.arange(float(n * 2)).reshape(n * 2, 1))
         calls = reg.counter("collectives.ring.calls").value - c0
         hops = reg.counter("collectives.ring.hops").value - h0

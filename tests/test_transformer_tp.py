@@ -251,8 +251,6 @@ class TestGSPMDLayers:
         np.testing.assert_allclose(np.asarray(y), expect, atol=1e-6)
 
 
-@pytest.mark.skipif(not hasattr(jax, "set_mesh"),
-                    reason="jax.set_mesh (jax>=0.9 GSPMD surface) required")
 class TestSequenceParallelParity:
     """ISSUE 5 satellite: the ``sequence_parallel_enabled`` Column/Row
     layers vs their non-SP counterparts, forward AND backward, on the
@@ -386,7 +384,10 @@ class TestSequenceParallelMappingTable:
             @functools.partial(shard_map, mesh=tp8_mesh,
                                in_specs=P("tp"), out_specs=P())
             def fwd(x_):
-                return tp.gather_from_sequence_parallel_region(x_)
+                # gathered values are typed varying: pmean over the
+                # identical copies is the identity, typed invariant
+                return jax.lax.pmean(
+                    tp.gather_from_sequence_parallel_region(x_), "tp")
 
             base = reg.counter("collectives.ring.calls").value
             out_mono = fwd(x)
@@ -395,7 +396,8 @@ class TestSequenceParallelMappingTable:
             @functools.partial(shard_map, mesh=tp8_mesh,
                                in_specs=P("tp"), out_specs=P())
             def fwd2(x_):
-                return tp.gather_from_sequence_parallel_region(x_)
+                return jax.lax.pmean(
+                    tp.gather_from_sequence_parallel_region(x_), "tp")
 
             with overlap_scope(True):
                 out_ring = fwd2(x)

@@ -133,14 +133,17 @@ def smoke_cluster() -> int:
 
 
 def main() -> int:
-    import jax
+    import os
 
-    # jax<0.9 compatibility shim (a no-op on the target toolchain,
-    # same as bench.py): pinned containers lack jax.typeof, which the
-    # flash-attention gate consults on every prefill
-    if not hasattr(jax, "typeof"):
-        jax.typeof = lambda x: jax.core.get_aval(x)
+    import jax
     import numpy as np
+
+    # a toy-model smoke of the export surface whose second half spawns
+    # worker processes: a chip belongs to one process, so everything is
+    # pinned to the CPU before backend init (workers inherit os.environ)
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+    print("[exporter_smoke] every process pinned to the CPU")
 
     from apex_tpu import observability as obs
     from apex_tpu.models.config import gpt_125m
