@@ -246,7 +246,8 @@ def make_train_step(
         def scaled_loss_fn(master_params, *mb):
             # Forward runs on compute-dtype params derived from the masters
             # (reference O2: model holds fp16 copies of fp32 masters).
-            compute_params = policy.cast_params(master_params)
+            with jax.named_scope("cast_params"):
+                compute_params = policy.cast_params(master_params)
             if policy.per_op_casts:
                 # O1/O4 "patch the world": params pre-cast at the step
                 # boundary AND jax entry points patched per the cast
@@ -255,13 +256,16 @@ def make_train_step(
                 from apex_tpu.amp.patch import amp_patch_scope
                 from apex_tpu.amp.policy import _effective
 
-                compute_params = policy.cast_to_compute(
-                    compute_params, respect_norms=True
-                )
-                with amp_patch_scope(_effective(policy.compute_dtype)):
+                with jax.named_scope("cast_params"):
+                    compute_params = policy.cast_to_compute(
+                        compute_params, respect_norms=True
+                    )
+                with amp_patch_scope(_effective(policy.compute_dtype)), \
+                        jax.named_scope("model"):
                     out = loss_fn(compute_params, *mb)
             else:
-                out = loss_fn(compute_params, *mb)
+                with jax.named_scope("model"):
+                    out = loss_fn(compute_params, *mb)
             loss, aux = (out if has_aux else (out, None))
             return scaler_lib.scale_loss(loss, ls_state), (loss, aux)
 
@@ -328,43 +332,44 @@ def make_train_step(
             grads, (loss, aux) = jax.grad(scaled_loss_fn, has_aux=True)(
                 diff_params, *batch
             )
-        grads, finite = scaler_lib.unscale_grads(grads, ls_state)
+        with jax.named_scope("amp_unscale"):
+            grads, finite = scaler_lib.unscale_grads(grads, ls_state)
 
         new_comm_state = state.comm_state
         if axis_name is not None:
             from apex_tpu.utils.collectives import flag_and, grad_mean
 
-            if compressing:
-                from apex_tpu import comm as comm_lib
+            with jax.named_scope("grad_reduce"):
+                if compressing:
+                    from apex_tpu import comm as comm_lib
 
-                # bucketed block-scaled quantized all-reduce; residuals
-                # (when error feedback is on) ride the train state in
-                # unscaled-fp32 units, so loss-scale changes between
-                # steps don't corrupt the carried error
-                grads, new_comm_state = comm_lib.reduce_gradients(
-                    grads, axis_name, comm_cfg,
-                    residuals=state.comm_state if use_ef else None,
-                )
-            else:
-                # vma-aware: under shard_map SPMD-AD the grads arrive
-                # pre-summed (see utils/collectives.py) — grad_mean only
-                # divides then.
-                grads = grad_mean(grads, axis_name)
-            finite = flag_and(finite, axis_name)
+                    # bucketed block-scaled quantized all-reduce; residuals
+                    # (when error feedback is on) ride the train state in
+                    # unscaled-fp32 units, so loss-scale changes between
+                    # steps don't corrupt the carried error
+                    grads, new_comm_state = comm_lib.reduce_gradients(
+                        grads, axis_name, comm_cfg,
+                        residuals=state.comm_state if use_ef else None,
+                    )
+                else:
+                    # vma-aware: under shard_map SPMD-AD the grads arrive
+                    # pre-summed (see utils/collectives.py) — grad_mean
+                    # only divides then.
+                    grads = grad_mean(grads, axis_name)
+                finite = flag_and(finite, axis_name)
 
         if grad_postprocess is not None:
             grads = grad_postprocess(grads)
 
-        new_ls_state, overflow = scaler_lib.update_loss_scale(
-            ls_cfg, ls_state, ~finite
-        )
+        with jax.named_scope("amp_scale_update"):
+            new_ls_state, overflow = scaler_lib.update_loss_scale(
+                ls_cfg, ls_state, ~finite
+            )
 
-        updates, new_opt_state = optimizer.update(
-            grads, state.opt_state, state.master_params
-        )
-        new_master = jax.tree_util.tree_map(
-            lambda p, u: p + u.astype(p.dtype), state.master_params, updates
-        )
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.master_params
+            )
 
         # Overflow ⇒ keep old params & opt state (skip-step, handle.py:128-154)
         def select(new, old):
@@ -372,13 +377,19 @@ def make_train_step(
                 lambda n, o: jnp.where(overflow, o, n), new, old
             )
 
-        new_master = select(new_master, state.master_params)
-        new_opt_state = select(new_opt_state, state.opt_state)
-        if use_ef:
-            # an overflowed step's grads (and thus residuals) are
-            # garbage — keep the carried error exactly like the params
-            new_comm_state = select(new_comm_state, state.comm_state)
-        new_params = policy.cast_params(new_master)
+        with jax.named_scope("apply_update"):
+            new_master = jax.tree_util.tree_map(
+                lambda p, u: p + u.astype(p.dtype),
+                state.master_params, updates
+            )
+            new_master = select(new_master, state.master_params)
+            new_opt_state = select(new_opt_state, state.opt_state)
+            if use_ef:
+                # an overflowed step's grads (and thus residuals) are
+                # garbage — keep the carried error exactly like the params
+                new_comm_state = select(new_comm_state, state.comm_state)
+        with jax.named_scope("cast_params"):
+            new_params = policy.cast_params(new_master)
 
         new_state = TrainState(
             step=state.step + jnp.where(overflow, 0, 1),

@@ -78,14 +78,16 @@ def bert_forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
     """→ (lm_logits [b,s,v], binary_logits [b,2])."""
     cd = cfg.compute_dtype
     emb = params["embedding"]
-    h = jnp.take(emb["word"].astype(cd), tokens, axis=0)
-    h = h + emb["position"][: tokens.shape[1]].astype(cd)[None]
-    if tokentype_ids is not None:
-        h = h + jnp.take(emb["tokentype"].astype(cd), tokentype_ids,
-                         axis=0)
-    h = fused_layer_norm(h, params["embedding_ln"]["scale"],
-                         params["embedding_ln"]["bias"],
-                         eps=cfg.layernorm_epsilon)
+    with jax.named_scope("embed"):
+        h = jnp.take(emb["word"].astype(cd), tokens, axis=0)
+        h = h + emb["position"][: tokens.shape[1]].astype(cd)[None]
+        if tokentype_ids is not None:
+            h = h + jnp.take(emb["tokentype"].astype(cd), tokentype_ids,
+                             axis=0)
+    with jax.named_scope("embedding_ln"):
+        h = fused_layer_norm(h, params["embedding_ln"]["scale"],
+                             params["embedding_ln"]["bias"],
+                             eps=cfg.layernorm_epsilon)
 
     kpm = _padding_mask(attention_mask)
     h = transformer_backbone(params, h, cfg, _ident_ctx(),
@@ -93,19 +95,21 @@ def bert_forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
                              dropout_rng=dropout_rng)
 
     # MLM head (Megatron lm_head: dense+gelu+LN then tied decoder)
-    lm = params["lm_head"]
-    g = jax.nn.gelu(h @ lm["dense_kernel"].astype(cd)
-                    + lm["dense_bias"].astype(cd))
-    g = apply_norm(cfg, g, lm["ln_scale"], lm["ln_bias"])
-    lm_logits = jnp.einsum(
-        "bsh,vh->bsv", g, emb["word"].astype(cd),
-        preferred_element_type=jnp.float32) + lm["decoder_bias"]
+    with jax.named_scope("mlm_head"):
+        lm = params["lm_head"]
+        g = jax.nn.gelu(h @ lm["dense_kernel"].astype(cd)
+                        + lm["dense_bias"].astype(cd))
+        g = apply_norm(cfg, g, lm["ln_scale"], lm["ln_bias"])
+        lm_logits = jnp.einsum(
+            "bsh,vh->bsv", g, emb["word"].astype(cd),
+            preferred_element_type=jnp.float32) + lm["decoder_bias"]
 
     # NSP head on [CLS] (position 0)
-    bh = params["binary_head"]
-    pooled = jnp.tanh(h[:, 0].astype(jnp.float32)
-                      @ bh["pooler_kernel"] + bh["pooler_bias"])
-    binary_logits = pooled @ bh["cls_kernel"] + bh["cls_bias"]
+    with jax.named_scope("nsp_head"):
+        bh = params["binary_head"]
+        pooled = jnp.tanh(h[:, 0].astype(jnp.float32)
+                          @ bh["pooler_kernel"] + bh["pooler_bias"])
+        binary_logits = pooled @ bh["cls_kernel"] + bh["cls_bias"]
     return lm_logits, binary_logits
 
 
@@ -123,18 +127,20 @@ def bert_pretrain_loss(params, tokens, mlm_labels, nsp_labels, cfg,
     lm_logits, bin_logits = bert_forward(
         params, tokens, cfg, tokentype_ids=tokentype_ids,
         attention_mask=attention_mask, dropout_rng=dropout_rng)
-    v = lm_logits.shape[-1]
-    flat_logits = lm_logits.reshape(-1, v)
-    flat_labels = mlm_labels.reshape(-1)
-    valid = flat_labels >= 0
-    per_tok = softmax_cross_entropy_loss(
-        flat_logits, jnp.clip(flat_labels, 0, v - 1), padding_idx=None)
-    denom = jnp.maximum(jnp.sum(valid), 1)
-    mlm_loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / denom
+    with jax.named_scope("mlm_head"):
+        v = lm_logits.shape[-1]
+        flat_logits = lm_logits.reshape(-1, v)
+        flat_labels = mlm_labels.reshape(-1)
+        valid = flat_labels >= 0
+        per_tok = softmax_cross_entropy_loss(
+            flat_logits, jnp.clip(flat_labels, 0, v - 1), padding_idx=None)
+        denom = jnp.maximum(jnp.sum(valid), 1)
+        mlm_loss = jnp.sum(jnp.where(valid, per_tok, 0.0)) / denom
 
-    nsp_lp = jax.nn.log_softmax(bin_logits, axis=-1)
-    nsp_loss = -jnp.mean(
-        jnp.take_along_axis(nsp_lp, nsp_labels[:, None], axis=1))
+    with jax.named_scope("nsp_head"):
+        nsp_lp = jax.nn.log_softmax(bin_logits, axis=-1)
+        nsp_loss = -jnp.mean(
+            jnp.take_along_axis(nsp_lp, nsp_labels[:, None], axis=1))
     return mlm_loss + nsp_loss
 
 

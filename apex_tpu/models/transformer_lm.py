@@ -631,44 +631,45 @@ def _attention(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
     nh = cfg.num_attention_heads // ctx.tp
     b, s, _ = x.shape
 
-    xi = ctx.copy_in(x)
-    wq = lp["qkv_kernel"]
-    if _is_quantized(wq):
-        # weight-only int8 serving path (ISSUE 14): single-device by
-        # contract — quantize_params is a serving conversion, manual-TP
-        # training never sees quantized leaves
-        if ctx.tp > 1:
-            raise ValueError(
-                "quantized kernels (models/quantized.quantize_params) "
-                "are a single-device serving path; they cannot shard "
-                f"over the manual tp={ctx.tp} context")
-        from apex_tpu.ops.dense import quantized_matmul
+    with jax.named_scope("qkv"):
+        xi = ctx.copy_in(x)
+        wq = lp["qkv_kernel"]
+        if _is_quantized(wq):
+            # weight-only int8 serving path (ISSUE 14): single-device by
+            # contract — quantize_params is a serving conversion, manual-TP
+            # training never sees quantized leaves
+            if ctx.tp > 1:
+                raise ValueError(
+                    "quantized kernels (models/quantized.quantize_params) "
+                    "are a single-device serving path; they cannot shard "
+                    f"over the manual tp={ctx.tp} context")
+            from apex_tpu.ops.dense import quantized_matmul
 
-        qkv = quantized_matmul(xi, wq) + lp["qkv_bias"].astype(x.dtype)
-    else:
-        qkv = xi @ wq.astype(x.dtype) + lp["qkv_bias"].astype(x.dtype)
-    qkv = ctx.constrain_col(qkv)
-    if cfg.is_gqa:
-        # group-major layout (per group [q x rep | k | v]): a contiguous
-        # tp chunk holds whole groups, so manual TP is legal whenever
-        # each rank gets an integral number of groups
-        if ctx.tp > 1 and cfg.kv_groups % ctx.tp:
-            raise ValueError(
-                f"GQA with num_query_groups={cfg.kv_groups} cannot "
-                f"shard over the manual shard_map tensor-parallel "
-                f"context with tp={ctx.tp}: tp must divide the group "
-                "count (each rank needs whole [q x rep | k | v] "
-                "groups). Use a tp that divides num_query_groups, or "
-                "the GSPMD context (make_gpt_train_step over a mesh), "
-                "which replicates KV heads as needed")
-        q, k, v = split_qkv_gqa(cfg, qkv, b, s, nh)
-    else:
-        qkv = qkv.reshape(b, s, nh, -1)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-    if rope is not None:
-        cos, sin = rope
-        q = _apply_rope(q, cos, sin)
-        k = _apply_rope(k, cos, sin)
+            qkv = quantized_matmul(xi, wq) + lp["qkv_bias"].astype(x.dtype)
+        else:
+            qkv = xi @ wq.astype(x.dtype) + lp["qkv_bias"].astype(x.dtype)
+        qkv = ctx.constrain_col(qkv)
+        if cfg.is_gqa:
+            # group-major layout (per group [q x rep | k | v]): a contiguous
+            # tp chunk holds whole groups, so manual TP is legal whenever
+            # each rank gets an integral number of groups
+            if ctx.tp > 1 and cfg.kv_groups % ctx.tp:
+                raise ValueError(
+                    f"GQA with num_query_groups={cfg.kv_groups} cannot "
+                    f"shard over the manual shard_map tensor-parallel "
+                    f"context with tp={ctx.tp}: tp must divide the group "
+                    "count (each rank needs whole [q x rep | k | v] "
+                    "groups). Use a tp that divides num_query_groups, or "
+                    "the GSPMD context (make_gpt_train_step over a mesh), "
+                    "which replicates KV heads as needed")
+            q, k, v = split_qkv_gqa(cfg, qkv, b, s, nh)
+        else:
+            qkv = qkv.reshape(b, s, nh, -1)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+        if rope is not None:
+            cos, sin = rope
+            q = _apply_rope(q, cos, sin)
+            k = _apply_rope(k, cos, sin)
     # Under GQA, k/v stay at group width here: the flash kernel consumes
     # them directly (its index maps broadcast each group head to its rep
     # query heads — the repeated tensor never exists in HBM); the paths
@@ -685,15 +686,16 @@ def _attention(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
     with jax.named_scope("core_attention"):
         ctxv = _core_attention(cfg, q, k, v, attention_mask, dropout_rng,
                                ctx)
-    ctxv = ctxv.reshape(b, s, -1)
-    wp = lp["proj_kernel"]
-    if _is_quantized(wp):
-        from apex_tpu.ops.dense import quantized_matmul
+    with jax.named_scope("proj"):
+        ctxv = ctxv.reshape(b, s, -1)
+        wp = lp["proj_kernel"]
+        if _is_quantized(wp):
+            from apex_tpu.ops.dense import quantized_matmul
 
-        out = ctx.reduce_out(quantized_matmul(ctxv, wp))
-    else:
-        out = _row_parallel_out(ctx, ctxv, wp.astype(x.dtype))
-    out = out + lp["proj_bias"].astype(x.dtype)
+            out = ctx.reduce_out(quantized_matmul(ctxv, wp))
+        else:
+            out = _row_parallel_out(ctx, ctxv, wp.astype(x.dtype))
+        out = out + lp["proj_bias"].astype(x.dtype)
     return (out, k, v) if return_kv else out
 
 
@@ -746,41 +748,43 @@ def _mlp(cfg: TransformerConfig, lp: dict, x, ctx: TPContext):
     matmul instead — single-device serving path; the 3-D swiglu paired
     kernel's trailing axes flatten inside ``dense_quantized`` so the
     ``[b, s, 2, f]`` layout is unchanged."""
-    xi = ctx.copy_in(x)
-    w1 = lp["fc1_kernel"]
-    if cfg.activation == "swiglu":
-        if _is_quantized(w1):
+    with jax.named_scope("fc1"):
+        xi = ctx.copy_in(x)
+        w1 = lp["fc1_kernel"]
+        if cfg.activation == "swiglu":
+            if _is_quantized(w1):
+                from apex_tpu.ops.dense import quantized_matmul
+
+                y = quantized_matmul(xi, w1)          # [b, s, 2, f]
+            else:
+                # paired [h, 2, f] kernel: each tp shard of the f dim is a
+                # (gate, up) pair, matching the single-device layout exactly
+                y = jnp.einsum("bsh,hcf->bscf", xi, w1.astype(x.dtype))
+            y = ctx.constrain_col(y)
+            y = fused_bias_swiglu_paired(y, lp["fc1_bias"].astype(x.dtype))
+        else:
+            if _is_quantized(w1):
+                from apex_tpu.ops.dense import quantized_matmul
+
+                y = quantized_matmul(xi, w1) + lp["fc1_bias"].astype(x.dtype)
+            else:
+                y = xi @ w1.astype(x.dtype) + lp["fc1_bias"].astype(x.dtype)
+            y = ctx.constrain_col(y)
+            # 'gelu_tanh' = the tanh approximation (HF gpt2's gelu_new) —
+            # needed for bit-comparable imports of reference-ecosystem
+            # checkpoints (tools/import_hf.py)
+            y = jax.nn.gelu(
+                y.astype(jnp.float32),
+                approximate=cfg.activation == "gelu_tanh").astype(x.dtype)
+    with jax.named_scope("fc2"):
+        w2 = lp["fc2_kernel"]
+        if _is_quantized(w2):
             from apex_tpu.ops.dense import quantized_matmul
 
-            y = quantized_matmul(xi, w1)          # [b, s, 2, f]
+            out = ctx.reduce_out(quantized_matmul(y, w2))
         else:
-            # paired [h, 2, f] kernel: each tp shard of the f dim is a
-            # (gate, up) pair, matching the single-device layout exactly
-            y = jnp.einsum("bsh,hcf->bscf", xi, w1.astype(x.dtype))
-        y = ctx.constrain_col(y)
-        y = fused_bias_swiglu_paired(y, lp["fc1_bias"].astype(x.dtype))
-    else:
-        if _is_quantized(w1):
-            from apex_tpu.ops.dense import quantized_matmul
-
-            y = quantized_matmul(xi, w1) + lp["fc1_bias"].astype(x.dtype)
-        else:
-            y = xi @ w1.astype(x.dtype) + lp["fc1_bias"].astype(x.dtype)
-        y = ctx.constrain_col(y)
-        # 'gelu_tanh' = the tanh approximation (HF gpt2's gelu_new) —
-        # needed for bit-comparable imports of reference-ecosystem
-        # checkpoints (tools/import_hf.py)
-        y = jax.nn.gelu(
-            y.astype(jnp.float32),
-            approximate=cfg.activation == "gelu_tanh").astype(x.dtype)
-    w2 = lp["fc2_kernel"]
-    if _is_quantized(w2):
-        from apex_tpu.ops.dense import quantized_matmul
-
-        out = ctx.reduce_out(quantized_matmul(y, w2))
-    else:
-        out = _row_parallel_out(ctx, y, w2.astype(x.dtype))
-    return out + lp["fc2_bias"].astype(x.dtype)
+            out = _row_parallel_out(ctx, y, w2.astype(x.dtype))
+        return out + lp["fc2_bias"].astype(x.dtype)
 
 
 def _layer(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
@@ -802,8 +806,9 @@ def _layer(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
     # apply_residual_connection_post_layernorm flag (reference
     # standalone_transformer_lm.py:707-710)
     res = h if cfg.apply_residual_connection_post_layernorm else x
-    x = res + _drop_path(_dropout(a, cfg.hidden_dropout, r2),
-                         cfg.drop_path_rate, r4)
+    with jax.named_scope("residual"):
+        x = res + _drop_path(_dropout(a, cfg.hidden_dropout, r2),
+                             cfg.drop_path_rate, r4)
     with jax.named_scope("ln2"):
         h = apply_norm(cfg, x, lp["ln2_scale"], lp["ln2_bias"])
     with jax.named_scope("mlp"):
@@ -813,8 +818,9 @@ def _layer(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
             m = _mlp(cfg, lp, h, ctx)
             aux = jnp.float32(0.0)
     res = h if cfg.apply_residual_connection_post_layernorm else x
-    x = res + _drop_path(_dropout(m, cfg.hidden_dropout, r3),
-                         cfg.drop_path_rate, r5)
+    with jax.named_scope("residual"):
+        x = res + _drop_path(_dropout(m, cfg.hidden_dropout, r3),
+                             cfg.drop_path_rate, r5)
     return ctx.constrain_hidden(x), aux
 
 
@@ -839,9 +845,10 @@ def embed_tokens(emb: dict, tokens, cfg: TransformerConfig,
     """Word embedding lookup + learned position add (shared by the GSPMD
     forward and the shard_map pipeline stage)."""
     cd = cfg.compute_dtype
-    h = vocab_parallel_embed(emb["word"].astype(cd), tokens, ctx)
-    if cfg.position_embedding_type == "learned":
-        h = h + emb["position"][: tokens.shape[1]].astype(cd)[None]
+    with jax.named_scope("embed"):
+        h = vocab_parallel_embed(emb["word"].astype(cd), tokens, ctx)
+        if cfg.position_embedding_type == "learned":
+            h = h + emb["position"][: tokens.shape[1]].astype(cd)[None]
     return h
 
 
@@ -901,20 +908,23 @@ def transformer_backbone(params: dict, hidden, cfg: TransformerConfig,
         from apex_tpu.utils.collectives import pvary as _pvary_
 
         aux0 = _pvary_(aux0, axis)
-    if cfg.scan_layers:
-        (hidden, aux), _ = jax.lax.scan(
-            step, (hidden, aux0), (params["layers"], keys))
-    else:
-        carry = (hidden, aux0)
-        for i in range(n_layers):
-            lp = jax.tree_util.tree_map(lambda v: v[i], params["layers"])
-            carry, _ = step(carry, (lp, keys[i] if needs_rng else None))
-        hidden, aux = carry
+    with jax.named_scope("backbone"):
+        if cfg.scan_layers:
+            (hidden, aux), _ = jax.lax.scan(
+                step, (hidden, aux0), (params["layers"], keys))
+        else:
+            carry = (hidden, aux0)
+            for i in range(n_layers):
+                lp = jax.tree_util.tree_map(
+                    lambda v: v[i], params["layers"])
+                carry, _ = step(carry, (lp, keys[i] if needs_rng else None))
+            hidden, aux = carry
 
     if not apply_final_norm:
         return (hidden, aux) if with_aux else hidden
-    out = apply_norm(cfg, hidden, params["final_ln"]["scale"],
-                     params["final_ln"]["bias"])
+    with jax.named_scope("final_ln"):
+        out = apply_norm(cfg, hidden, params["final_ln"]["scale"],
+                         params["final_ln"]["bias"])
     return (out, aux) if with_aux else out
 
 
@@ -958,27 +968,24 @@ def gpt_loss(params: dict, tokens: jax.Array, labels: jax.Array,
     models; causal masking needs none.
     """
     ctx = ctx or single_device_ctx()
-    if cfg.fused_head_ce and not ctx.vocab_parallel:
-        # fused head+CE: stop before the head and chunk the vocab matmul
-        # into the loss (ops/lm_head_ce.py) — the [tokens, vocab] logits
-        # are never materialized
-        from apex_tpu.ops.lm_head_ce import lm_head_cross_entropy
+    h, aux = gpt_hidden(params, tokens, cfg, ctx,
+                        attention_mask=attention_mask,
+                        dropout_rng=dropout_rng)
+    with jax.named_scope("lm_head_ce"):
+        if cfg.fused_head_ce and not ctx.vocab_parallel:
+            # fused head+CE: chunk the vocab matmul into the loss
+            # (ops/lm_head_ce.py) — the [tokens, vocab] logits are never
+            # materialized
+            from apex_tpu.ops.lm_head_ce import lm_head_cross_entropy
 
-        h, aux = gpt_hidden(params, tokens, cfg, ctx,
-                            attention_mask=attention_mask,
-                            dropout_rng=dropout_rng)
-        head = lm_head_weight(params, cfg).astype(cfg.compute_dtype)
-        losses = lm_head_cross_entropy(
-            h, head, labels, chunk=cfg.head_ce_chunk, ignore_index=-1)
-        n_valid = jnp.maximum(jnp.sum(labels != -1), 1)
-        loss = jnp.sum(losses) / n_valid.astype(jnp.float32)
-        if cfg.num_experts:
-            loss = loss + cfg.moe_aux_loss_coeff * aux / cfg.num_layers
-        return loss
-    logits, aux = gpt_forward(params, tokens, cfg, ctx,
-                              attention_mask=attention_mask,
-                              dropout_rng=dropout_rng, with_aux=True)
-    loss = lm_cross_entropy(logits, labels, ctx)
+            head = lm_head_weight(params, cfg).astype(cfg.compute_dtype)
+            losses = lm_head_cross_entropy(
+                h, head, labels, chunk=cfg.head_ce_chunk, ignore_index=-1)
+            n_valid = jnp.maximum(jnp.sum(labels != -1), 1)
+            loss = jnp.sum(losses) / n_valid.astype(jnp.float32)
+        else:
+            loss = lm_cross_entropy(lm_head_logits(params, h, cfg), labels,
+                                    ctx)
     if cfg.num_experts:
         # Switch load-balance term, mean over layers
         loss = loss + cfg.moe_aux_loss_coeff * aux / cfg.num_layers

@@ -3,8 +3,8 @@
 The reference apex ships its subsystems dark: loss-scale decisions,
 fused-optimizer behavior, and collective traffic are invisible without
 user prints.  This package is the one measurement path for the repo —
-``bench.py``, ``tools/measure_all.py``, ``tools/step_breakdown.py`` and
-the training loops all report through it — built from three pieces:
+``bench.py``, ``tools/measure_all.py`` and the training loops all
+report through it — built from three pieces:
 
 - :mod:`apex_tpu.observability.metrics` — a process-local registry of
   counters, gauges and histogram/quantile summaries, tagged with the

@@ -8,11 +8,10 @@ host wall time between two ``time.perf_counter()`` calls measures
   by materializing one scalar of it through numpy: a device-to-host
   read cannot return before the value exists.  :class:`StepTimer`
   times through it so every row is fenced the same way.
-- :class:`StepTimer` — the steady-state step-timing protocol shared by
-  ``bench.py`` and ``tools/step_breakdown.py``: warmup calls each
-  fenced (absorbing compilation), then ``iters`` back-to-back
-  dispatches with ONE trailing fence, so queue drain amortizes across
-  the timed iterations.
+- :class:`StepTimer` — the steady-state step-timing protocol of
+  ``bench.py``: warmup calls each fenced (absorbing compilation), then
+  ``iters`` back-to-back dispatches with ONE trailing fence, so queue
+  drain amortizes across the timed iterations.
 
 :func:`span` measures host wall time (enter → exit) and is the right
 tool for host-side phases (data loading, a whole train step including
@@ -20,7 +19,8 @@ its host work, a measurement-campaign stage); pass ``fence_on=`` to
 fence a device value at exit when the span closes over async device
 work.  Never use spans *inside* a jit body — they would measure
 trace-time only; record step-boundary values instead
-(``metrics.record_step_metrics``).
+(``metrics.record_step_metrics``); inside a jitted program the span is
+``jax.named_scope``, read by ``benchmark/scope_times.py``.
 """
 
 from __future__ import annotations
@@ -138,9 +138,8 @@ class StepTimer:
       returned tuple's LAST element is fenced (by convention the loss).
       Warmup iterations are fenced individually; the timed iterations
       dispatch back-to-back with one trailing fence.
-    - :meth:`time_call` — fixed-args protocol
-      (``tools/step_breakdown.py``): ``fn(*args)`` repeatedly; the
-      whole output's first leaf is fenced.
+    - :meth:`time_call` — fixed-args protocol: ``fn(*args)``
+      repeatedly; the whole output's first leaf is fenced.
 
     Both return mean seconds per timed iteration, keep the last output
     on ``self.last`` (donating steps thread state through the loop),

@@ -349,21 +349,22 @@ def _fwd_pallas(q3, k3, v3, kpm, seg, seed, scale, causal, sk_real,
         out_struct((bh, sqp, d), out_dtype or q3.dtype, q3),
         out_struct((bh, sqp, _LANES), jnp.float32, q3),
     ]
-    o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale, causal, sk_real,
-                          block_q, block_k, kpm is not None,
-                          seg is not None, dropout_p),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_fwd"):
+        o, lse = pl.pallas_call(
+            functools.partial(_fwd_kernel, scale, causal, sk_real,
+                              block_q, block_k, kpm is not None,
+                              seg is not None, dropout_p),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+            ],
+            interpret=interpret,
+        )(*args)
     return o, lse[:, :, 0]
 
 
@@ -688,20 +689,21 @@ def _bwd_pallas_fused(q3, k3, v3, do3, lse, delta, kpm, seg, seed, scale,
             memory_space=pltpu.VMEM))
         args.append(kpm)
     nkv = k3.shape[0]
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_fused_kernel, scale, causal, sq_real,
-                          sk_real, block_q, skp, kpm is not None,
-                          seg is not None, dropout_p, gqa),
-        grid=(bh, sqp // block_q),
-        in_specs=in_specs,
-        out_specs=[qspec, kspec, kspec],
-        out_shape=[out_struct((bh, sqp, d), out_dtype or q3.dtype, q3),
-                   out_struct((nkv, skp, d), out_dtype or k3.dtype, k3),
-                   out_struct((nkv, skp, d), out_dtype or v3.dtype, k3)],
-        scratch_shapes=[pltpu.VMEM((skp, d), jnp.float32),
-                        pltpu.VMEM((skp, d), jnp.float32)],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_bwd"):
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, scale, causal, sq_real,
+                              sk_real, block_q, skp, kpm is not None,
+                              seg is not None, dropout_p, gqa),
+            grid=(bh, sqp // block_q),
+            in_specs=in_specs,
+            out_specs=[qspec, kspec, kspec],
+            out_shape=[out_struct((bh, sqp, d), out_dtype or q3.dtype, q3),
+                       out_struct((nkv, skp, d), out_dtype or k3.dtype, k3),
+                       out_struct((nkv, skp, d), out_dtype or v3.dtype, k3)],
+            scratch_shapes=[pltpu.VMEM((skp, d), jnp.float32),
+                            pltpu.VMEM((skp, d), jnp.float32)],
+            interpret=interpret,
+        )(*args)
     return dq, dk, dv
 
 
@@ -758,17 +760,18 @@ def _bwd_pallas(q3, k3, v3, do3, lse, delta, kpm, seg, seed, scale,
             (1, 1, block_k), lambda b, i, j, h=heads: (b // h, 0, j),
             memory_space=pltpu.VMEM))
         args.append(kpm)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale, causal, sk_real,
-                          block_q, block_k, kpm is not None,
-                          seg is not None, dropout_p),
-        grid=(bh, sqp // block_q, skp // block_k),
-        in_specs=in_specs,
-        out_specs=qspec(qmap),
-        out_shape=out_struct((bh, sqp, d), out_dtype or q3.dtype, q3),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, scale, causal, sk_real,
+                              block_q, block_k, kpm is not None,
+                              seg is not None, dropout_p),
+            grid=(bh, sqp // block_q, skp // block_k),
+            in_specs=in_specs,
+            out_specs=qspec(qmap),
+            out_shape=out_struct((bh, sqp, d), out_dtype or q3.dtype, q3),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            interpret=interpret,
+        )(*args)
 
     # --- dk/dv ---------------------------------------------------------
     # Classic: grid (bh, kv, q), one q-head per dk/dv row.  GQA: grid
@@ -816,19 +819,20 @@ def _bwd_pallas(q3, k3, v3, do3, lse, delta, kpm, seg, seed, scale,
             (1, 1, block_k), kpm_map, memory_space=pltpu.VMEM))
         args.append(kpm)
     nkv = k3.shape[0]
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale, causal, sq_real,
-                          sk_real, block_q, block_k, kpm is not None,
-                          seg is not None, dropout_p, gqa),
-        grid=grid2,
-        in_specs=in_specs,
-        out_specs=[kspec(kmap2), kspec(kmap2)],
-        out_shape=[out_struct((nkv, skp, d), out_dtype or k3.dtype, k3),
-                   out_struct((nkv, skp, d), out_dtype or v3.dtype, k3)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, scale, causal, sq_real,
+                              sk_real, block_q, block_k, kpm is not None,
+                              seg is not None, dropout_p, gqa),
+            grid=grid2,
+            in_specs=in_specs,
+            out_specs=[kspec(kmap2), kspec(kmap2)],
+            out_shape=[out_struct((nkv, skp, d), out_dtype or k3.dtype, k3),
+                       out_struct((nkv, skp, d), out_dtype or v3.dtype, k3)],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)],
+            interpret=interpret,
+        )(*args)
     return dq, dk, dv
 
 

@@ -304,9 +304,10 @@ def _norm(x, weight, bias, eps, rms, memory_efficient):
         rows *= d
     hidden = x.shape[-1]
     if _pallas_ok(hidden, x.dtype):
-        y, _, _ = _ln_fwd_pallas(
-            x.reshape(rows, hidden), weight, bias, eps, rms
-        )
+        with jax.named_scope("layer_norm_fwd"):
+            y, _, _ = _ln_fwd_pallas(
+                x.reshape(rows, hidden), weight, bias, eps, rms
+            )
         return y.reshape(x.shape)
     if rms:
         return rms_norm_ref(x, weight, eps)
@@ -319,7 +320,8 @@ def _norm_fwd(x, weight, bias, eps, rms, memory_efficient):
     rows = x.size // hidden
     x2 = x.reshape(rows, hidden)
     if _pallas_ok(hidden, x.dtype):
-        y2, mu, rs = _ln_fwd_pallas(x2, weight, bias, eps, rms)
+        with jax.named_scope("layer_norm_fwd"):
+            y2, mu, rs = _ln_fwd_pallas(x2, weight, bias, eps, rms)
     else:
         x32 = x2.astype(jnp.float32)
         if rms:
@@ -391,9 +393,10 @@ def _norm_bwd(eps, rms, memory_efficient, res, dy):
 
     bwd_mode = _ln_bwd_mode(hidden, x2.dtype)
     if bwd_mode is not None:
-        dx, dw, db = _ln_bwd_pallas(
-            dy2, x2, weight, mu, rs, rms, bias is not None
-        )
+        with jax.named_scope("layer_norm_bwd"):
+            dx, dw, db = _ln_bwd_pallas(
+                dy2, x2, weight, mu, rs, rms, bias is not None
+            )
     else:
         dy32 = dy2.astype(jnp.float32)
         x32 = x2.astype(jnp.float32)

@@ -108,11 +108,12 @@ def fused_lamb(
             u = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
             if adam_w_mode and weight_decay != 0.0:
                 u = u + weight_decay * p32
-            w_norm = jnp.sqrt(jnp.sum(jnp.square(p32)))
-            u_norm = jnp.sqrt(jnp.sum(jnp.square(u)))
-            ratio = jnp.where(
-                (w_norm > 0) & (u_norm > 0), w_norm / u_norm, 1.0
-            )
+            with jax.named_scope("trust_ratio"):
+                w_norm = jnp.sqrt(jnp.sum(jnp.square(p32)))
+                u_norm = jnp.sqrt(jnp.sum(jnp.square(u)))
+                ratio = jnp.where(
+                    (w_norm > 0) & (u_norm > 0), w_norm / u_norm, 1.0
+                )
             if weight_decay == 0.0 and not use_nvlamb:
                 ratio = jnp.asarray(1.0, jnp.float32)
             return -lr_t * ratio * u

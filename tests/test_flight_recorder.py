@@ -435,21 +435,20 @@ class TestFlightRecorder:
 
     def test_crash_excepthook_dumps(self, tmp_path):
         dump_path = tmp_path / "f.json"
+        prev_hook = sys.excepthook
         obs.configure(flight_recorder=str(dump_path))
         obs.record_step_metrics({"loss": 2.5, "step": 7})
-        prev_hook = sys.excepthook
+        assert sys.excepthook is not prev_hook
         try:
             sys.excepthook(RuntimeError, RuntimeError("boom"), None)
+            doc = json.load(open(dump_path))
+            assert doc["reason"] == "crash"
+            assert doc["error"] == "RuntimeError: boom"
+            assert doc["steps"][-1]["loss"] == 2.5
         finally:
-            sys.excepthook = prev_hook
-        doc = json.load(open(dump_path))
-        assert doc["reason"] == "crash"
-        assert doc["error"] == "RuntimeError: boom"
-        assert doc["steps"][-1]["loss"] == 2.5
-        obs.shutdown()
-        # shutdown restores the hook it installed
-        assert sys.excepthook is prev_hook or not hasattr(
-            sys.excepthook, "__self__")
+            obs.shutdown()
+        # shutdown restores the hook that configure() replaced
+        assert sys.excepthook is prev_hook
 
     def test_shutdown_preserves_the_incident_dump(self, tmp_path):
         """The anomaly-time dump brackets the incident; a run that

@@ -1,0 +1,110 @@
+"""The jitted train step carries the program's ``jax.named_scope`` words on
+every instruction that does work (docs/observability.md, "Scopes inside the
+jitted step"): ``benchmark/scope_times.py`` splits a chip trace's device time
+by them, so a matmul or kernel that loses its scope goes dark there.
+
+Toy sizes on the CPU with the kernels interpreted, so that the Pallas routes
+(and their scopes) are taken.  The compiled text is read, not the lowered
+one: a scanned or called body's ``op_name`` gets its caller's prefix
+(``jvp(model)/backbone/while/body/...``) only once the call is inlined.
+"""
+
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from apex_tpu.models.bert import make_bert_train_step
+from apex_tpu.models.config import bert_large, gpt_125m
+from apex_tpu.models.gpt import make_gpt_train_step
+from apex_tpu.optimizers import fused_adam, fused_lamb
+
+TOY = dict(num_layers=2, hidden_size=128, num_attention_heads=4,
+           vocab_size=512, max_position_embeddings=64)
+B, S = 2, 64
+
+LAYER = {"ln1", "attention", "qkv", "core_attention", "proj", "ln2", "mlp",
+         "fc1", "fc2"}
+# forward and backward of the same scope; ``residual`` is an add, whose
+# transpose is no instruction
+BOTH_WAYS = LAYER | {"embed", "final_ln", "cast_params"}
+STEP = {"amp_unscale", "amp_scale_update", "optimizer", "apply_update",
+        "cast_params"}
+KERNELS_FWD = {"flash_fwd", "layer_norm_fwd"}
+KERNELS_BWD = {"flash_bwd", "layer_norm_bwd"}      # toy s64: fused backward
+# what the backward needs again of a rematted layer (fc2's output and the
+# projection's are not among it, their inputs are)
+RECOMPUTED = {"ln1", "qkv", "core_attention", "ln2", "fc1"} | KERNELS_FWD
+ALL = (BOTH_WAYS | STEP | KERNELS_FWD | KERNELS_BWD
+       | {"residual", "lm_head_ce", "embedding_ln", "mlm_head", "nsp_head",
+          "trust_ratio", "flash_bwd_dq", "flash_bwd_dkv", "grad_reduce"})
+
+
+def _gpt():
+    cfg = gpt_125m(**TOY, activation="gelu_tanh", fused_head_ce=True,
+                   remat=True, scan_layers=True)
+    init, step = make_gpt_train_step(cfg, fused_adam(lr=1e-4), "O2")
+    ids = np.zeros((B, S), np.int32)
+    return init, step, (ids, ids), {"lm_head_ce"}, set(), True
+
+
+def _bert():
+    cfg = bert_large(**TOY, remat=False, scan_layers=False)
+    init, step = make_bert_train_step(
+        cfg, fused_lamb(lr=1e-4, weight_decay=0.01), "O2")
+    ids = np.zeros((B, S), np.int32)
+    batch = (ids, ids, np.zeros((B,), np.int32), ids, np.ones_like(ids))
+    return (init, step, batch, {"embedding_ln", "mlm_head", "nsp_head"},
+            {"trust_ratio"}, False)
+
+
+def _words(op_name: str) -> set:
+    """The scope words of an ``op_name``: its parts less the transforms
+    around them (``transpose(jvp(model))`` -> ``model``)."""
+    return {re.sub(r"^(?:\w+\()+|\)+$", "", part)
+            for part in op_name.split("/")}
+
+
+@pytest.mark.parametrize("build", [_gpt, _bert],
+                         ids=["gpt_scan_remat", "bert_unrolled"])
+def test_every_part_of_the_step_is_scoped(build, monkeypatch):
+    monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
+    init, step, batch, heads, optimizer_words, remat = build()
+    state = jax.eval_shape(init, jax.random.key_data(jax.random.key(0)))
+    text = step.lower(state, *batch).compile().as_text()
+
+    seen = {"forward": set(), "recompute": set(), "backward": set(),
+            "update": set()}
+    unscoped_work = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? ([\w\-]+)\(", line)
+        if not m:
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        op_name = op.group(1) if op else ""
+        words = _words(op_name) & ALL
+        if "rematted_computation" in op_name:
+            seen["recompute"] |= words
+        elif "transpose(" in op_name:
+            seen["backward"] |= words
+        elif "jvp(" in op_name:
+            seen["forward"] |= words
+        else:
+            seen["update"] |= words
+        if m.group(2) in ("dot", "convolution", "custom-call") and not words:
+            unscoped_work.append(line.strip()[:200])
+
+    # (a) forward, backward and, with remat, recomputed forward
+    assert BOTH_WAYS | heads | KERNELS_FWD | {"residual"} <= seen["forward"]
+    assert BOTH_WAYS | heads | KERNELS_BWD <= seen["backward"]
+    if remat:
+        assert RECOMPUTED <= seen["recompute"]
+    else:
+        assert not seen["recompute"]
+    # (b) no matmul or kernel without a word of the program's
+    assert not unscoped_work
+    # (c) the step's own phases, outside the differentiated function
+    assert STEP - {"cast_params"} | optimizer_words <= seen["update"]
+    assert "cast_params" in seen["update"]
+    assert not (STEP - {"cast_params"}) & (seen["forward"] | seen["backward"])

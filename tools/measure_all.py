@@ -17,8 +17,7 @@ workload matrix) and its ``--decode`` ablations (``--cache-layout``,
 topology rows (``--serve-trace``, ``--serve-trace --controller``,
 ``--cold-start``); ``--tp-overlap``, ``--moe``, ``--ckpt`` with their
 dryrun parity phases; ``tests/test_on_tpu_kernels.py`` on the chip;
-``tools/sweep_r5.py``, ``tools/sweep_r4.py``, ``bench_kernels.py``,
-``tools/step_breakdown.py --model resnet50``; and a final
+``tools/sweep_r5.py``, ``tools/sweep_r4.py``, ``bench_kernels.py``; and a final
 ``aggregate_telemetry`` merge into ``measure_logs/fleet_aggregate.json``.
 """
 
@@ -296,9 +295,6 @@ def main():
     results["bench_kernels"] = _run(
         "bench_kernels", [sys.executable, "bench_kernels.py", "--json",
                           "KERNEL_BENCH.json"])
-    results["rn50_breakdown"] = _run(
-        "rn50_breakdown", [sys.executable, "tools/step_breakdown.py",
-                           "--model", "resnet50"])
 
     print("\n[measure_all] stage results:", json.dumps(results))
     sweep_path = os.path.join(ROOT, "SWEEP_r5.json")
