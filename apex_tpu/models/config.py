@@ -81,7 +81,10 @@ class TransformerConfig:
     compute_dtype: jnp.dtype = jnp.bfloat16
     softmax_in_fp32: bool = True
     attention_backend: str = "flash"              # 'flash' | 'fused_softmax'
-    remat: bool = False                           # jax.checkpoint each layer
+    # jax.checkpoint each layer; all of it is recomputed in the backward
+    # pass except flash attention's output and logsumexp, which are kept
+    # (17.3 MB a layer at b8 x s1024 x 1024 against a second kernel run)
+    remat: bool = False
     scan_layers: bool = True                      # lax.scan over the stack
     # fuse the LM-head matmul into the CE loss, chunked over tokens, so
     # the [tokens, vocab] logits never hit HBM (ops/lm_head_ce.py);

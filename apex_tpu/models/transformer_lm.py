@@ -48,6 +48,7 @@ from apex_tpu.ops import (
     softmax_cross_entropy_loss,
 )
 from apex_tpu.ops.dense import is_quantized as _is_quantized
+from apex_tpu.ops.flash_attention import REMAT_SAVED_NAMES
 from apex_tpu.ops.swiglu import fused_bias_swiglu_paired
 from apex_tpu.transformer.tensor_parallel.mappings import (
     copy_to_tensor_model_parallel_region,
@@ -893,7 +894,12 @@ def transformer_backbone(params: dict, hidden, cfg: TransformerConfig,
         x, aux = _layer(cfg, lp, x, ctx, attention_mask, rope, rngs)
         return (x, aux_acc + aux), None
 
-    step = jax.checkpoint(body) if cfg.remat else body
+    # a rematted layer recomputes everything but the flash kernel's
+    # output and logsumexp: keeping them (one more x-sized residual a
+    # layer) takes the forward kernel out of the backward pass
+    step = jax.checkpoint(
+        body, policy=jax.checkpoint_policies.save_only_these_names(
+            *REMAT_SAVED_NAMES)) if cfg.remat else body
 
     needs_rng = dropout_rng is not None and (
         cfg.hidden_dropout > 0 or cfg.attention_dropout > 0

@@ -34,8 +34,11 @@ STEP = {"amp_unscale", "amp_scale_update", "optimizer", "apply_update",
 KERNELS_FWD = {"flash_fwd", "layer_norm_fwd"}
 KERNELS_BWD = {"flash_bwd", "layer_norm_bwd"}      # toy s64: fused backward
 # what the backward needs again of a rematted layer (fc2's output and the
-# projection's are not among it, their inputs are)
-RECOMPUTED = {"ln1", "qkv", "core_attention", "ln2", "fc1"} | KERNELS_FWD
+# projection's are not among it, their inputs are).  Not the flash kernel:
+# the layer's checkpoint keeps its output and logsumexp, so neither the
+# kernel nor the layout copies that fed it run a second time
+RECOMPUTED = {"ln1", "qkv", "ln2", "fc1", "layer_norm_fwd"}
+NOT_RECOMPUTED = {"flash_fwd", "core_attention"}
 ALL = (BOTH_WAYS | STEP | KERNELS_FWD | KERNELS_BWD
        | {"residual", "lm_head_ce", "embedding_ln", "mlm_head", "nsp_head",
           "trust_ratio", "flash_bwd_dq", "flash_bwd_dkv", "grad_reduce"})
@@ -100,6 +103,7 @@ def test_every_part_of_the_step_is_scoped(build, monkeypatch):
     assert BOTH_WAYS | heads | KERNELS_BWD <= seen["backward"]
     if remat:
         assert RECOMPUTED <= seen["recompute"]
+        assert not NOT_RECOMPUTED & seen["recompute"]
     else:
         assert not seen["recompute"]
     # (b) no matmul or kernel without a word of the program's

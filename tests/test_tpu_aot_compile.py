@@ -189,3 +189,30 @@ def test_ddp_step_compiles_for_four_chips(topo, as_tpu):
     compiled = jax.jit(step).lower(state, batch, batch).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+def test_rematted_layer_runs_the_forward_flash_kernel_once(one_chip, as_tpu):
+    """The benchmark's GPT cell (hidden 1024, 16 heads, b8 x s1024, remat
+    + scan) cut to 2 layers: the layer's checkpoint keeps the forward
+    kernel's output and logsumexp, so the backward loop holds the two
+    backward kernels and no second forward kernel.  Only the chip's
+    compiler can say what XLA and Mosaic make of the names."""
+    from apex_tpu.models.gpt import make_gpt_train_step
+    from apex_tpu.optimizers import fused_adam
+
+    cfg = gpt_125m(num_layers=2, hidden_size=1024, num_attention_heads=16,
+                   max_position_embeddings=1024, activation="gelu_tanh",
+                   fused_head_ce=True, remat=True, scan_layers=True)
+    init, step = make_gpt_train_step(cfg, fused_adam(lr=1e-4), "O2")
+    state = _like(jax.eval_shape(
+        init, jax.random.key_data(jax.random.key(0))), one_chip)
+    ids = _spec((8, 1024), jnp.int32, one_chip)
+    text = step.lower(state, ids, ids).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+
+    def count(scope):
+        return sum(f"/{scope}/" in line for line in kernels)
+
+    assert count("flash_fwd") == 1
+    assert count("flash_bwd_dq") == 1 and count("flash_bwd_dkv") == 1
