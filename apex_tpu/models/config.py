@@ -14,7 +14,10 @@ from typing import Optional
 
 import jax.numpy as jnp
 
-__all__ = ["TransformerConfig", "gpt_tiny", "gpt_125m", "bert_large"]
+__all__ = ["TransformerConfig", "gpt_tiny", "gpt_125m", "bert_large",
+           "lfm2_moe"]
+
+LAYER_KINDS = ("attention", "conv")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +71,30 @@ class TransformerConfig:
     # | 'int8' — the grad_comm= surface applied to expert all-to-alls)
     moe_comm: str = "fp32"
 
+    # 'softmax' = Switch probabilities; 'sigmoid' = float32 sigmoid
+    # scores, a selection-only bias, gates normalised over the chosen
+    # (ragged routing, no auxiliary loss)
+    moe_router: str = "softmax"
+    # (first, count): the expert slabs hold these of the router's
+    # num_experts — one chip's share of an expert-parallel layer, run
+    # without its exchange.  None = all experts are held
+    moe_experts_held: Optional[tuple] = None
+
+    # stacks whose layers differ in kind (models/hybrid.py): one of
+    # LAYER_KINDS per layer ('conv' = gated short convolution in place
+    # of attention); None = attention everywhere, the homogeneous stack
+    layer_types: Optional[tuple] = None
+    conv_kernel_size: int = 3
+    # with num_experts set, the first num_dense_layers layers keep a
+    # dense FFN of width dense_ffn_hidden_size
+    num_dense_layers: int = 0
+    dense_ffn_hidden_size: Optional[int] = None
+    rope_theta: float = 10000.0
+    # RMSNorm over each head's channels of q and k, before rope
+    qk_norm: bool = False
+    # False: no bias leaf on any projection (hybrid stacks only)
+    use_bias: bool = True
+
     # regularization
     hidden_dropout: float = 0.0
     attention_dropout: float = 0.0
@@ -118,6 +145,43 @@ class TransformerConfig:
             raise ValueError(
                 f"moe_comm ({self.moe_comm!r}) must be 'fp32', 'bf16' "
                 "or 'int8'")
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_router ({self.moe_router!r}) must be 'softmax' or "
+                "'sigmoid'")
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)      # a JSON list is no key
+            object.__setattr__(self, "layer_types", kinds)
+            if len(kinds) != self.num_layers or set(kinds) - set(
+                    LAYER_KINDS):
+                raise ValueError(
+                    f"layer_types must name one of {LAYER_KINDS} for "
+                    f"each of the {self.num_layers} layers, got {kinds}")
+        if self.moe_experts_held is not None:
+            first, count = (int(v) for v in self.moe_experts_held)
+            object.__setattr__(self, "moe_experts_held", (first, count))
+            if (not self.num_experts or first < 0 or count < 1
+                    or first + count > self.num_experts):
+                raise ValueError(
+                    f"moe_experts_held {(first, count)} is no range of "
+                    f"the {self.num_experts} experts")
+        if self.num_dense_layers and not (
+                self.num_experts and self.dense_ffn_hidden_size):
+            raise ValueError(
+                "num_dense_layers needs num_experts (the layers after "
+                "them) and dense_ffn_hidden_size")
+        if self.is_hybrid and (
+                self.moe_routing != "ragged" and self.num_experts):
+            raise ValueError("a hybrid stack's experts need "
+                             "moe_routing='ragged'")
+        if not self.is_hybrid and (
+                self.moe_router != "softmax" or self.qk_norm
+                or self.moe_experts_held is not None
+                or not self.use_bias):
+            raise ValueError(
+                "moe_router='sigmoid', moe_experts_held, qk_norm and "
+                "use_bias=False belong to the hybrid stack: set "
+                "layer_types")
         if self.num_query_groups is not None:
             if (self.num_query_groups < 1
                     or self.num_attention_heads % self.num_query_groups):
@@ -125,6 +189,19 @@ class TransformerConfig:
                     f"num_query_groups ({self.num_query_groups}) must "
                     f"be a positive divisor of num_attention_heads "
                     f"({self.num_attention_heads})")
+
+    @property
+    def is_hybrid(self) -> bool:
+        """True when the layers differ (kind of operator, or dense FFN
+        before expert layers): the stack is built layer by layer
+        (models/hybrid.py) instead of one scanned ``[L, ...]`` tree."""
+        return self.layer_types is not None or self.num_dense_layers > 0
+
+    @property
+    def held_experts(self) -> tuple:
+        """``(first, count)`` of the experts whose weights are held: all
+        of them unless ``moe_experts_held`` says which."""
+        return self.moe_experts_held or (0, self.num_experts or 0)
 
     @property
     def projection_size(self) -> int:
@@ -183,3 +260,43 @@ def bert_large(**kw) -> TransformerConfig:
     kw.setdefault("max_position_embeddings", 512)
     kw.setdefault("attn_mask_type", "padding")    # bidirectional encoder
     return TransformerConfig(**kw)
+
+
+def lfm2_moe(*, hidden_size: int, num_hidden_layers: int, layer_types,
+             num_attention_heads: int, num_key_value_heads: int,
+             intermediate_size: int, moe_intermediate_size: int,
+             num_dense_layers: int, num_experts: int,
+             num_experts_per_tok: int, vocab_size: int,
+             conv_L_cache: int = 3, norm_eps: float = 1e-5,
+             rope_theta: float = 1e6, max_position_embeddings: int = 128000,
+             experts_held=None, **kw) -> TransformerConfig:
+    """The LFM2 mixture-of-experts family (``model_type`` ``lfm2_moe``)
+    from its published ``config.json`` keys: gated short-convolution and
+    grouped-query attention layers by ``layer_types``, RMSNorm, q/k norm,
+    rope, bias-free projections, ``num_dense_layers`` SwiGLU layers and
+    then sigmoid-routed experts (selection bias, gates normalised over
+    the chosen, no auxiliary loss).  ``num_experts`` is the router's
+    width; ``experts_held=(first, count)`` makes the expert layers one
+    chip's share of an expert-parallel deployment.  Further keywords go
+    to :class:`TransformerConfig` (``remat``, ``fused_head_ce`` …)."""
+    kinds = tuple("attention" if k == "full_attention" else k
+                  for k in layer_types)
+    kw.setdefault("moe_aux_loss_coeff", 0.0)
+    return TransformerConfig(
+        num_layers=num_hidden_layers, hidden_size=hidden_size,
+        num_attention_heads=num_attention_heads,
+        num_query_groups=num_key_value_heads,
+        ffn_hidden_size=moe_intermediate_size,
+        dense_ffn_hidden_size=intermediate_size,
+        num_dense_layers=num_dense_layers, num_experts=num_experts,
+        moe_top_k=num_experts_per_tok, moe_routing="ragged",
+        moe_router="sigmoid",
+        moe_experts_held=(tuple(experts_held) if experts_held is not None
+                          else None),
+        layer_types=kinds, conv_kernel_size=conv_L_cache,
+        vocab_size=vocab_size,
+        max_position_embeddings=max_position_embeddings,
+        activation="swiglu", normalization="rmsnorm",
+        position_embedding_type="rope", rope_theta=float(rope_theta),
+        qk_norm=True, use_bias=False, layernorm_epsilon=norm_eps,
+        scan_layers=False, **kw)

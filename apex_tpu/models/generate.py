@@ -622,7 +622,7 @@ def decode_step(params: dict, token: jax.Array, cache: dict,
             max_pos = cache["block_tables"].shape[1] * cache["k"].shape[2]
         else:
             max_pos = cache["k"].shape[2]
-        rope = rope_cos_sin(max_pos, cfg.kv_channels)
+        rope = rope_cos_sin(max_pos, cfg.kv_channels, cfg.rope_theta)
 
     # one compiled layer body scanned over the stacked layer params
     # (transformer_backbone's shape — compile time constant in depth).
@@ -816,7 +816,7 @@ def decode_verify(params: dict, tokens: jax.Array, cache: dict,
             max_pos = cache["block_tables"].shape[1] * cache["k"].shape[2]
         else:
             max_pos = cache["k"].shape[2]
-        rope = rope_cos_sin(max_pos, cfg.kv_channels)
+        rope = rope_cos_sin(max_pos, cfg.kv_channels, cfg.rope_theta)
 
     slabs, plan = _lora_operands(lora, m=m)
     quant = "k_scale" in cache
@@ -951,7 +951,7 @@ def prefill(
         x = x + params["embedding"]["position"][:s].astype(cd)[None]
     rope = None
     if cfg.position_embedding_type == "rope":
-        rope = rope_cos_sin(s, cfg.kv_channels)
+        rope = rope_cos_sin(s, cfg.kv_channels, cfg.rope_theta)
 
     quant = "k_scale" in cache
 

@@ -143,19 +143,37 @@ def make_gpt_train_step(
                    or cfg.drop_path_rate > 0)
     has_mask = cfg.attn_mask_type == "padding"
 
+    # a hybrid stack's expert layers count their assignments
+    # (models/hybrid.py MOE_COUNTERS); the counters come out beside
+    # ``loss`` and ``overflow`` in the step's metrics
+    counted = cfg.is_hybrid and bool(cfg.num_experts)
+    if cfg.is_hybrid and mesh is not None:
+        raise ValueError(
+            "a hybrid stack (cfg.layer_types / num_dense_layers) has no "
+            "GSPMD partitioning; run it on one device")
+
     def loss_fn(params, tokens, labels, *rest):
         rest = list(rest)
         mask = rest.pop(0) if has_mask else None
         rng = rest.pop(0) if has_dropout else None
         return gpt_loss(params, tokens, labels, cfg, ctx,
-                        attention_mask=mask, dropout_rng=rng)
+                        attention_mask=mask, dropout_rng=rng,
+                        with_counters=counted)
 
     init_fn, step_fn = make_train_step(
         loss_fn, optimizer, policy_or_amp,
         grad_postprocess=grad_postprocess,
         norm_telemetry=norm_telemetry,
         overlap_comm=overlap_comm,
+        **({"has_aux": True} if counted else {}),
     )
+    if counted:
+        amp_step = step_fn
+
+        def step_fn(state, *batch):   # noqa: F811
+            state, metrics = amp_step(state, *batch)
+            metrics.update(metrics.pop("aux"))
+            return state, metrics
 
     def init(rng):
         params = init_gpt_params(rng, cfg)

@@ -255,8 +255,14 @@ def init_gpt_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     Init follows the reference: N(0, std) everywhere
     (standalone_transformer_lm.py:146 ``init_method_normal``), with output
     projections scaled by 1/sqrt(2L) (:155 ``scaled_init_method_normal``).
-    Layers are stacked on a leading ``num_layers`` axis.
+    Layers are stacked on a leading ``num_layers`` axis; a stack whose
+    layers differ (``cfg.is_hybrid``) is a list of per-layer trees
+    (models/hybrid.py).
     """
+    if cfg.is_hybrid:
+        from apex_tpu.models.hybrid import init_hybrid_params
+
+        return init_hybrid_params(rng, cfg)
     h, L = cfg.hidden_size, cfg.num_layers
     p = cfg.projection_size
     f = cfg.ffn_hidden_size
@@ -339,6 +345,10 @@ def gpt_param_specs(cfg: TransformerConfig, *, tp_axis: str = "tp",
     vocab rows over tp (layers.py:167), qkv/fc1 columns over tp (:429),
     proj/fc2 rows over tp (:613).
     """
+    if cfg.is_hybrid:
+        raise NotImplementedError(
+            "a hybrid stack (cfg.layer_types / num_dense_layers) runs on "
+            "one device or data parallel; it has no tp/pp partitioning")
     t = tp_axis
     pp = (pp_axis,) if pp_axis else ()
     swiglu = cfg.activation == "swiglu"
@@ -646,9 +656,11 @@ def _attention(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
                     f"over the manual tp={ctx.tp} context")
             from apex_tpu.ops.dense import quantized_matmul
 
-            qkv = quantized_matmul(xi, wq) + lp["qkv_bias"].astype(x.dtype)
+            qkv = quantized_matmul(xi, wq)
         else:
-            qkv = xi @ wq.astype(x.dtype) + lp["qkv_bias"].astype(x.dtype)
+            qkv = xi @ wq.astype(x.dtype)
+        if "qkv_bias" in lp:
+            qkv = qkv + lp["qkv_bias"].astype(x.dtype)
         qkv = ctx.constrain_col(qkv)
         if cfg.is_gqa:
             # group-major layout (per group [q x rep | k | v]): a contiguous
@@ -667,7 +679,11 @@ def _attention(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
         else:
             qkv = qkv.reshape(b, s, nh, -1)
             q, k, v = jnp.split(qkv, 3, axis=-1)
-        if rope is not None:
+        if cfg.qk_norm:
+            from apex_tpu.models.hybrid import qk_norm_rope
+
+            q, k = qk_norm_rope(cfg, lp, q, k, rope)
+        elif rope is not None:
             cos, sin = rope
             q = _apply_rope(q, cos, sin)
             k = _apply_rope(k, cos, sin)
@@ -696,7 +712,8 @@ def _attention(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
             out = ctx.reduce_out(quantized_matmul(ctxv, wp))
         else:
             out = _row_parallel_out(ctx, ctxv, wp.astype(x.dtype))
-        out = out + lp["proj_bias"].astype(x.dtype)
+        if "proj_bias" in lp:
+            out = out + lp["proj_bias"].astype(x.dtype)
     return (out, k, v) if return_kv else out
 
 
@@ -710,7 +727,7 @@ def _row_parallel_out(ctx: TPContext, x, w):
     return ctx.reduce_out(x @ w)
 
 
-def _moe_mlp(cfg: TransformerConfig, lp: dict, x):
+def _moe_mlp(cfg: TransformerConfig, lp: dict, x, with_load: bool = False):
     """MoE FFN (transformer/moe.py) in place of the dense MLP when
     ``cfg.num_experts`` is set; returns (out, aux_loss).  Experts shard
     over the 'ep' mesh axis — via GSPMD annotations on the capacity
@@ -722,13 +739,10 @@ def _moe_mlp(cfg: TransformerConfig, lp: dict, x):
     block)."""
     from apex_tpu.transformer.moe import switch_moe_mlp
 
-    moe_params = {
-        "router": lp["router_kernel"],
-        "fc1": lp["moe_fc1"],
-        "fc1_bias": lp["moe_fc1_bias"],
-        "fc2": lp["moe_fc2"],
-        "fc2_bias": lp["moe_fc2_bias"],
-    }
+    names = {"router": "router_kernel", "router_bias": "router_bias",
+             "fc1": "moe_fc1", "fc1_bias": "moe_fc1_bias",
+             "fc2": "moe_fc2", "fc2_bias": "moe_fc2_bias"}
+    moe_params = {k: lp[v] for k, v in names.items() if v in lp}
     o = switch_moe_mlp(
         moe_params, x,
         capacity_factor=cfg.moe_capacity_factor,
@@ -736,8 +750,11 @@ def _moe_mlp(cfg: TransformerConfig, lp: dict, x):
         ep_axis=cfg.moe_ep_axis,
         activation=cfg.activation,
         routing=cfg.moe_routing,
-        moe_comm=cfg.moe_comm)
-    return o.out, o.aux_loss
+        moe_comm=cfg.moe_comm,
+        router=cfg.moe_router,
+        experts_held=cfg.moe_experts_held)
+    return (o.out, o.aux_loss, o.expert_load) if with_load else (
+        o.out, o.aux_loss)
 
 
 def _mlp(cfg: TransformerConfig, lp: dict, x, ctx: TPContext):
@@ -762,7 +779,9 @@ def _mlp(cfg: TransformerConfig, lp: dict, x, ctx: TPContext):
                 # (gate, up) pair, matching the single-device layout exactly
                 y = jnp.einsum("bsh,hcf->bscf", xi, w1.astype(x.dtype))
             y = ctx.constrain_col(y)
-            y = fused_bias_swiglu_paired(y, lp["fc1_bias"].astype(x.dtype))
+            b1 = lp.get("fc1_bias")
+            y = fused_bias_swiglu_paired(
+                y, None if b1 is None else b1.astype(x.dtype))
         else:
             if _is_quantized(w1):
                 from apex_tpu.ops.dense import quantized_matmul
@@ -785,7 +804,9 @@ def _mlp(cfg: TransformerConfig, lp: dict, x, ctx: TPContext):
             out = ctx.reduce_out(quantized_matmul(y, w2))
         else:
             out = _row_parallel_out(ctx, y, w2.astype(x.dtype))
-        return out + lp["fc2_bias"].astype(x.dtype)
+        if "fc2_bias" in lp:
+            out = out + lp["fc2_bias"].astype(x.dtype)
+        return out
 
 
 def _layer(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
@@ -875,15 +896,35 @@ def lm_head_logits(params: dict, hidden, cfg: TransformerConfig):
 def transformer_backbone(params: dict, hidden, cfg: TransformerConfig,
                          ctx: TPContext, *, attention_mask=None,
                          dropout_rng=None, apply_final_norm: bool = True,
-                         with_aux: bool = False):
+                         with_aux: bool = False,
+                         with_counters: bool = False):
     """The scanned decoder stack + final norm. ``hidden`` [b, s, h].
 
     ``with_aux=True`` additionally returns the summed per-layer auxiliary
-    loss (the MoE load-balance term; 0 for dense configs)."""
+    loss (the MoE load-balance term; 0 for dense configs);
+    ``with_counters=True`` (hybrid stacks) the expert layers' assignment
+    counters after it (models/hybrid.py ``moe_counters``)."""
     s = hidden.shape[1]
     rope = None
     if cfg.position_embedding_type == "rope":
-        rope = rope_cos_sin(s, cfg.kv_channels)
+        rope = rope_cos_sin(s, cfg.kv_channels, cfg.rope_theta)
+    if cfg.is_hybrid:
+        from apex_tpu.models.hybrid import hybrid_backbone
+
+        if attention_mask is not None or dropout_rng is not None:
+            raise ValueError("a hybrid stack takes no attention mask "
+                             "and no dropout")
+        hidden, counters = hybrid_backbone(params, hidden, cfg, ctx, rope)
+        if apply_final_norm:
+            with jax.named_scope("final_ln"):
+                hidden = apply_norm(cfg, hidden,
+                                    params["final_ln"]["scale"],
+                                    params["final_ln"].get("bias"))
+        out = ((hidden,) + (jnp.float32(0.0),) * with_aux
+               + (counters,) * with_counters)
+        return out if len(out) > 1 else hidden
+    if with_counters:
+        raise ValueError("only a hybrid stack counts its assignments")
 
     n_layers = jax.tree_util.tree_leaves(params["layers"])[0].shape[0]
 
@@ -930,7 +971,7 @@ def transformer_backbone(params: dict, hidden, cfg: TransformerConfig,
         return (hidden, aux) if with_aux else hidden
     with jax.named_scope("final_ln"):
         out = apply_norm(cfg, hidden, params["final_ln"]["scale"],
-                         params["final_ln"]["bias"])
+                         params["final_ln"].get("bias"))
     return (out, aux) if with_aux else out
 
 
@@ -953,30 +994,35 @@ def gpt_forward(params: dict, tokens: jax.Array, cfg: TransformerConfig,
 
 
 def gpt_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
-               ctx: TPContext, *, attention_mask=None, dropout_rng=None):
-    """Embed + decoder stack + final norm → (hidden [b,s,h], moe_aux).
-    The shared prologue of :func:`gpt_forward` and the fused head+CE
-    loss path."""
+               ctx: TPContext, *, attention_mask=None, dropout_rng=None,
+               with_counters: bool = False):
+    """Embed + decoder stack + final norm → (hidden [b,s,h], moe_aux[,
+    counters]).  The shared prologue of :func:`gpt_forward` and the fused
+    head+CE loss path."""
     h = ctx.constrain_hidden(embed_tokens(params["embedding"], tokens,
                                           cfg, ctx))
     return transformer_backbone(params, h, cfg, ctx,
                                 attention_mask=attention_mask,
-                                dropout_rng=dropout_rng, with_aux=True)
+                                dropout_rng=dropout_rng, with_aux=True,
+                                with_counters=with_counters)
 
 
 def gpt_loss(params: dict, tokens: jax.Array, labels: jax.Array,
              cfg: TransformerConfig, ctx: Optional[TPContext] = None,
-             *, attention_mask=None, dropout_rng=None) -> jax.Array:
+             *, attention_mask=None, dropout_rng=None,
+             with_counters: bool = False):
     """Mean next-token CE. Uses the fused xentropy op (GSPMD/single) or the
     vocab-parallel CE (manual TP) — reference post_language_model_processing
     (standalone_transformer_lm.py:1547 → tensor_parallel/cross_entropy.py:23).
     ``attention_mask`` (True = masked) feeds ``attn_mask_type='padding'``
-    models; causal masking needs none.
+    models; causal masking needs none.  ``with_counters=True`` (hybrid
+    stacks with experts) returns ``(loss, counters)``.
     """
     ctx = ctx or single_device_ctx()
-    h, aux = gpt_hidden(params, tokens, cfg, ctx,
-                        attention_mask=attention_mask,
-                        dropout_rng=dropout_rng)
+    h, aux, *counters = gpt_hidden(params, tokens, cfg, ctx,
+                                   attention_mask=attention_mask,
+                                   dropout_rng=dropout_rng,
+                                   with_counters=with_counters)
     with jax.named_scope("lm_head_ce"):
         if cfg.fused_head_ce and not ctx.vocab_parallel:
             # fused head+CE: chunk the vocab matmul into the loss
@@ -992,10 +1038,10 @@ def gpt_loss(params: dict, tokens: jax.Array, labels: jax.Array,
         else:
             loss = lm_cross_entropy(lm_head_logits(params, h, cfg), labels,
                                     ctx)
-    if cfg.num_experts:
+    if cfg.num_experts and cfg.moe_aux_loss_coeff:
         # Switch load-balance term, mean over layers
         loss = loss + cfg.moe_aux_loss_coeff * aux / cfg.num_layers
-    return loss
+    return (loss, counters[0]) if with_counters else loss
 
 
 def lm_cross_entropy(logits, labels, ctx: TPContext) -> jax.Array:

@@ -16,7 +16,11 @@ two ways, selected by ``routing=``:
   (``ops/grouped_matmul.py``); an inverse-permutation scatter weighted by
   the gates combines.  No token is ever dropped
   (``dropped_fraction == 0`` by construction) and no pad-to-capacity
-  slots are computed.
+  slots are computed.  ``router="sigmoid"`` swaps the softmax for float32
+  sigmoid scores with a selection-only bias and gates normalised over the
+  chosen; ``experts_held=(first, count)`` makes the layer one rank's
+  share of an expert-parallel layer, run without its exchange: it routes
+  over all experts and computes what its own give (``_ragged_local``).
 
 On a mesh with an ``ep`` axis the ragged path runs expert parallelism
 *explicitly* inside a ``jax.shard_map`` island instead of leaving the
@@ -63,6 +67,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.comm.quantize import (
@@ -78,9 +83,10 @@ from apex_tpu.ops.collective_matmul import _mesh_axis, _nbytes
 from apex_tpu.ops.grouped_matmul import grouped_matmul, group_ids
 
 __all__ = ["init_moe_params", "switch_moe_mlp", "MoEOutput",
-           "MOE_ROUTINGS"]
+           "MOE_ROUTINGS", "MOE_ROUTERS"]
 
 MOE_ROUTINGS = ("capacity", "ragged")
+MOE_ROUTERS = ("softmax", "sigmoid")
 
 
 class MoEOutput(NamedTuple):
@@ -143,19 +149,40 @@ def _router_probs(router, x2, router_noise_rng):
     return jax.nn.softmax(logits, axis=-1)
 
 
-def _topk_routing(probs, top_k):
+def _topk_routing(scores, top_k):
     """Iterative-argmax top-k (the Switch selection rule, ties and all):
-    ``(choice [..., k] int32, gates [..., k] fp32)``."""
-    e_n = probs.shape[-1]
-    remaining = probs
-    choices, gates = [], []
+    ``(choice [..., k] int32, picked [..., k] fp32)``.  A taken expert is
+    masked with ``-inf``, so scores may be negative (a sigmoid score plus
+    a selection bias) and no expert is taken twice."""
+    e_n = scores.shape[-1]
+    remaining = scores
+    choices, picked = [], []
     for _ in range(top_k):
         c = jnp.argmax(remaining, axis=-1)
-        g = jnp.take_along_axis(remaining, c[..., None], axis=-1)[..., 0]
+        hot = jax.nn.one_hot(c, e_n, dtype=jnp.bool_)
+        # the chosen score through the mask, not a gather of single
+        # elements (slow on a TPU, and its transpose is a scatter)
+        picked.append(jnp.sum(jnp.where(hot, remaining, 0.0), axis=-1))
         choices.append(c.astype(jnp.int32))
-        gates.append(g)
-        remaining = remaining * (1.0 - jax.nn.one_hot(c, e_n))
-    return jnp.stack(choices, axis=-1), jnp.stack(gates, axis=-1)
+        remaining = jnp.where(hot, -jnp.inf, remaining)
+    return jnp.stack(choices, axis=-1), jnp.stack(picked, axis=-1)
+
+
+def _sigmoid_routing(router, bias, x2, top_k, scaling=1.0):
+    """The sigmoid router: scores ``r = sigmoid(x W_g)`` in float32 (the
+    product too: ``HIGHEST``, no bf16 passes on a TPU), the ``top_k``
+    largest of ``r + bias`` chosen (the bias selects, it never weighs),
+    gates ``r_e / (sum of the chosen r + 1e-6) * scaling``.  Returns
+    ``(choice [T, k], gates [T, k], r [T, E])``."""
+    r = jax.nn.sigmoid(jnp.dot(
+        x2.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    select = r if bias is None else r + bias.astype(jnp.float32)
+    choice, _ = _topk_routing(jax.lax.stop_gradient(select), top_k)
+    hot = jax.nn.one_hot(choice, r.shape[-1], dtype=jnp.bool_)  # [T, k, E]
+    picked = jnp.sum(jnp.where(hot, r[:, None, :], 0.0), axis=-1)
+    gates = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6)
+    return choice, gates * scaling, r
 
 
 def _aux_loss(probs_mean, sel_counts, n_assignments):
@@ -243,13 +270,18 @@ def _grouped_ffn(xs, offsets, fc1, b1, fc2, b2, activation, dtype,
     Per-row biases gather through a zero-padded table so sentinel rows
     (outside the window / past the valid count) contribute nothing.
     ``fc1``/``fc2`` may be weight-only quantized slabs (ISSUE 14) —
-    see :func:`_expert_matmul`."""
+    see :func:`_expert_matmul`; ``b1``/``b2`` may be ``None`` (bias-free
+    experts)."""
     g_n = _slab_groups(fc1)
     gid = group_ids(offsets, xs.shape[0], g_n)
-    b1e = jnp.concatenate(
-        [b1, jnp.zeros((1,) + b1.shape[1:], b1.dtype)])[gid]
-    b2e = jnp.concatenate(
-        [b2, jnp.zeros((1,) + b2.shape[1:], b2.dtype)])[gid]
+
+    def rows_of(b):
+        if b is None:
+            return None
+        return jnp.concatenate(
+            [b, jnp.zeros((1,) + b.shape[1:], b.dtype)])[gid]
+
+    b1e, b2e = rows_of(b1), rows_of(b2)
     h1 = _expert_matmul(xs, fc1, offsets, dtype, backend)
     if activation == "swiglu":
         from apex_tpu.ops.swiglu import fused_bias_swiglu
@@ -258,12 +290,13 @@ def _grouped_ffn(xs, offsets, fc1, b1, fc2, b2, activation, dtype,
         # the capacity path's per-expert vmapped application
         h1 = fused_bias_swiglu(h1, b1e)
     else:
-        h1 = h1 + b1e.astype(dtype)
+        if b1e is not None:
+            h1 = h1 + b1e.astype(dtype)
         h1 = jax.nn.gelu(h1.astype(jnp.float32),
                          approximate=activation == "gelu_tanh"
                          ).astype(dtype)
     h2 = _expert_matmul(h1, fc2, offsets, dtype, backend)
-    return h2 + b2e.astype(dtype)
+    return h2 if b2e is None else h2 + b2e.astype(dtype)
 
 
 def _sorted_assignment(choice, gates, e_n):
@@ -379,24 +412,121 @@ _compressed_ring_gather.defvjp(_crg_fwd, _crg_bwd)
 # ---------------------------------------------------------------------------
 
 
-def _ragged_local(params, x2, probs, top_k, activation, gmm_backend):
-    """Single-shard ragged path: sort-by-expert, grouped FFN, inverse-
-    permutation combine.  Also the fallback under GSPMD when the
+# rows of the sorted buffer that one ``lax.cond`` covers when only some
+# experts are held (a multiple of the grouped kernels' 512-row blocks)
+_CHUNK_ROWS = 16384
+
+
+def _float0(x):
+    return np.zeros(x.shape, jax.dtypes.float0)
+
+
+def _live_chunks(rows, n, chunk, out_shape):
+    """``sum_c rows(lo_c, args, order, offsets)`` [T, h] over the chunks
+    ``lo_c = c · chunk`` of the ``n`` sorted rows that start before the
+    last held row (``offsets[-1]``); the others are skipped, forward and
+    backward.  A ``lax.scan`` of ``lax.cond``s with a hand-written VJP:
+    autodiff's transpose of a skipped ``cond`` would write a zero
+    gradient as large as the expert slabs for every skipped chunk and
+    add them all up; here the cotangents are accumulated in the scan's
+    carry by the live chunks alone (each recomputes its forward, as the
+    layer's remat would)."""
+    starts = np.arange(0, n, chunk, dtype=np.int32)    # no tracer: closed over
+
+    def scan_live(step, carry, offsets):
+        def body(carry, lo):
+            return jax.lax.cond(lo < offsets[-1],
+                                lambda c: step(c, lo), lambda c: c,
+                                carry), None
+        return jax.lax.scan(body, carry, starts)[0]
+
+    @jax.custom_vjp
+    def run(args, order, offsets):
+        return scan_live(
+            lambda out, lo: out + rows(lo, args, order, offsets),
+            jnp.zeros(out_shape, jnp.float32), offsets)
+
+    def fwd(args, order, offsets):
+        return run(args, order, offsets), (args, order, offsets)
+
+    def bwd(res, g):
+        args, order, offsets = res
+
+        def step(acc, lo):
+            pull = jax.vjp(lambda a: rows(lo, a, order, offsets), args)[1]
+            return jax.tree_util.tree_map(jnp.add, acc, pull(g)[0])
+
+        zeros = jax.tree_util.tree_map(jnp.zeros_like, args)
+        return (scan_live(step, zeros, offsets), _float0(order),
+                _float0(offsets))
+
+    run.defvjp(fwd, bwd)
+    return run
+
+
+def _ragged_local(params, x2, choice, gates, activation, gmm_backend,
+                  held=None):
+    """Single-shard ragged path: sort-by-expert, grouped FFN, scatter-add
+    combine weighted by the gates.  Also the fallback under GSPMD when the
     explicit island does not apply (the partitioner then gathers the
-    expert weights — correct, just not expert-parallel)."""
+    expert weights — correct, just not expert-parallel).
+
+    ``held = (first, count)``: the expert slabs hold experts ``first ..
+    first + count`` of the router's ``E`` only (one rank's share of an
+    expert-parallel layer).  Tokens are routed over all ``E``; the
+    assignments on held experts sort to the front of the worst-case
+    ``T·k`` buffer, and what the absent experts would have added is left
+    out of the result.  No token is dropped whatever the imbalance, but
+    the work follows the rows that are held: the buffer goes through in
+    chunks of ``_CHUNK_ROWS`` sorted rows (:func:`_live_chunks`), a chunk
+    that starts past the last held row is skipped whole (gather, grouped
+    products, activation, scatter), and inside a chunk the grouped
+    products cover the held rows alone.  Returns ``(out [T, h], load
+    [E])``."""
     e_n = params["router"].shape[-1]
     t_n, h = x2.shape
-    choice, gates = _topk_routing(probs, top_k)
-    order, counts, tok, gate_s, _ = _sorted_assignment(choice, gates, e_n)
-    offsets = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
-    xs = x2[tok]
-    h2 = _grouped_ffn(xs, offsets, params["fc1"], params["fc1_bias"],
-                      params["fc2"], params["fc2_bias"], activation,
-                      x2.dtype, gmm_backend)
-    out = jnp.zeros((t_n, h), jnp.float32).at[tok].add(
-        gate_s[:, None] * h2.astype(jnp.float32))
-    return out.astype(x2.dtype), counts
+    top_k = choice.shape[-1]
+    n = t_n * top_k
+    first, g_n = held if held is not None else (0, e_n)
+    with jax.named_scope("moe_dispatch"):
+        fe = choice.reshape(-1)
+        load = jnp.sum(fe[:, None] == jnp.arange(e_n, dtype=fe.dtype),
+                       axis=0, dtype=jnp.int32)
+        local = fe - first
+        key = jnp.where((local >= 0) & (local < g_n), local, g_n)
+        order = jnp.argsort(key).astype(jnp.int32)          # stable
+        offsets = jnp.concatenate(
+            [jnp.zeros(1, jnp.int32),
+             jnp.cumsum(load[first:first + g_n], dtype=jnp.int32)])
+    chunked = (held is not None and n > _CHUNK_ROWS
+               and n % _CHUNK_ROWS == 0)
+    chunk = _CHUNK_ROWS if chunked else n
+
+    def rows(lo, args, order, offsets):
+        """What sorted rows ``lo .. lo + chunk`` add to the output."""
+        x2, gates, w = args
+        slot = jax.lax.dynamic_slice(order, (lo,), (chunk,))
+        with jax.named_scope("moe_dispatch"):
+            tok = slot // top_k
+            xs = x2[tok]
+        with jax.named_scope("expert_ffn"):
+            h2 = _grouped_ffn(
+                xs, jnp.clip(offsets - lo, 0, chunk), w["fc1"],
+                w.get("fc1_bias"), w["fc2"], w.get("fc2_bias"),
+                activation, x2.dtype, gmm_backend)
+        with jax.named_scope("moe_combine"):
+            # a row past the window is zero by the grouped products'
+            # contract, whatever its gate
+            return jnp.zeros((t_n, h), jnp.float32).at[tok].add(
+                gates.reshape(-1)[slot][:, None] * h2.astype(jnp.float32))
+
+    args = (x2, gates, {k: v for k, v in params.items()
+                        if k.startswith("fc")})
+    if chunked:
+        out = _live_chunks(rows, n, chunk, (t_n, h))(args, order, offsets)
+    else:
+        out = rows(0, args, order, offsets)
+    return out.astype(x2.dtype), load
 
 
 def _ep_abstract_mesh():
@@ -658,6 +788,8 @@ def switch_moe_mlp(
     overlap_comm: Optional[bool] = None,
     ep_mesh=None,
     gmm_backend: Optional[str] = None,
+    router: str = "softmax",
+    experts_held: Optional[tuple] = None,
 ) -> MoEOutput:
     """Token-choice top-k MoE FFN over ``x`` [b, s, h].
 
@@ -686,7 +818,21 @@ def switch_moe_mlp(
 
     ``activation='swiglu'`` expects ``fc1``/``fc1_bias`` with a doubled
     trailing dim ``2f`` ([gate ‖ up] concatenated) and applies the fused
-    bias-SwiGLU epilogue (ops/swiglu.py) inside each expert.
+    bias-SwiGLU epilogue (ops/swiglu.py) inside each expert.  The bias
+    leaves may be absent (bias-free experts).
+
+    ``router="sigmoid"`` (ragged routing only): float32 sigmoid scores,
+    the ``top_k`` largest of ``score + params["router_bias"]`` chosen
+    (the bias selects and never weighs; it gets no gradient), gates
+    normalised over the chosen (:func:`_sigmoid_routing`); no auxiliary
+    loss is defined for it (``aux_loss`` is 0).
+
+    ``experts_held=(first, count)`` (ragged routing only): ``fc1``/
+    ``fc2`` hold experts ``first .. first + count`` of the router's
+    ``E``, this caller being one rank of an expert-parallel layer.  The
+    layer routes over all ``E`` and returns the part of the result that
+    its own experts give, with no exchange; ``expert_load`` still counts
+    all ``E``.  ``None`` means all experts are held.
     """
     if routing not in MOE_ROUTINGS:
         raise ValueError(
@@ -710,6 +856,13 @@ def switch_moe_mlp(
             raise ValueError(
                 "quantized expert slabs are a single-device serving "
                 "path; run them outside an expert-parallel mesh")
+    if routing != "ragged" and (router != "softmax"
+                                or experts_held is not None):
+        raise ValueError(
+            "router='sigmoid' and experts_held need routing='ragged'")
+    if router not in MOE_ROUTERS:
+        raise ValueError(
+            f"router={router!r}: expected one of {MOE_ROUTERS}")
     if routing == "capacity":
         return _capacity_moe(
             params, x, capacity_factor=capacity_factor, top_k=top_k,
@@ -725,7 +878,8 @@ def switch_moe_mlp(
 
     mesh = ep_mesh if ep_mesh is not None else _ep_abstract_mesh()
     ep = _mesh_axis_size(mesh, ep_axis)
-    if ep >= 2 and (b * s) % ep == 0 and e_n % ep == 0:
+    local = router != "softmax" or experts_held is not None
+    if not local and ep >= 2 and (b * s) % ep == 0 and e_n % ep == 0:
         out2, aux, load = _ragged_ep_island(
             params, x2, mesh=mesh, ep_axis=ep_axis, top_k=top_k,
             router_noise_rng=router_noise_rng, activation=activation,
@@ -733,11 +887,22 @@ def switch_moe_mlp(
             overlap=overlap_enabled(overlap_comm),
             gmm_backend=gmm_backend)
     else:
-        probs = _router_probs(params["router"], x2, router_noise_rng)
+        with jax.named_scope("router"):
+            if router == "sigmoid":
+                choice, gates, _ = _sigmoid_routing(
+                    params["router"], params.get("router_bias"), x2,
+                    top_k)
+                probs = None
+            else:
+                probs = _router_probs(params["router"], x2,
+                                      router_noise_rng)
+                choice, gates = _topk_routing(probs, top_k)
         out2, counts = _ragged_local(
-            params, x2, probs, top_k, activation, gmm_backend)
+            params, x2, choice, gates, activation, gmm_backend,
+            held=experts_held)
         load = counts.astype(jnp.float32)
-        aux = _aux_loss(jnp.mean(probs, axis=0), load, b * s * top_k)
+        aux = (jnp.zeros((), jnp.float32) if probs is None else
+               _aux_loss(jnp.mean(probs, axis=0), load, b * s * top_k))
 
     return MoEOutput(out=out2.reshape(b, s, h).astype(x.dtype),
                      aux_loss=aux,

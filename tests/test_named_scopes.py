@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from apex_tpu.models.bert import make_bert_train_step
-from apex_tpu.models.config import bert_large, gpt_125m
+from apex_tpu.models.config import bert_large, gpt_125m, lfm2_moe
 from apex_tpu.models.gpt import make_gpt_train_step
 from apex_tpu.optimizers import fused_adam, fused_lamb
 
@@ -39,9 +39,17 @@ KERNELS_BWD = {"flash_bwd", "layer_norm_bwd"}      # toy s64: fused backward
 # kernel nor the layout copies that fed it run a second time
 RECOMPUTED = {"ln1", "qkv", "ln2", "fc1", "layer_norm_fwd"}
 NOT_RECOMPUTED = {"flash_fwd", "core_attention"}
+# the hybrid stack's own (models/hybrid.py, transformer/moe.py,
+# ops/grouped_matmul.py): forward and backward alike, but for the kernels
+HYBRID = {"short_conv", "conv_in", "conv_gate", "conv_out", "qk_norm",
+          "rope", "router", "moe_dispatch", "expert_ffn", "moe_combine",
+          "dense_ffn"}
+HYBRID_KERNELS_FWD = {"gmm_fwd"}
+HYBRID_KERNELS_BWD = {"gmm_dx", "gmm_dw"}
 ALL = (BOTH_WAYS | STEP | KERNELS_FWD | KERNELS_BWD
        | {"residual", "lm_head_ce", "embedding_ln", "mlm_head", "nsp_head",
-          "trust_ratio", "flash_bwd_dq", "flash_bwd_dkv", "grad_reduce"})
+          "trust_ratio", "flash_bwd_dq", "flash_bwd_dkv", "grad_reduce"}
+       | HYBRID | HYBRID_KERNELS_FWD | HYBRID_KERNELS_BWD)
 
 
 def _gpt():
@@ -49,7 +57,7 @@ def _gpt():
                    remat=True, scan_layers=True)
     init, step = make_gpt_train_step(cfg, fused_adam(lr=1e-4), "O2")
     ids = np.zeros((B, S), np.int32)
-    return init, step, (ids, ids), {"lm_head_ce"}, set(), True
+    return init, step, (ids, ids), {"lm_head_ce"}, set(), True, set()
 
 
 def _bert():
@@ -59,7 +67,24 @@ def _bert():
     ids = np.zeros((B, S), np.int32)
     batch = (ids, ids, np.zeros((B,), np.int32), ids, np.ones_like(ids))
     return (init, step, batch, {"embedding_ln", "mlm_head", "nsp_head"},
-            {"trust_ratio"}, False)
+            {"trust_ratio"}, False, set())
+
+
+def _lfm2():
+    """The LFM2-MoE pattern at toy widths: a conv layer with a dense FFN,
+    then attention, conv, conv, conv layers with experts (4 of 16 held),
+    each layer its own checkpoint."""
+    cfg = lfm2_moe(
+        hidden_size=128, num_hidden_layers=5,
+        layer_types=["conv", "full_attention", "conv", "conv", "conv"],
+        num_attention_heads=4, num_key_value_heads=2,
+        intermediate_size=256, moe_intermediate_size=128,
+        num_dense_layers=1, num_experts=16, num_experts_per_tok=4,
+        vocab_size=512, experts_held=(4, 4), fused_head_ce=True,
+        remat=True)
+    init, step = make_gpt_train_step(cfg, fused_adam(lr=1e-4), "O2")
+    ids = np.zeros((B, S), np.int32)
+    return init, step, (ids, ids), {"lm_head_ce"}, set(), True, HYBRID
 
 
 def _words(op_name: str) -> set:
@@ -69,11 +94,12 @@ def _words(op_name: str) -> set:
             for part in op_name.split("/")}
 
 
-@pytest.mark.parametrize("build", [_gpt, _bert],
-                         ids=["gpt_scan_remat", "bert_unrolled"])
+@pytest.mark.parametrize("build", [_gpt, _bert, _lfm2],
+                         ids=["gpt_scan_remat", "bert_unrolled",
+                              "lfm2_hybrid_remat"])
 def test_every_part_of_the_step_is_scoped(build, monkeypatch):
     monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
-    init, step, batch, heads, optimizer_words, remat = build()
+    init, step, batch, heads, optimizer_words, remat, hybrid = build()
     state = jax.eval_shape(init, jax.random.key_data(jax.random.key(0)))
     text = step.lower(state, *batch).compile().as_text()
 
@@ -106,6 +132,12 @@ def test_every_part_of_the_step_is_scoped(build, monkeypatch):
         assert not NOT_RECOMPUTED & seen["recompute"]
     else:
         assert not seen["recompute"]
+    if hybrid:
+        assert hybrid | HYBRID_KERNELS_FWD <= seen["forward"]
+        assert hybrid | HYBRID_KERNELS_BWD <= seen["backward"]
+        # the expert layer is recomputed with the rest of its layer
+        assert {"router", "expert_ffn", "gmm_fwd",
+                "conv_in"} <= seen["recompute"]
     # (b) no matmul or kernel without a word of the program's
     assert not unscoped_work
     # (c) the step's own phases, outside the differentiated function

@@ -145,6 +145,24 @@ def _grouped_matmul(s):
                             _spec((9,), jnp.int32, s))
 
 
+def _grouped_matmul_published(k, p):
+    """The LFM2-24B-A2B cell's expert products (hidden 2048, expert width
+    1536, SwiGLU: 2048 x 3072 and 1536 x 2048; 8 experts held, the
+    worst-case buffer's chunk of 16,384 rows): forward, input gradient and
+    weight gradient, three kernels."""
+    def build(s):
+        from apex_tpu.ops.grouped_matmul import grouped_matmul
+
+        def fn(x, w, off):
+            out, pull = jax.vjp(
+                lambda x, w: grouped_matmul(x, w, off), x, w)
+            return out, pull(out)
+
+        return fn, (_spec((16384, k), BF16, s), _spec((8, k, p), BF16, s),
+                    _spec((9,), jnp.int32, s))
+    return build
+
+
 def _prefill(s):
     from apex_tpu.models.generate import prefill
 
@@ -160,12 +178,70 @@ def _prefill(s):
     pytest.param(_sample, id="fused_sample_topk_topp"),
     pytest.param(_quantized_matmul, id="quantized_matmul"),
     pytest.param(_grouped_matmul, id="grouped_matmul"),
+    pytest.param(_grouped_matmul_published(2048, 3072),
+                 id="grouped_matmul_fc1_2048x3072"),
+    pytest.param(_grouped_matmul_published(1536, 2048),
+                 id="grouped_matmul_fc2_1536x2048"),
     pytest.param(_prefill, id="prefill_4x512"),
 ])
 def test_compiles_for_v5e(build, one_chip, as_tpu):
     fn, args = build(one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _kernels(text, scope):
+    """The kernels' custom calls under ``scope`` (``/scope/`` in the
+    ``op_name``, or ``jvp(scope)`` where the scope is outermost)."""
+    import re
+
+    word = re.compile(rf"[/(]{scope}[/)]")
+    return [line for line in text.splitlines()
+            if "tpu_custom_call" in line and " custom-call(" in line
+            and word.search(line)]
+
+
+def test_grouped_products_are_three_kernels(one_chip, as_tpu):
+    """Forward, input gradient and weight gradient of a grouped product
+    each compile to a kernel of their own name (no masked XLA product over
+    all rows is left for the weight gradient)."""
+    fn, args = _grouped_matmul_published(2048, 3072)(one_chip)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    for scope in ("gmm_fwd", "gmm_dx", "gmm_dw"):
+        assert len(_kernels(text, scope)) == 1, scope
+
+
+def test_lfm2_step_compiles_at_published_widths(one_chip, as_tpu):
+    """The LFM2-24B-A2B hybrid stack at its published widths (hidden 2048,
+    32 query heads over 8 K/V heads of 64, dense FFN 11776, experts of
+    1536, 4 of 64 a token with 8 held) at the benchmark cell's b2 x s8192,
+    cut to three layers (conv + dense FFN, attention + experts, conv +
+    experts): the whole O2 train step compiles for the described v5e with
+    every kernel in and fits the chip."""
+    from apex_tpu.models.config import lfm2_moe
+    from apex_tpu.models.gpt import make_gpt_train_step
+    from apex_tpu.optimizers import fused_adam
+
+    cfg = lfm2_moe(
+        hidden_size=2048, num_hidden_layers=3,
+        layer_types=["conv", "full_attention", "conv"],
+        num_attention_heads=32, num_key_value_heads=8,
+        intermediate_size=11776, moe_intermediate_size=1536,
+        num_dense_layers=1, num_experts=64, num_experts_per_tok=4,
+        vocab_size=8192, experts_held=(0, 8), fused_head_ce=True,
+        remat=True)
+    init, step = make_gpt_train_step(cfg, fused_adam(lr=1e-4), "O2")
+    state = _like(jax.eval_shape(
+        init, jax.random.key_data(jax.random.key(0))), one_chip)
+    ids = _spec((2, 8192), jnp.int32, one_chip)
+    compiled = step.lower(state, ids, ids).compile()
+    text = compiled.as_text()
+    for scope in ("gmm_fwd", "gmm_dx", "gmm_dw", "flash_fwd",
+                  "flash_bwd_dq", "flash_bwd_dkv"):
+        assert _kernels(text, scope), scope
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) < 14e9
 
 
 def test_ddp_step_compiles_for_four_chips(topo, as_tpu):
