@@ -14,7 +14,21 @@ grid runs (batch*heads, q-blocks, kv-blocks) with kv innermost; VMEM scratch
 carries the running max / normalizer / accumulator across kv steps.
 
 Variants:
-- ``causal=True`` — upper-triangular mask generated from iota in-kernel.
+- ``causal=True`` — upper-triangular mask generated from iota in-kernel;
+  score tiles wholly above the diagonal are skipped.  Where the tile is
+  1024 x 1024 (every sequence of 1024 or more, :func:`_blocks`) the three
+  split kernels work a tile ON the diagonal in static bands of
+  :func:`_sub_tile` rows (forward, dq) or columns (dk/dv) that end at
+  the diagonal, so the squares above it cost no product, no ``exp`` and
+  no mask, with the next band's products issued before this band's
+  vector arithmetic; a tile wholly below the diagonal is one unmasked
+  product.  Where the keys are one tile (s1024) a band holds every key
+  its rows will see, and the forward writes its softmax straight out:
+  no running max, sum or accumulator in scratch.
+  :func:`causal_work_share` is the share of the score rectangle that is
+  still computed.  Segment ids keep whole tiles (their skip is by id
+  range, a tile at a time), as do the fused backward (sk <= 512) and
+  everything non-causal.
 - ``key_padding_mask`` [b, sk] — bool (True = masked) or additive float
   (the reference's ``mask_additive`` MHA mode) — fused in-kernel as an
   additive score term.
@@ -59,8 +73,8 @@ from jax.experimental import pallas as pl
 from apex_tpu.ops._pallas_utils import LANES as _LANES, out_struct
 from apex_tpu.utils.registry import on_tpu
 
-__all__ = ["flash_attention", "flash_attention_packed", "mha_reference",
-           "segment_ids_from_cu_seqlens"]
+__all__ = ["causal_work_share", "flash_attention", "flash_attention_packed",
+           "mha_reference", "segment_ids_from_cu_seqlens"]
 
 _NEG_INF = -1e30
 
@@ -202,17 +216,183 @@ def mha_reference(q, k, v, *, causal=False, key_padding_mask=None,
 # ---------------------------------------------------------------------------
 
 
+def _both(pred, more):
+    return more if pred is None else pred & more
+
+
+def _open_pairs(q0, k0, shape, crossed, sk_real=None, sq_real=None):
+    """Which (row, col) pairs of a score rectangle whose corner is the
+    absolute position (q0, k0) may attend: ``col < sk_real`` and
+    ``row < sq_real`` where a bound is given (padded tails),
+    ``col <= row`` where the diagonal crosses the rectangle.  None when
+    nothing is asked: the rectangle then costs no iota, compare or
+    select."""
+    pred = None
+    if sk_real is not None or crossed:
+        col = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if sq_real is not None or crossed:
+        row = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    if sk_real is not None:
+        pred = col < sk_real
+    if sq_real is not None:
+        pred = _both(pred, row < sq_real)
+    if crossed:
+        pred = _both(pred, col <= row)
+    return pred
+
+
+def _causal_rects(block, sub, by):
+    """The part of a (block, block) score tile ON the diagonal that lies
+    at or below it, as static ``(row0, rows, col0, cols, crossed)``
+    rectangles in the tile's own coordinates, every one crossed by the
+    diagonal.  ``by="rows"``: one band a ``sub``-row block, from column
+    0 to the block's own square (forward, dq: one softmax step and one
+    accumulation a row block).  ``by="cols"``: one band a ``sub``-column
+    block, from its own square down to the tile's last row (dk/dv: one
+    accumulation a column block).  What lies above the diagonal's
+    squares is in no band, so nothing is computed for it;
+    :func:`causal_work_share` counts areas from this same list.  (Masking
+    a band's one crossed square alone measured the same as masking the
+    band: the mask is not what bounds these kernels.)"""
+    if by == "rows":
+        return [(r, sub, 0, r + sub, True) for r in range(0, block, sub)]
+    return [(c, block - c, c, sub, True) for c in range(0, block, sub)]
+
+
+def _visit_tile(front, back, causal, block_q, block_k, sub, multi, qi, kj,
+                seg_refs, by="rows"):
+    """Work off what grid step (qi, kj) has to compute of its (block_q,
+    block_k) score tile, a rectangle ``(row0, rows, col0, cols, crossed)``
+    at a time in two stages: ``front(*rect)`` makes the rectangle's MXU
+    products from the operands, ``back(*rect, made)`` does the rest.
+
+    Sub-tiled causal (``sub``; block_q == block_k): a tile below the
+    diagonal is one whole unmasked rectangle, the tile on it the static
+    nest of :func:`_causal_rects` (bands ``by`` rows or columns), a tile
+    above it nothing.  The nest runs the next band's ``front`` before
+    this band's ``back``: the bands share no value, so the MXU can work
+    under the other band's vector arithmetic.  With one tile a head
+    (``multi`` False, s1024) only the nest is emitted.  Otherwise whole
+    tiles: skipped when wholly above the diagonal or when the segment-id
+    ranges of its rows and columns are disjoint."""
+    def tile(*rect):
+        back(*rect, front(*rect))
+
+    if sub:
+        def on_diagonal():
+            rects = _causal_rects(block_q, sub, by)
+            made = front(*rects[0])
+            for rect, ahead in zip(rects, rects[1:] + [None]):
+                made_ahead = None if ahead is None else front(*ahead)
+                back(*rect, made)
+                made = made_ahead
+
+        if not multi:
+            on_diagonal()
+            return
+        pl.when(kj < qi)(lambda: tile(0, block_q, 0, block_k, False))
+        pl.when(kj == qi)(on_diagonal)
+        return
+    run = None
+    if causal:
+        # whole kv block above the diagonal -> skip its FLOPs
+        run = _tile_runs(block_q, block_k, qi, kj)
+    if seg_refs is not None:
+        # block-sparse skip of fully-disjoint tiles: if any q/k segment
+        # ids match, the id ranges overlap -- so disjoint ranges are a
+        # safe (conservative) skip regardless of id ordering
+        qseg, kseg = seg_refs[0][0], seg_refs[1][0]
+        overlap = (jnp.min(kseg) <= jnp.max(qseg)) & (
+            jnp.max(kseg) >= jnp.min(qseg))
+        run = _both(run, overlap)
+
+    def whole():
+        tile(0, block_q, 0, block_k, causal)
+
+    if run is None:
+        whole()
+    else:
+        pl.when(run)(whole)
+
+
 def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
-                has_seg, dropout_p, *refs):
+                has_seg, dropout_p, sub, multi, padded, direct, *refs):
     if dropout_p > 0.0:
         seed_ref, refs = refs[0], refs[1:]
+    seg_refs = None
     if has_seg:
-        qseg_ref, kseg_ref, refs = refs[0], refs[1], refs[2:]
-    if has_kpm:
-        q_ref, k_ref, v_ref, kpm_ref, o_ref, lse_ref, acc, m_s, l_s = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s = refs
+        seg_refs, refs = refs[:2], refs[2:]
+    q_ref, k_ref, v_ref = refs[:3]
+    kpm_ref, refs = (refs[3], refs[4:]) if has_kpm else (None, refs[3:])
+    o_ref, lse_ref = refs[:2]
     bh, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    q_start = qi * block_q
+    k_start = kj * block_k
+    # sub-tiled causal rows always hold an open key (col 0) from their
+    # first rectangle on, and need the kv-tail bound only when padded
+    bounded = padded or not sub
+    guarded = has_kpm or not sub
+
+    def _scores(r0, rn, c0, cn, crossed):
+        rows, cols = slice(r0, r0 + rn), slice(c0, c0 + cn)
+        q = q_ref[0, rows, :].astype(jnp.float32)
+        k = k_ref[0, cols, :].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if has_kpm:
+            s = s + kpm_ref[0, :, cols]  # additive [1, cols] broadcast
+
+        pred = _open_pairs(q_start + r0, k_start + c0, (rn, cn), crossed,
+                           sk_real if bounded else None)
+        if has_seg:
+            # packed multi-sequence rows: attend within a segment only
+            # (negative ids = padding slots, matching nothing)
+            qseg = seg_refs[0][0, rows].reshape(rn, 1)
+            kseg = seg_refs[1][0, cols].reshape(1, cn)
+            pred = _both(pred, (qseg == kseg) & (kseg >= 0))
+        return s if pred is None else jnp.where(pred, s, _NEG_INF)
+
+    def _weights(r0, rn, c0, cn, s, m_prev):
+        """One softmax step over a rectangle: the rows' new maximum, the
+        sums of their weights, and the weights' product with v."""
+        m_new = jnp.max(s, axis=-1, keepdims=True)
+        if m_prev is not None:
+            m_new = jnp.maximum(m_prev, m_new)
+        p = jnp.exp(s - m_new)
+        if guarded:
+            # fully-masked-so-far rows: m_new == -inf => exp(NaN) guards
+            p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
+        l_new = jnp.sum(p, axis=-1, keepdims=True)
+        if dropout_p > 0.0:
+            keep = _keep_mask(seed_ref[0], bh, q_start + r0, k_start + c0,
+                              (rn, cn), 1.0 - dropout_p)
+            p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
+        pv = jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, c0:c0 + cn, :],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return m_new, l_new, pv
+
+    def _write(rows, m, l, acc_rows):
+        safe_l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, rows, :] = (acc_rows / safe_l).astype(o_ref.dtype)
+        # logsumexp (fully-masked rows get -inf-ish sentinel)
+        lse = m + jnp.log(safe_l)
+        lse_ref[0, rows, :] = jnp.broadcast_to(
+            jnp.where(l == 0.0, _NEG_INF, lse),
+            (lse.shape[0], lse_ref.shape[2]))
+
+    if direct:
+        # one kv tile: a rectangle holds all the keys its rows will see,
+        # so its softmax is whole -- no running state, no scratch
+        def _emit(r0, rn, c0, cn, crossed, s):
+            _write(slice(r0, r0 + rn), *_weights(r0, rn, c0, cn, s, None))
+
+        _visit_tile(_scores, _emit, causal, block_q, block_k, sub, multi,
+                    qi, kj, seg_refs)
+        return
+
+    acc, m_s, l_s = refs[2:]
 
     @pl.when(kj == 0)
     def _init():
@@ -220,81 +400,23 @@ def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
-    q_start = qi * block_q
-    k_start = kj * block_k
-
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if has_kpm:
-            s = s + kpm_ref[0]  # additive [1, block_k] broadcast
-
-        col = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        pred = col < sk_real                       # kv tail padding
-        if causal:
-            row = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            pred &= col <= row
-        if has_seg:
-            # packed multi-sequence rows: attend within a segment only
-            # (negative ids = padding slots, matching nothing)
-            qseg = qseg_ref[0].reshape(block_q, 1)
-            kseg = kseg_ref[0].reshape(1, block_k)
-            pred &= (qseg == kseg) & (kseg >= 0)
-        s = jnp.where(pred, s, _NEG_INF)
-
-        m_prev = m_s[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+    def _absorb(r0, rn, c0, cn, crossed, s):
+        rows = slice(r0, r0 + rn)
+        m_prev = m_s[rows, :1]
+        m_new, l_new, pv = _weights(r0, rn, c0, cn, s, m_prev)
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        # fully-masked-so-far rows: m_new == -inf ⇒ exp(NaN) guards
-        p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
-        alpha = jnp.where(m_new > _NEG_INF / 2, alpha, 0.0)
+        if guarded:
+            alpha = jnp.where(m_new > _NEG_INF / 2, alpha, 0.0)
+        l_s[rows, :] = l_s[rows, :] * alpha + l_new
+        acc[rows, :] = acc[rows, :] * alpha + pv
+        m_s[rows, :] = jnp.broadcast_to(m_new, (rn, m_s.shape[1]))
 
-        l_s[:] = l_s[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if dropout_p > 0.0:
-            keep = _keep_mask(seed_ref[0], bh, q_start, k_start,
-                              (block_q, block_k), 1.0 - dropout_p)
-            p_acc = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
-        else:
-            p_acc = p
-        acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            p_acc.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-
-    run = None
-    if causal:
-        # whole kv block above the diagonal → skip its FLOPs
-        run = k_start <= q_start + block_q - 1
-    if has_seg:
-        # block-sparse skip of fully-disjoint tiles: if any q/k segment
-        # ids match, the id ranges overlap — so disjoint ranges are a
-        # safe (conservative) skip regardless of id ordering
-        qseg = qseg_ref[0]
-        kseg = kseg_ref[0]
-        overlap = (jnp.min(kseg) <= jnp.max(qseg)) & (
-            jnp.max(kseg) >= jnp.min(qseg))
-        run = overlap if run is None else (run & overlap)
-    if run is None:
-        _compute()
-    else:
-        pl.when(run)(_compute)
+    _visit_tile(_scores, _absorb, causal, block_q, block_k, sub, multi,
+                qi, kj, seg_refs)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
-        l = l_s[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc[:] / safe_l).astype(o_ref.dtype)
-        # logsumexp (fully-masked rows get -inf-ish sentinel)
-        lse = m_s[:, :1] + jnp.log(safe_l)
-        lse_ref[0] = jnp.broadcast_to(
-            jnp.where(l == 0.0, _NEG_INF, lse), lse_ref.shape[1:])
+        _write(slice(None), m_s[:, :1], l_s[:, :1], acc[:])
 
 
 def _kv_of(bq_flat, n, g):
@@ -312,6 +434,8 @@ def _fwd_pallas(q3, k3, v3, kpm, seg, seed, scale, causal, sk_real,
     bh, sqp, d = q3.shape
     skp = k3.shape[1]
     grid = (bh, sqp // block_q, skp // block_k)
+    sub = _sub_tile(block_q, block_k) if causal and seg is None else 0
+    direct = bool(sub) and grid[2] == 1
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                           memory_space=pltpu.VMEM)
@@ -367,12 +491,13 @@ def _fwd_pallas(q3, k3, v3, kpm, seg, seed, scale, causal, sk_real,
         o, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, scale, causal, sk_real,
                               block_q, block_k, kpm is not None,
-                              seg is not None, dropout_p),
+                              seg is not None, dropout_p, sub,
+                              grid[1:] != (1, 1), skp != sk_real, direct),
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shape,
-            scratch_shapes=[
+            scratch_shapes=[] if direct else [
                 pltpu.VMEM((block_q, d), jnp.float32),
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -387,18 +512,33 @@ def _fwd_pallas(q3, k3, v3, kpm, seg, seed, scale, causal, sk_real,
 # ---------------------------------------------------------------------------
 
 
+def _scores_and_dp(q_ref, k_ref, v_ref, do_ref, kpm_ref, scale, rows, cols):
+    """The backward kernels' first stage over a score rectangle: the
+    scaled scores ``q k^T`` (plus the additive key mask) and ``do v^T``."""
+    q = q_ref[0, rows, :].astype(jnp.float32)
+    k = k_ref[0, cols, :].astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if kpm_ref is not None:
+        s = s + kpm_ref[0, :, cols]
+    do = do_ref[0, rows, :].astype(jnp.float32)
+    dp = jax.lax.dot_general(
+        do, v_ref[0, cols, :].astype(jnp.float32),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    return s, dp
+
+
 def _bwd_dq_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
-                   has_seg, dropout_p, *refs):
+                   has_seg, dropout_p, sub, multi, padded, *refs):
     if dropout_p > 0.0:
         seed_ref, refs = refs[0], refs[1:]
+    seg_refs = None
     if has_seg:
-        qseg_ref, kseg_ref, refs = refs[0], refs[1], refs[2:]
-    if has_kpm:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kpm_ref,
-         dq_ref, dq_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dq_acc) = refs
+        seg_refs, refs = refs[:2], refs[2:]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    kpm_ref, refs = (refs[6], refs[7:]) if has_kpm else (None, refs[6:])
+    dq_ref, dq_acc = refs
     bh, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(kj == 0)
@@ -406,58 +546,44 @@ def _bwd_dq_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     q_start, k_start = qi * block_q, kj * block_k
+    bounded = padded or not sub     # see _fwd_kernel
+    guarded = has_kpm or not sub
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if has_kpm:
-            s = s + kpm_ref[0]
-        col = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        pred = col < sk_real
-        if causal:
-            row = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            pred &= col <= row
+    def _products(r0, rn, c0, cn, crossed):
+        return _scores_and_dp(q_ref, k_ref, v_ref, do_ref, kpm_ref, scale,
+                              slice(r0, r0 + rn), slice(c0, c0 + cn))
+
+    def _accumulate(r0, rn, c0, cn, crossed, made):
+        s, dp = made
+        rows, cols = slice(r0, r0 + rn), slice(c0, c0 + cn)
+        pred = _open_pairs(q_start + r0, k_start + c0, (rn, cn), crossed,
+                           sk_real if bounded else None)
         if has_seg:
-            qseg = qseg_ref[0].reshape(block_q, 1)
-            kseg = kseg_ref[0].reshape(1, block_k)
-            pred &= (qseg == kseg) & (kseg >= 0)
-        lse = lse_ref[0][:, :1]
-        # fully-masked rows carry the -inf lse sentinel: s - lse would be
-        # ~0 there (additive -1e30 mask == -1e30 sentinel), not -inf —
-        # zero them explicitly or pad keys receive garbage gradients
-        pred &= lse > _NEG_INF / 2
-        p = jnp.where(pred, jnp.exp(s - lse), 0.0)
-        do = do_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            qseg = seg_refs[0][0, rows].reshape(rn, 1)
+            kseg = seg_refs[1][0, cols].reshape(1, cn)
+            pred = _both(pred, (qseg == kseg) & (kseg >= 0))
+        lse = lse_ref[0, rows, :1]
+        if guarded:
+            # fully-masked rows carry the -inf lse sentinel: s - lse would
+            # be ~0 there (additive -1e30 mask == -1e30 sentinel), not
+            # -inf -- zero them explicitly or pad keys receive garbage
+            # gradients
+            pred = _both(pred, lse > _NEG_INF / 2)
+        p = jnp.exp(s - lse)
+        if pred is not None:
+            p = jnp.where(pred, p, 0.0)
         if dropout_p > 0.0:
-            keep = _keep_mask(seed_ref[0], bh, q_start, k_start,
-                              (block_q, block_k), 1.0 - dropout_p)
+            keep = _keep_mask(seed_ref[0], bh, q_start + r0, k_start + c0,
+                              (rn, cn), 1.0 - dropout_p)
             dp = jnp.where(keep, dp / (1.0 - dropout_p), 0.0)
-        delta = delta_ref[0][:, :1]
+        delta = delta_ref[0, rows, :1]
         ds = p * (dp - delta) * scale
-        dq_acc[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dq_acc[rows, :] += jax.lax.dot_general(
+            ds, k_ref[0, cols, :].astype(jnp.float32),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    run = None
-    if causal:
-        run = k_start <= q_start + block_q - 1
-    if has_seg:
-        qs, ks = qseg_ref[0], kseg_ref[0]
-        overlap = (jnp.min(ks) <= jnp.max(qs)) & (
-            jnp.max(ks) >= jnp.min(qs))
-        run = overlap if run is None else (run & overlap)
-    if run is None:
-        _compute()
-    else:
-        pl.when(run)(_compute)
+    _visit_tile(_products, _accumulate, causal, block_q, block_k, sub,
+                multi, qi, kj, seg_refs)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
@@ -465,17 +591,16 @@ def _bwd_dq_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
 
 
 def _bwd_dkv_kernel(scale, causal, sq_real, sk_real, block_q, block_k,
-                    has_kpm, has_seg, dropout_p, gqa, *refs):
+                    has_kpm, has_seg, dropout_p, gqa, sub, multi, padded,
+                    *refs):
     if dropout_p > 0.0:
         seed_ref, refs = refs[0], refs[1:]
+    seg_refs = None
     if has_seg:
-        qseg_ref, kseg_ref, refs = refs[0], refs[1], refs[2:]
-    if has_kpm:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kpm_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
+        seg_refs, refs = refs[:2], refs[2:]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    kpm_ref, refs = (refs[6], refs[7:]) if has_kpm else (None, refs[6:])
+    dk_ref, dv_ref, dk_acc, dv_acc = refs
     if gqa is not None:
         # grid (b*g, kv, rep, q): one dk/dv row accumulates all rep query
         # heads of its group; bh reconstructs the flat q-head row so the
@@ -498,64 +623,50 @@ def _bwd_dkv_kernel(scale, causal, sq_real, sk_real, block_q, block_k,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     q_start, k_start = qi * block_q, kj * block_k
+    bounded = padded or not sub     # see _fwd_kernel
+    guarded = has_kpm or not sub
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if has_kpm:
-            s = s + kpm_ref[0]
-        col = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        row = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        pred = (col < sk_real) & (row < sq_real)
-        if causal:
-            pred &= col <= row
+    def _products(r0, rn, c0, cn, crossed):
+        return _scores_and_dp(q_ref, k_ref, v_ref, do_ref, kpm_ref, scale,
+                              slice(r0, r0 + rn), slice(c0, c0 + cn))
+
+    def _accumulate(r0, rn, c0, cn, crossed, made):
+        s, dp = made
+        rows, cols = slice(r0, r0 + rn), slice(c0, c0 + cn)
+        pred = _open_pairs(q_start + r0, k_start + c0, (rn, cn), crossed,
+                           sk_real if bounded else None,
+                           sq_real if bounded else None)
         if has_seg:
-            qseg = qseg_ref[0].reshape(block_q, 1)
-            kseg = kseg_ref[0].reshape(1, block_k)
-            pred &= (qseg == kseg) & (kseg >= 0)
-        lse = lse_ref[0][:, :1]
-        # see _bwd_dq_kernel: zero fully-masked rows (lse sentinel)
-        pred &= lse > _NEG_INF / 2
-        p = jnp.where(pred, jnp.exp(s - lse), 0.0)
-        do = do_ref[0].astype(jnp.float32)
+            qseg = seg_refs[0][0, rows].reshape(rn, 1)
+            kseg = seg_refs[1][0, cols].reshape(1, cn)
+            pred = _both(pred, (qseg == kseg) & (kseg >= 0))
+        lse = lse_ref[0, rows, :1]
+        if guarded:
+            # see _bwd_dq_kernel: zero fully-masked rows (lse sentinel)
+            pred = _both(pred, lse > _NEG_INF / 2)
+        p = jnp.exp(s - lse)
+        if pred is not None:
+            p = jnp.where(pred, p, 0.0)
+        do = do_ref[0, rows, :].astype(jnp.float32)
         if dropout_p > 0.0:
-            keep = _keep_mask(seed_ref[0], bh, q_start, k_start,
-                              (block_q, block_k), 1.0 - dropout_p)
+            keep = _keep_mask(seed_ref[0], bh, q_start + r0, k_start + c0,
+                              (rn, cn), 1.0 - dropout_p)
             inv = 1.0 / (1.0 - dropout_p)
             p_acc = jnp.where(keep, p * inv, 0.0)
+            dp = jnp.where(keep, dp * inv, 0.0)
         else:
             p_acc = p
-        dv_acc[:] += jax.lax.dot_general(
+        dv_acc[cols, :] += jax.lax.dot_general(
             p_acc, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if dropout_p > 0.0:
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_p)), 0.0)
-        delta = delta_ref[0][:, :1]
+        delta = delta_ref[0, rows, :1]
         ds = p * (dp - delta) * scale
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dk_acc[cols, :] += jax.lax.dot_general(
+            ds, q_ref[0, rows, :].astype(jnp.float32),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    run = None
-    if causal:
-        run = k_start <= q_start + block_q - 1
-    if has_seg:
-        qs, ks = qseg_ref[0], kseg_ref[0]
-        overlap = (jnp.min(ks) <= jnp.max(qs)) & (
-            jnp.max(ks) >= jnp.min(qs))
-        run = overlap if run is None else (run & overlap)
-    if run is None:
-        _compute()
-    else:
-        pl.when(run)(_compute)
+    _visit_tile(_products, _accumulate, causal, block_q, block_k, sub,
+                multi, qi, kj, seg_refs, by="cols")
 
     @pl.when(last)
     def _finalize():
@@ -730,6 +841,8 @@ def _bwd_pallas(q3, k3, v3, do3, lse, delta, kpm, seg, seed, scale,
     skp = k3.shape[1]
     lse3 = jnp.broadcast_to(lse[:, :, None], (bh, sqp, _LANES))
     delta3 = jnp.broadcast_to(delta[:, :, None], (bh, sqp, _LANES))
+    sub = _sub_tile(block_q, block_k) if causal and seg is None else 0
+    multi = (sqp, skp) != (block_q, block_k)
 
     def qspec(f):
         return pl.BlockSpec((1, block_q, d), f, memory_space=pltpu.VMEM)
@@ -778,7 +891,8 @@ def _bwd_pallas(q3, k3, v3, do3, lse, delta, kpm, seg, seed, scale,
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, scale, causal, sk_real,
                               block_q, block_k, kpm is not None,
-                              seg is not None, dropout_p),
+                              seg is not None, dropout_p, sub, multi,
+                              skp != sk_real),
             grid=(bh, sqp // block_q, skp // block_k),
             in_specs=in_specs,
             out_specs=qspec(qmap),
@@ -837,7 +951,8 @@ def _bwd_pallas(q3, k3, v3, do3, lse, delta, kpm, seg, seed, scale,
         dk, dv = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, scale, causal, sq_real,
                               sk_real, block_q, block_k, kpm is not None,
-                              seg is not None, dropout_p, gqa),
+                              seg is not None, dropout_p, gqa, sub, multi,
+                              (sqp, skp) != (sq_real, sk_real)),
             grid=grid2,
             in_specs=in_specs,
             out_specs=[kspec(kmap2), kspec(kmap2)],
@@ -867,16 +982,73 @@ def _from_bh(x3, b, n):
 
 
 def _blocks(sq, sk):
-    """Block sizes from a round-3 sweep on a v5e (not measured on
-    today's code): at sk>=1024 the 1024x1024 score tile amortizes
-    per-grid-step overhead and beat the old 256x512 default ~1.5x (fwd
-    s1024 causal:
-    946us vs 1494us; s2048: 644us vs 964us); short sequences keep the
-    small tiles (256x512 best at s512).  1024x2048 fails to compile
-    (VMEM), so 1024 caps both dims."""
+    """Grid tile of the score rectangle: 1024 x 1024 from 1024 keys on,
+    256 x 512 below (256 x 512 best at s512 in the round-5 sweep;
+    1024 x 2048 once failed to compile on VMEM, so 1024 caps both).
+
+    The large tile amortizes the per-grid-step cost and fetches K/V once
+    a head; the causal kernels then skip INSIDE it (:func:`_sub_tile`).
+    Per call at b8 x 16 heads x s1024 x d64, bf16, causal, the kernel
+    chained 20 times in one jit on a v5e (my chip runs, PR 31; dq and
+    dkv include ~250 us of widening ``lse``/``delta`` to 128 lanes):
+
+    ==============================  =======  =======  =======
+    tile                            forward  dq       dk/dv
+    ==============================  =======  =======  =======
+    1024 x 1024 whole (PR 30)       646 us   838 us   1081 us
+    1024 x 1024 in bands of 512     394      713      892
+    1024 x 1024 in bands of 256     352      644      811
+    1024 x 1024 in bands of 128     344      601      822
+    512 x 512 grid tiles            1023     968      1251
+    256 x 512 grid tiles            1052     1205     1351
+    ==============================  =======  =======  =======
+
+    At b2 x 32/8 heads x s8192 (8 x 8 tiles, 8 of the 36 executed on the
+    diagonal): whole 10.77 / 12.80 / 22.28 ms, bands of 256 9.03 / 11.95
+    / 17.60, of 128 9.06 / 11.82 / 17.70, of 512 9.23 / 12.22 / 17.93."""
     bq = min(1024 if sq >= 1024 else 256, pl.cdiv(sq, _LANES) * _LANES)
     bk = min(1024 if sk >= 1024 else 512, pl.cdiv(sk, _LANES) * _LANES)
     return bq, bk
+
+
+def _sub_tile(block_q, block_k):
+    """Rows (forward, dq) or columns (dk/dv) of the bands a causal score
+    tile ON the diagonal is worked in, 0 for whole tiles: the second
+    half of :func:`_blocks`'s rule, a function of the static shapes
+    alone.  256 where the tile is 1024 x 1024: within 2% of 128 over the
+    three kernels at s1024 and level with it at s8192 (table above) with
+    half the unrolled bands; 512 keeps 3 squares of 4 and loses 10%."""
+    return 256 if block_q == block_k >= 1024 else 0
+
+
+def _tile_runs(block_q, block_k, qi, kj):
+    """Whether score tile (qi, kj) reaches down to the diagonal."""
+    return kj * block_k <= qi * block_q + block_q - 1
+
+
+def causal_work_share(sq, sk, causal=True):
+    """Share of the padded score rectangle's elements that the kernels
+    compute for an [sq, sk] attention: a static function of the shapes,
+    counted with the rule (:func:`_blocks`, :func:`_sub_tile`), the
+    dispatch (:func:`_visit_tile`) and the rectangles
+    (:func:`_causal_rects`) that the kernels run.  1.0 when not causal;
+    0.625 at s1024 (10 of 16 squares of 256); 33/64 at s8192."""
+    if not causal:
+        return 1.0
+    bq, bk = _blocks(sq, sk)
+    sub = _sub_tile(bq, bk)
+    on_diagonal = sub and sum(
+        rn * cn for _, rn, _, cn, _ in _causal_rects(bq, sub, "rows")
+    ) / (bq * bk)
+    nq, nk = pl.cdiv(sq, bq), pl.cdiv(sk, bk)
+    done = 0.0
+    for qi in range(nq):
+        for kj in range(nk):
+            if sub:
+                done += 1.0 if kj < qi else on_diagonal if kj == qi else 0.0
+            else:
+                done += bool(_tile_runs(bq, bk, qi, kj))
+    return done / (nq * nk)
 
 
 def _seg_pads(seg, sqp, skp):

@@ -102,7 +102,8 @@ def _fmt(name, pallas_s, xla_s):
 
 
 def bench_flash_attention(results):
-    from apex_tpu.ops.flash_attention import flash_attention, mha_reference
+    from apex_tpu.ops.flash_attention import (
+        causal_work_share, flash_attention, mha_reference)
 
     print("flash_attention (bf16, d=64)")
     rng = np.random.RandomState(0)
@@ -112,6 +113,9 @@ def bench_flash_attention(results):
         k = jnp.asarray(rng.randn(b, s, h, 64), jnp.bfloat16)
         v = jnp.asarray(rng.randn(b, s, h, 64), jnp.bfloat16)
         tag = f"b{b}xs{s}{'_causal' if causal else ''}"
+        # share of the score rectangle the kernels compute at this shape
+        share = causal_work_share(s, s, causal)
+        print(f"  {tag}: causal_work_share {share:.4f}")
 
         fa = functools.partial(flash_attention, causal=causal)
         ref = functools.partial(mha_reference, causal=causal)
@@ -122,6 +126,8 @@ def bench_flash_attention(results):
             f"fwd+bwd {tag}",
             chain_grad(fa, (0, 1, 2), q, k, v, inner=(16, 48, 160)),
             chain_grad(ref, (0, 1, 2), q, k, v, inner=(16, 48, 160)))
+        for row in (f"flash_fwd_{tag}", f"flash_fwdbwd_{tag}"):
+            results[row]["causal_work_share"] = share
 
 
 def bench_flash_gqa(results):

@@ -609,3 +609,74 @@ class TestBackwardModeRouting:
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5,
                 err_msg=f"d{name}")
+
+
+class TestCausalSubTiles:
+    """Sequences of 1024 or more run 1024 x 1024 score tiles that the
+    causal kernels work in 256-sided squares: none above the diagonal is
+    computed, only the squares the diagonal crosses are masked, a grid
+    tile below the diagonal is one unmasked product.  Small b*h keeps
+    the interpreter in seconds."""
+
+    @pytest.mark.parametrize("with_kpm", [False, True])
+    @pytest.mark.parametrize("sq,n,g", [
+        (1024, 2, 2),        # one tile a head: the static nest
+        (2048, 2, 1),        # 2 x 2 tiles: below / on / above, GQA rep grid
+        (1024 + 40, 1, 1),   # a padded tail inside a sub-tile
+    ])
+    def test_forward_and_grads_match_reference(self, sq, n, g, with_kpm):
+        d = 32
+        rng = np.random.RandomState(sq + n)
+        q = jnp.asarray(rng.randn(1, sq, n, d), jnp.float32) * 0.5
+        k = jnp.asarray(rng.randn(1, sq, g, d), jnp.float32) * 0.5
+        v = jnp.asarray(rng.randn(1, sq, g, d), jnp.float32) * 0.5
+        kw = dict(causal=True)
+        if with_kpm:
+            # masks key 0 too: the first rows have no open key at all
+            kw["key_padding_mask"] = jnp.asarray(
+                (np.arange(sq) % 7 == 0)[None])
+
+        def loss(fn):
+            def f(q, k, v):
+                o = fn(q, k, v, **kw)
+                return jnp.sum(o * jnp.cos(o)), o
+            return f
+
+        (_, o1), g1 = jax.value_and_grad(
+            loss(flash_attention), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        (_, o2), g2 = jax.value_and_grad(
+            loss(mha_reference), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), **TOL)
+        for a, b, name in zip(g1, g2, "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4,
+                err_msg=f"d{name}")
+
+    def test_dropout_bits_do_not_depend_on_the_tiling(self):
+        """The keep mask hashes absolute (row, col): sub-tiles draw the
+        bits of ``_keep_mask`` over the whole square."""
+        from apex_tpu.ops.flash_attention import _keep_mask, _seed_from_rng
+
+        s, d, p_drop = 1024, 32, 0.25
+        q, k, v = make_qkv(1, s, 1, d, seed=31)
+        rng = jax.random.PRNGKey(9)
+        got = flash_attention(q, k, v, causal=True, dropout_p=p_drop,
+                              dropout_rng=rng)
+        s_ = jnp.einsum("bsnd,btnd->bnst", q, k) / d ** 0.5
+        s_ = jnp.where(np.tril(np.ones((s, s), bool)), s_, -1e30)
+        keep = _keep_mask(_seed_from_rng(rng), jnp.int32(0), 0, 0, (s, s),
+                          1 - p_drop)
+        p = jnp.where(keep, jax.nn.softmax(s_, axis=-1) / (1 - p_drop), 0.0)
+        want = jnp.einsum("bnst,btnd->bsnd", p, v)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+
+    @pytest.mark.parametrize("s,share", [
+        (512, 1.0),          # 256 x 512 tiles, both reach the diagonal
+        (1024, 10 / 16),     # 10 of 16 squares of 256, 4 of them masked
+        (8192, 33 / 64),     # 28 tiles below + 8 on the diagonal of 64
+    ])
+    def test_causal_work_share(self, s, share):
+        from apex_tpu.ops.flash_attention import causal_work_share
+
+        assert causal_work_share(s, s) == share
+        assert causal_work_share(s, s, causal=False) == 1.0

@@ -201,6 +201,31 @@ def _kernels(text, scope):
             and word.search(line)]
 
 
+@pytest.mark.parametrize("b,s,heads,kv_heads", [
+    pytest.param(8, 1024, 16, 16, id="gpt_cell_b8s1024"),
+    pytest.param(2, 8192, 32, 8, id="lfm2_cell_b2s8192_gqa"),
+    pytest.param(2, 2048, 4, 4, id="s2048"),
+    pytest.param(1, 4096, 4, 2, id="s4096_gqa"),
+    pytest.param(1, 1024 + 40, 2, 2, id="s1064_padded_tail"),
+])
+def test_causal_flash_kernels_compile(b, s, heads, kv_heads, one_chip,
+                                      as_tpu):
+    """The sub-tiled causal forward and both split backward kernels at
+    the two causal cells' shapes and where the grid has tiles below, on
+    and above the diagonal (every s >= 1024 takes 1024 x 1024 tiles
+    worked in 256-sided squares)."""
+    from apex_tpu.ops.flash_attention import flash_attention
+
+    q = _spec((b, s, heads, 64), BF16, one_chip)
+    k = _spec((b, s, kv_heads, 64), BF16, one_chip)
+    loss = lambda q, k, v: flash_attention(       # noqa: E731
+        q, k, v, causal=True).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k).compile().as_text()
+    for scope in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert len(_kernels(text, scope)) == 1, scope
+
+
 def test_grouped_products_are_three_kernels(one_chip, as_tpu):
     """Forward, input gradient and weight gradient of a grouped product
     each compile to a kernel of their own name (no masked XLA product over
