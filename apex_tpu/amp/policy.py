@@ -134,7 +134,7 @@ def _effective(dtype):
     import os
 
     if dtype == jnp.float16 and os.environ.get("APEX_TPU_ALLOW_FP16") != "1":
-        from apex_tpu.utils.registry import on_tpu
+        from apex_tpu.ops._pallas_utils import on_tpu
 
         if on_tpu():
             return jnp.bfloat16

@@ -3,9 +3,9 @@
 PR 4 established the pattern for telemetry: one registered, validated,
 documented table (``observability.metrics.ENV_VARS``) with warn-by-name
 on anything unknown.  This module generalizes it to the whole repo: any
-``os.environ`` read of an ``APEX_TPU_*`` name must appear here (exact
-name or a ``*``-suffixed family), name the module that owns its
-validated parser, and point at the doc file that describes it.  The
+``os.environ`` read of an ``APEX_TPU_*`` name must appear here by its
+exact name, name the module that owns its validated parser, and point
+at the doc file that describes it.  The
 linter enforces all three:
 
 - APX201 (``unregistered-env-var``): an env read whose literal name is
@@ -27,7 +27,7 @@ __all__ = ["EnvVar", "ENV_REGISTRY", "lookup", "telemetry_names"]
 
 
 class EnvVar(NamedTuple):
-    name: str          # exact name, or a family ending in "*"
+    name: str          # exact name
     owner: str         # module whose parser validates it
     doc: str           # repo-relative doc file that describes it
     help: str
@@ -37,10 +37,9 @@ def _v(name, owner, doc, help):
     return (name, EnvVar(name, owner, doc, help))
 
 
-# One row per variable (or per dynamic family, "*"-suffixed).  Keep
-# sorted by name within each group; docs/static_analysis.md renders the
-# consolidated table and the docs-sync rule holds each row to its
-# declared file.
+# One row per variable.  Keep sorted by name within each group;
+# docs/static_analysis.md renders the consolidated table and the
+# docs-sync rule holds each row to its declared file.
 ENV_REGISTRY: Dict[str, EnvVar] = dict([
     # ---- telemetry (must mirror observability.metrics.ENV_VARS) ----
     _v("APEX_TPU_TELEMETRY", "apex_tpu.observability.metrics",
@@ -60,50 +59,12 @@ ENV_REGISTRY: Dict[str, EnvVar] = dict([
     _v("APEX_TPU_TELEMETRY_PORT", "apex_tpu.observability.metrics",
        "docs/observability.md", "serve /metrics + /healthz on this port"),
     # ---- kernel/backend routing --------------------------------------
-    _v("APEX_TPU_BACKEND", "apex_tpu.utils.registry",
-       "docs/static_analysis.md",
-       "force the op registry's backend (pallas|xla)"),
-    _v("APEX_TPU_PALLAS_INTERPRET", "apex_tpu.utils.registry",
+    _v("APEX_TPU_PALLAS_INTERPRET", "apex_tpu.ops._pallas_utils",
        "docs/inference.md",
        "run Pallas kernels in interpret mode (CPU testing)"),
-    _v("APEX_TPU_DISABLE_*", "apex_tpu.utils.registry",
-       "docs/static_analysis.md",
-       "disable one registered op by name (fall back to XLA)"),
     _v("APEX_TPU_DISABLE_NATIVE", "apex_tpu.contrib.sparsity",
        "docs/static_analysis.md",
        "sparsity permutation search: force the python path"),
-    _v("APEX_TPU_FLASH_BWD", "apex_tpu.ops.flash_attention",
-       "docs/static_analysis.md",
-       "flash-attention backward mode (auto|fused|split)"),
-    _v("APEX_TPU_FLASH_BWD_FUSED_MAX", "apex_tpu.ops.flash_attention",
-       "docs/static_analysis.md",
-       "auto mode's fused/split seq-length crossover (default 512)"),
-    _v("APEX_TPU_FLASH_FUSED_BQ", "apex_tpu.ops.flash_attention",
-       "docs/static_analysis.md",
-       "fused flash backward query-block size override"),
-    _v("APEX_TPU_LN_BWD", "apex_tpu.ops.layer_norm",
-       "docs/static_analysis.md",
-       "layer-norm backward routing (pallas|xla)"),
-    _v("APEX_TPU_SOFTMAX", "apex_tpu.ops.softmax",
-       "docs/static_analysis.md",
-       "softmax family routing (pallas forces the kernel)"),
-    _v("APEX_TPU_FUSED_SAMPLING", "apex_tpu.ops.fused_sampling",
-       "docs/inference.md",
-       "fused sampling kernel routing (kernel|reference|auto)"),
-    _v("APEX_TPU_PAGED_ATTENTION", "apex_tpu.ops.paged_attention",
-       "docs/inference.md",
-       "paged-attention kernel routing (kernel|reference|auto)"),
-    _v("APEX_TPU_GROUPED_MATMUL", "apex_tpu.ops.grouped_matmul",
-       "docs/parallelism.md",
-       "grouped (ragged expert) matmul routing (kernel|reference|auto)"),
-    _v("APEX_TPU_DECODE_FUSED", "apex_tpu.ops.decode_step",
-       "docs/inference.md",
-       "fused decode-layer megakernel routing "
-       "(kernel|reference|auto)"),
-    _v("APEX_TPU_QUANT_MATMUL", "apex_tpu.ops.dense",
-       "docs/inference.md",
-       "weight-only int8 dense/grouped matmul routing "
-       "(kernel|reference|auto)"),
     # ---- serving knobs -----------------------------------------------
     _v("APEX_TPU_CHUNK_TOKENS", "apex_tpu.serving.engine",
        "docs/serving.md",
@@ -159,15 +120,6 @@ def telemetry_names() -> tuple:
 
 
 def lookup(name: str):
-    """Resolve an env-var name against the table: exact match first,
-    then the longest matching ``*`` family.  Returns the
-    :class:`EnvVar` row or ``None`` (unregistered)."""
-    hit = ENV_REGISTRY.get(name)
-    if hit is not None:
-        return hit
-    best = None
-    for key, row in ENV_REGISTRY.items():
-        if key.endswith("*") and name.startswith(key[:-1]):
-            if best is None or len(key) > len(best[0]):
-                best = (key, row)
-    return best[1] if best else None
+    """The :class:`EnvVar` row of ``name``, or ``None`` (unregistered;
+    a name built at run time has no row, so its read is refused)."""
+    return ENV_REGISTRY.get(name)

@@ -482,8 +482,7 @@ class UndocumentedEnvVarRule(Rule):
                         cache[doc] = f.read()
                 except OSError:
                     cache[doc] = ""
-            needle = name[:-1] if name.endswith("*") else name
-            if needle not in cache[doc]:
+            if name not in cache[doc]:
                 yield Finding(
                     rule=self.id, path=doc, line=1, col=1,
                     message=(f"registered env var {name} is not "

@@ -599,11 +599,10 @@ def decode_step(params: dict, token: jax.Array, cache: dict,
     ``decode_fused`` picks the fused decode-layer route
     (``ops/decode_step.py``: rope + attention + output projection in
     one kernel): ``"kernel"``/``"reference"`` pin, ``None``/``"auto"``
-    resolve ``APEX_TPU_DECODE_FUSED`` here and now — jitted callers
-    (``generate``, the serving engine) resolve the route ONCE outside
-    their jit and pass it as a static argument, because an env read at
-    trace time would freeze the first call's route into every cached
-    trace."""
+    resolve here and now (kernel on TPU or in interpret mode) — jitted
+    callers (``generate``, the serving engine) resolve the route ONCE
+    outside their jit and pass it as a static argument, so that a trace
+    made in interpret mode is not replayed outside it."""
     from apex_tpu.ops.decode_step import route_decode_fused
 
     _check_decode_cfg(cfg)
@@ -1148,9 +1147,9 @@ def sample_logits(logits, key, *, temperature: float = 0.0,
     Since ISSUE 8 this is a thin wrapper over
     :func:`apex_tpu.ops.fused_sampling.fused_sample`, which fuses the
     whole temperature → top-k/top-p → draw chain into one kernel on the
-    decode hot path (``APEX_TPU_FUSED_SAMPLING`` routes; the XLA
-    reference path is bit-identical to the historical op sequence
-    given the same key, so seeded callers see no change off-TPU).
+    decode hot path on a TPU (elsewhere the XLA reference, which is
+    bit-identical to the historical op sequence given the same key, so
+    seeded callers see no change off-TPU).
     ``temperature == 0`` short-circuits every filter and returns the
     argmax — the cutoffs cannot change which token is largest
     (regression-pinned in tests/test_fused_sampling.py).
@@ -1275,12 +1274,11 @@ def generate(
 
     The decode layer routes through the FUSED decode step
     (``ops/decode_step.py``: rope + attention + output projection in
-    one kernel, ``APEX_TPU_DECODE_FUSED=kernel|reference|auto``) —
-    greedy output is token-identical across routes on both layouts and
-    both ``cache_wire`` forms (tests/test_decode_fused.py pins it);
-    the route is resolved here, outside the jit, and threaded as a
-    static argument so env flips retrace instead of replaying a stale
-    trace.
+    one kernel on a TPU, the XLA composition elsewhere) — greedy output
+    is token-identical across routes on both layouts and both
+    ``cache_wire`` forms (tests/test_decode_fused.py pins it); the
+    route is resolved here, outside the jit, and threaded as a static
+    argument.
 
     ``cache_layout="paged"`` runs the same prefill + while-loop decode
     over the block-pool cache (``block_size`` tokens per block, tables
@@ -1356,8 +1354,7 @@ def generate(
         return tokens
     # resolve the fused-decode route HERE, outside the jit: threading
     # the resolved route through the static args keys the trace cache
-    # on it, so flipping APEX_TPU_DECODE_FUSED between calls retraces
-    # instead of replaying the first call's frozen route
+    # on it (interpret mode on or off retraces)
     from apex_tpu.ops.decode_step import route_decode_fused
 
     tokens, n_steps = _generate_impl(

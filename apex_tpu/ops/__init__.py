@@ -2,7 +2,8 @@
 
 Reference equivalents live in csrc/ and apex/contrib/csrc/ (see SURVEY.md
 §2.2-2.3). Every op has a pure-jnp/lax implementation (always available,
-XLA-fused) and, where profitable, a Pallas TPU kernel behind the op registry.
+XLA-fused) and, where profitable, a Pallas TPU kernel; ``_pallas_utils``
+decides which of the two runs.
 """
 
 from apex_tpu.ops.dense import (  # noqa: F401

@@ -29,9 +29,9 @@ residency (ROADMAP item 4's kernel half):
 tpu.ops.paged_attention.ragged_paged_attention` → matmul), numerically
 the exact op sequence ``models/generate._layer_decode_paged`` ran
 before this op existed — the always-available fallback and the parity
-oracle.  ``APEX_TPU_DECODE_FUSED=kernel|reference|auto`` routes exactly
-like flash/paged/grouped (auto → kernel on TPU or under
-``APEX_TPU_PALLAS_INTERPRET=1``), and ``backend=`` pins a path.
+oracle.  The route is ``_pallas_utils.resolve_backend``'s like
+paged/grouped (kernel on TPU or under ``APEX_TPU_PALLAS_INTERPRET=1``),
+and ``backend=`` pins a path.
 
 VMEM budget note: the projection weight is held fully resident
 (``nh·dh·h_out`` elements) next to one K/V block — the decode-layer
@@ -53,7 +53,6 @@ block_size, kv_groups, dh]``, ``block_tables`` ``[b, max_blocks]``
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -61,10 +60,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from apex_tpu.ops._pallas_utils import LANES as _LANES
+from apex_tpu.ops._pallas_utils import on_tpu, resolve_backend
 from apex_tpu.ops.paged_attention import (
     _check_paged_shapes, ragged_paged_attention)
 from apex_tpu.ops.rope import _rope
-from apex_tpu.utils.registry import on_tpu
 
 __all__ = ["fused_decode_layer", "decode_layer_reference",
            "route_decode_fused"]
@@ -100,23 +99,11 @@ def _check_fused_shapes(q, w_proj, rope_cos, rope_sin):
 
 
 def route_decode_fused(backend: Optional[str]) -> str:
-    """Resolve the fused-decode-layer route: ``APEX_TPU_DECODE_FUSED=
-    kernel|reference|auto`` overrides, auto picks the kernel on TPU /
-    under ``APEX_TPU_PALLAS_INTERPRET=1`` — the flash/paged/grouped
-    pattern.  Exposed so ``models/generate`` can resolve the route ONCE
-    at the Python level and thread it through its jit static args (a
-    trace-time env read would pin the first call's route into every
-    cached trace)."""
-    if backend is None:
-        backend = os.environ.get("APEX_TPU_DECODE_FUSED", "auto")
-    if backend not in ("auto", "kernel", "reference"):
-        raise ValueError(
-            f"fused decode backend={backend!r} (APEX_TPU_DECODE_FUSED): "
-            "expected auto|kernel|reference")
-    if backend == "auto":
-        interp = os.environ.get("APEX_TPU_PALLAS_INTERPRET", "0") == "1"
-        backend = "kernel" if (on_tpu() or interp) else "reference"
-    return backend
+    """The fused-decode-layer route (``resolve_backend``).  Exposed so
+    ``models/generate`` and the serving engine resolve it ONCE at the
+    Python level and thread it through their jit static args: a jitted
+    body traced in interpret mode must not be replayed outside it."""
+    return resolve_backend("fused decode layer", backend)
 
 
 def decode_layer_reference(q, k_pool, v_pool, block_tables, lengths,
@@ -361,9 +348,9 @@ def fused_decode_layer(
     ``q.dtype`` (projection bias, residual and MLP stay with the
     caller — they are cheap elementwise/GEMM ops XLA already fuses).
 
-    ``backend``: ``None`` routes via ``APEX_TPU_DECODE_FUSED``
-    (auto → kernel on TPU or under ``APEX_TPU_PALLAS_INTERPRET=1``,
-    reference otherwise); ``"kernel"`` / ``"reference"`` pin a path —
+    ``backend``: ``None`` routes automatically (kernel on TPU or
+    under ``APEX_TPU_PALLAS_INTERPRET=1``, reference otherwise);
+    ``"kernel"`` / ``"reference"`` pin a path —
     the parity suite (tests/test_decode_fused.py) compares the two.
 
     Inference-only by design (no custom VJP), like the paged-attention

@@ -30,9 +30,9 @@ step dequantizes its ``[kb, out]`` tile in VMEM (one multiply by the
 tile's scale row after the int8 dot) — the fp32 weights never exist in
 HBM and the per-token weight read drops to ~1/4 (fp32) or ~1/2 (bf16)
 of the raw bytes.  The XLA reference path dequantizes whole slabs (the
-parity oracle); ``APEX_TPU_QUANT_MATMUL=kernel|reference|auto`` routes
-like every other op here.  ``custom_vjp`` keeps the backward in high
-precision: ``dx`` is computed against the fp32-dequantized weights, the
+parity oracle); ``backend=`` routes like every other op here
+(``_pallas_utils.resolve_backend``).  ``custom_vjp`` keeps the backward
+in high precision: ``dx`` is computed against the fp32-dequantized weights, the
 frozen wire/scales get zero cotangents (weight-only quantization is a
 serving conversion — nothing trains through it).
 """
@@ -40,7 +40,6 @@ serving conversion — nothing trains through it).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -48,10 +47,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from apex_tpu.ops._pallas_utils import on_tpu, resolve_backend
+
 __all__ = ["QUANT_BLOCK", "dense_quantized", "dequantize_weight",
            "fused_dense_function", "fused_dense_gelu_dense_function",
            "is_quantized", "pick_quant_block", "quantize_weight",
-           "quantized_matmul", "route_quant_backend"]
+           "quantized_matmul"]
 
 
 def _matmul(x, w):
@@ -182,28 +183,6 @@ def dequantize_weight(wire, scale):
     return (wf * scale[:, None]).reshape(wire.shape)
 
 
-# -- routing (the flash/paged/grouped pattern) ------------------------------
-
-
-def route_quant_backend(backend: Optional[str]) -> str:
-    """Resolve the quantized-matmul route (shared by the dense path
-    here and the grouped slab path in ``ops/grouped_matmul.py``):
-    ``APEX_TPU_QUANT_MATMUL=kernel|reference|auto`` overrides, auto
-    picks the kernel on TPU / under ``APEX_TPU_PALLAS_INTERPRET=1``."""
-    from apex_tpu.utils.registry import on_tpu
-
-    if backend is None:
-        backend = os.environ.get("APEX_TPU_QUANT_MATMUL", "auto")
-    if backend not in ("auto", "kernel", "reference"):
-        raise ValueError(
-            f"quantized matmul backend={backend!r}: expected "
-            "auto|kernel|reference")
-    if backend == "auto":
-        interp = os.environ.get("APEX_TPU_PALLAS_INTERPRET", "0") == "1"
-        backend = "kernel" if (on_tpu() or interp) else "reference"
-    return backend
-
-
 # -- Pallas kernel ----------------------------------------------------------
 
 _ROW_BLOCK = 128
@@ -260,11 +239,9 @@ def _dq_pallas(x, wire, scale, kb, interpret):
 
 
 def _dq_impl(x2, wire2, scale2, kb, backend):
-    from apex_tpu.utils.registry import on_tpu
-
     if x2.shape[0] == 0:
         return jnp.zeros((0, wire2.shape[1]), x2.dtype)
-    if route_quant_backend(backend) == "reference":
+    if resolve_backend("quantized matmul", backend) == "reference":
         deq = dequantize_weight(wire2, scale2)
         out = jax.lax.dot(x2.astype(jnp.float32), deq,
                           preferred_element_type=jnp.float32)
@@ -307,8 +284,8 @@ def dense_quantized(x, wire, scale, *, backend: Optional[str] = None):
 
     ``wire`` int8 ``[in, *out]`` + ``scale`` fp32 ``[in/kb, *out]``
     from :func:`quantize_weight`.  ``backend`` routes like every other
-    op (``APEX_TPU_QUANT_MATMUL``): the Pallas kernel dequantizes each
-    ``[kb, out]`` tile in its inner loop; the reference dequantizes the
+    op (``_pallas_utils.resolve_backend``): the Pallas kernel dequantizes
+    each ``[kb, out]`` tile in its inner loop; the reference dequantizes the
     whole slab in XLA — the parity oracle, and exactly what a
     fake-quantized float model computes (the dequantize-then-generate
     pin in tests/test_quantized_matmul.py)."""

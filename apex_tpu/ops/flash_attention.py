@@ -46,7 +46,9 @@ Variants:
 
 Backward: custom_vjp with the standard two-kernel scheme — dq accumulates
 over kv blocks, dk/dv over q blocks, both recomputing the probabilities
-from the saved logsumexp (no O(s²) residuals).
+from the saved logsumexp (no O(s²) residuals) — or, where the padded keys
+fit VMEM whole, one fused pass that computes them once; the static shape
+decides (:func:`_bwd_plan`).
 
 Under remat: the forward rule tags its two kernel-made residuals, the
 output ``o`` and the sliced logsumexp ``lse`` ([b·h, sq] f32), with
@@ -61,7 +63,6 @@ gone at lowering.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -70,8 +71,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops._pallas_utils import LANES as _LANES, out_struct
-from apex_tpu.utils.registry import on_tpu
+from apex_tpu.ops._pallas_utils import LANES as _LANES, on_tpu, out_struct
 
 __all__ = ["causal_work_share", "flash_attention", "flash_attention_packed",
            "mha_reference", "segment_ids_from_cu_seqlens"]
@@ -983,7 +983,7 @@ def _from_bh(x3, b, n):
 
 def _blocks(sq, sk):
     """Grid tile of the score rectangle: 1024 x 1024 from 1024 keys on,
-    256 x 512 below (256 x 512 best at s512 in the round-5 sweep;
+    256 x 512 below (other tiles at s512: not measured this round;
     1024 x 2048 once failed to compile on VMEM, so 1024 caps both).
 
     The large tile amortizes the per-grid-step cost and fetches K/V once
@@ -1009,6 +1009,29 @@ def _blocks(sq, sk):
     bq = min(1024 if sq >= 1024 else 256, pl.cdiv(sq, _LANES) * _LANES)
     bk = min(1024 if sk >= 1024 else 512, pl.cdiv(sk, _LANES) * _LANES)
     return bq, bk
+
+
+# The longest padded key length that takes the fused single-pass backward
+# (K/V whole in VMEM, p computed once for dq, dk and dv); longer keys take
+# the split pair.  The ledger holds each side where a cell runs it: BERT
+# s512 fused, `flash_bwd_ms` 7.37 a step; GPT s1024 split, 22.86 (PR 31).
+# PERF.md section 7(b) has the fused kernel at s1024, for the perf_opt
+# that moves this constant.
+_FUSED_BWD_MAX_SK = 512
+# The fused backward's q block, where it divides the padded query length
+# (smaller blocks at s512: not measured this round).
+_FUSED_BWD_BQ = 512
+
+
+def _bwd_plan(sqp, skp, block_q):
+    """Which backward runs, from the static padded shape alone:
+    ``("fused", q block)`` or ``("split", None)``."""
+    if skp > _FUSED_BWD_MAX_SK:
+        return "split", None
+    bq = min(_FUSED_BWD_BQ, sqp)
+    # block_q set the padding, so it always divides sqp (a floor-division
+    # grid would drop tail q rows)
+    return "fused", bq if sqp % bq == 0 else block_q
 
 
 def _sub_tile(block_q, block_k):
@@ -1125,34 +1148,8 @@ def _flash_bwd(causal, scale, dropout_p, res, do):
         None if seg3 is None else seg3[0],
         None if seg3 is None else seg3[1], seed)
     seg3 = None if seg3 is None else (seg3q, seg3k)
-    mode = os.environ.get("APEX_TPU_FLASH_BWD", "auto")
-    if mode not in ("auto", "fused", "split"):
-        raise ValueError(
-            f"APEX_TPU_FLASH_BWD={mode!r}: expected auto|fused|split")
-    # auto routes the short-key class (sk<=512) to the fused single-pass
-    # backward: the round-5 on-chip sweep (first silicon after the
-    # round-3/4 outage) measured fused beating the split pair at every
-    # swept q-block for s512 — causal 531.7us vs 708.0us, non-causal
-    # 569.0us vs 821.6us at bq=512 (tools/sweep_r4.py, SWEEP log
-    # 2026-07-31) — and improving monotonically with bq.  Above 512 the
-    # split pair keeps the s1024/s2048 wins from the round-3 retune
-    # until tools/sweep_r5.py measures the fused kernel there.
-    fused_max = int(os.environ.get("APEX_TPU_FLASH_BWD_FUSED_MAX", "512"))
-    if mode == "fused" or (mode == "auto" and skp <= fused_max):
-        # short-key class (BERT s512 etc.): K/V fit VMEM whole — one
-        # pass computes p once and emits dq/dk/dv together, vs the
-        # split kernels' two passes with p recomputed in each.  q-block
-        # default 512: the round-5 sweep improved monotonically with bq
-        # (128: 671us, 256: 581us, 512: 532us at s512 causal)
-        env_bq = os.environ.get("APEX_TPU_FLASH_FUSED_BQ")
-        fused_bq = min(int(env_bq) if env_bq else 512, sqp)
-        if sqp % fused_bq:
-            if env_bq:
-                raise ValueError(
-                    f"APEX_TPU_FLASH_FUSED_BQ={fused_bq} must divide the "
-                    f"padded query length {sqp} (floor-division grids "
-                    "would silently drop tail q-rows)")
-            fused_bq = block_q   # always divides sqp (it set the padding)
+    plan, fused_bq = _bwd_plan(sqp, skp, block_q)
+    if plan == "fused":
         dq3, dk3, dv3 = _bwd_pallas_fused(
             q3, k3, v3, do3, lse3, delta, kpm3, seg3, seed, scale,
             causal, sq, sk, fused_bq, dropout_p,

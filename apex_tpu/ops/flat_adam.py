@@ -7,14 +7,10 @@ TPU-native answer turned out to need no hand-written kernel at all:
 under ``jit`` XLA fuses the whole flat Adam chain (two moment updates,
 the rsqrt, the weight-decay add) into one HBM pass on its own.
 
-A Pallas tile-streaming kernel lived here through round 4
-(``adam_kernel_flat``, swept via ``APEX_TPU_ADAM_BLOCK_ROWS``).  The
-round-5 on-chip sweep was its win-or-delete gate (not measured on
-today's code): 88M fp32, rows=512 → 1.82×, rows=1024 → 1.85× the XLA
-fused update, and rows≥2048 failed to compile — so the kernel and its knob were deleted
-and every optimizer keeps the XLA flat path.
+No Pallas kernel: every optimizer takes the XLA flat path (the whole
+update's device time in each cell: PERF.md section 5).
 
-``adam_kernel_flat`` remains the flat-buffer entry point (the
+``adam_kernel_flat`` is the flat-buffer entry point (the
 ZeRO-sharded DistributedFusedAdam layout calls it on raw 1-D shards);
 ``flat_adam_update`` is the tree-level wrapper kept for the reference's
 ``multi_tensor_apply``-shaped API surface.
@@ -28,8 +24,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
-
-from apex_tpu.utils.registry import register_op
 
 __all__ = ["flat_adam_update", "adam_kernel_flat"]
 
@@ -47,9 +41,7 @@ def adam_kernel_flat(
 
     ``scalars`` = [lr, beta1, beta2, eps, weight_decay, bc1, bc2] (f32[7]).
     Returns (update, new_m, new_v) with the same length as the inputs.
-    XLA fuses the chain into a single pass over HBM (measured round 5:
-    4.02 ms for 88M fp32 on v5e — the deleted Pallas kernel's best
-    setting took 7.33 ms).
+    XLA fuses the chain into a single pass over HBM.
     """
     lr, beta1, beta2, eps, wd, bc1, bc2 = (scalars[i] for i in range(7))
     if not adam_w_mode:
@@ -94,7 +86,3 @@ def flat_adam_update(
     )
     return unravel(u), unravel(m_new), unravel(v_new)
 
-
-register_op(
-    "fused_adam_update", backend="xla", is_available=lambda: True
-)(flat_adam_update)

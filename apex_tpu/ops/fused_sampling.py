@@ -27,12 +27,10 @@ Two execution paths, routed like ``flash_attention`` /
   *distributional* (χ² in tests/test_fused_sampling.py) while greedy
   rows are exact.
 
-``APEX_TPU_FUSED_SAMPLING=kernel|reference|auto`` overrides the route
-(malformed values warn by name and fall back to ``auto``); an
-explicit ``backend=`` argument
-raises on malformed values like the paged-attention gate.  ``auto``
-picks the kernel on TPU or under ``APEX_TPU_PALLAS_INTERPRET=1`` (the
-8-virtual-device CI path) and the reference elsewhere.
+``backend=None`` picks the kernel on TPU or under
+``APEX_TPU_PALLAS_INTERPRET=1`` (the 8-virtual-device CI path) and the
+reference elsewhere (``_pallas_utils.resolve_backend``);
+``"kernel"``/``"reference"`` pin, anything else raises.
 
 ``temperature`` may be a per-sequence ``[b]`` vector (traced — the
 serving engine's mixed-temperature contract): rows at temperature 0
@@ -43,7 +41,6 @@ logits, exactly the engine's historical ``_mixed_sample`` composition.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -51,7 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from apex_tpu.ops._pallas_utils import LANES as _LANES
-from apex_tpu.utils.registry import on_tpu
+from apex_tpu.ops._pallas_utils import on_tpu, resolve_backend
 
 __all__ = ["fused_sample", "filter_logits", "sample_reference",
            "apply_token_mask"]
@@ -306,28 +303,6 @@ def _grid_spec(b, v_padded):
     )
 
 
-def _route(backend: Optional[str]) -> str:
-    if backend is None:
-        backend = os.environ.get("APEX_TPU_FUSED_SAMPLING", "auto")
-        if backend not in ("auto", "kernel", "reference"):
-            # env values warn BY NAME and fall back: a typo'd
-            # deployment var must not take the whole decode path down
-            from apex_tpu.utils.logging import get_logger
-
-            get_logger("ops").warning(
-                "APEX_TPU_FUSED_SAMPLING=%r is not one of "
-                "auto|kernel|reference; falling back to auto", backend)
-            backend = "auto"
-    elif backend not in ("auto", "kernel", "reference"):
-        raise ValueError(
-            f"fused sampling backend={backend!r}: expected "
-            "auto|kernel|reference")
-    if backend == "auto":
-        interp = os.environ.get("APEX_TPU_PALLAS_INTERPRET", "0") == "1"
-        backend = "kernel" if (on_tpu() or interp) else "reference"
-    return backend
-
-
 def fused_sample(
     logits: jax.Array,
     key: jax.Array,
@@ -347,10 +322,9 @@ def fused_sample(
     per-sequence temperatures (rows at 0 are greedy).  ``top_k`` /
     ``top_p`` / ``vocab_limit`` are static.  ``backend``: ``None``
     routes automatically (fused Pallas kernel on TPU or under
-    ``APEX_TPU_PALLAS_INTERPRET=1``; XLA reference otherwise;
-    ``APEX_TPU_FUSED_SAMPLING`` overrides, malformed values warn by
-    name), ``"kernel"`` / ``"reference"`` pin a path — the parity
-    suite compares the two.
+    ``APEX_TPU_PALLAS_INTERPRET=1``; XLA reference otherwise),
+    ``"kernel"`` / ``"reference"`` pin a path — the parity suite
+    compares the two.
 
     Distribution contract: the reference path is bit-identical to the
     historical ``sample_logits`` given the same key; the kernel path
@@ -373,7 +347,7 @@ def fused_sample(
             f"temperature={temperature}: negative temperatures would "
             "silently invert the distribution; pass 0 for greedy or a "
             "positive value")
-    if _route(backend) == "reference":
+    if resolve_backend("fused sampling", backend) == "reference":
         return sample_reference(logits, key, temperature=temperature,
                                 top_k=top_k, top_p=top_p,
                                 vocab_limit=vocab_limit)

@@ -26,9 +26,9 @@ Two implementations behind one route (the flash/paged-attention pattern):
   (``G`` dense matmuls), trivially correct and differentiable; the parity
   oracle and the CPU path.
 
-``APEX_TPU_GROUPED_MATMUL=kernel|reference|auto`` overrides the route;
-``auto`` picks the kernel on TPU (or under ``APEX_TPU_PALLAS_INTERPRET=1``)
-and the reference elsewhere.
+``backend=None`` picks the kernel on TPU (or under
+``APEX_TPU_PALLAS_INTERPRET=1``) and the reference elsewhere
+(``_pallas_utils.resolve_backend``); ``"kernel"``/``"reference"`` pin.
 
 ``offsets`` may describe a *window*: ``offsets[0] > 0`` / ``offsets[-1] <
 N`` leave the rows outside ``[offsets[0], offsets[-1])`` exactly zero in
@@ -49,7 +49,6 @@ reference route both are masked XLA products over all rows.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -57,8 +56,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops._pallas_utils import out_struct, param_cotangent
-from apex_tpu.utils.registry import on_tpu
+from apex_tpu.ops._pallas_utils import (
+    on_tpu,
+    out_struct,
+    param_cotangent,
+    resolve_backend,
+)
 
 __all__ = ["grouped_matmul", "grouped_matmul_quantized",
            "grouped_matmul_reference", "group_ids",
@@ -422,26 +425,13 @@ def _tgmm_pallas(x, dy, offsets, out_dtype, interpret):
 # ---------------------------------------------------------------------------
 
 
-def _route(backend: Optional[str]) -> str:
-    if backend is None:
-        backend = os.environ.get("APEX_TPU_GROUPED_MATMUL", "auto")
-    if backend not in ("auto", "kernel", "reference"):
-        raise ValueError(
-            f"grouped_matmul backend={backend!r}: expected "
-            "auto|kernel|reference")
-    if backend == "auto":
-        interp = os.environ.get("APEX_TPU_PALLAS_INTERPRET", "0") == "1"
-        backend = "kernel" if (on_tpu() or interp) else "reference"
-    return backend
-
-
 def _gmm_impl(x, w, offsets, backend, transpose_rhs=False):
     """``x @ w[g]`` per row, or ``x @ w[g]^T`` (the input gradient, which
     reads the expert slab as it lies instead of a transposed copy)."""
     p = w.shape[1] if transpose_rhs else w.shape[2]
     if x.shape[0] == 0:
         return jnp.zeros((0, p), jnp.result_type(x, w))
-    if _route(backend) == "reference":
+    if resolve_backend("grouped_matmul", backend) == "reference":
         return grouped_matmul_reference(
             x, w.swapaxes(1, 2) if transpose_rhs else w, offsets)
     with jax.named_scope("gmm_dx" if transpose_rhs else "gmm_fwd"):
@@ -473,7 +463,7 @@ def _grouped_dw(x, g, offsets, out_dtype, backend):
     if x.shape[0] == 0:
         return jnp.zeros((offsets.shape[0] - 1, x.shape[1], g.shape[1]),
                          out_dtype)
-    if _route(backend) == "reference":
+    if resolve_backend("grouped_matmul", backend) == "reference":
         return _grouped_dw_reference(x, g, offsets).astype(out_dtype)
     with jax.named_scope("gmm_dw"):
         return _tgmm_pallas(x, g.astype(x.dtype), offsets, out_dtype,
@@ -515,8 +505,8 @@ def grouped_matmul(x: jax.Array, w: jax.Array, offsets: jax.Array, *,
 
     ``backend``: ``None`` routes automatically (Pallas kernel on TPU or
     under ``APEX_TPU_PALLAS_INTERPRET=1``; XLA segment-sum reference
-    otherwise; ``APEX_TPU_GROUPED_MATMUL`` overrides), ``"kernel"`` /
-    ``"reference"`` pin a path — the parity suite compares the two.
+    otherwise), ``"kernel"`` / ``"reference"`` pin a path — the parity
+    suite compares the two.
 
     Differentiable: ``dx`` re-enters the routed primitive with the
     weights transposed (kernel backward stays a kernel), ``dw`` runs as
@@ -566,11 +556,10 @@ def _dequantize_group(wire, scale):
 
 
 def _gmmq_impl(x, wire, scale, offsets, backend):
-    from apex_tpu.ops.dense import route_quant_backend
-
     if x.shape[0] == 0:
         return jnp.zeros((0, wire.shape[-1]), x.dtype)
-    if route_quant_backend(backend) == "reference":
+    if resolve_backend("quantized grouped_matmul",
+                       backend) == "reference":
         return grouped_matmul_reference(
             x, _dequantize_group(wire, scale), offsets).astype(x.dtype)
     return _gmm_pallas(x, wire, offsets, interpret=not on_tpu(),
@@ -617,8 +606,8 @@ def grouped_matmul_quantized(x: jax.Array, wire: jax.Array,
     group index also dereferences the slab's scale rows, and each
     step's ``[k, p]`` expert tile dequantizes in VMEM before its dot —
     the HBM weight read per step is the int8 bytes, which is the
-    decode-bandwidth win.  ``APEX_TPU_QUANT_MATMUL`` routes (shared
-    with ``ops/dense.dense_quantized``); the XLA reference dequantizes
+    decode-bandwidth win.  ``backend`` routes as in
+    ``ops/dense.dense_quantized``; the XLA reference dequantizes
     the whole slab — the parity oracle.  Backward stays high-precision
     (``dx`` against fp32 dequantized weights; wire/scales frozen)."""
     if x.ndim != 2 or wire.ndim != 3 or offsets.ndim != 1:

@@ -31,8 +31,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops._pallas_utils import out_struct, param_cotangent
-from apex_tpu.utils.registry import on_tpu
+from apex_tpu.ops._pallas_utils import (
+    interpret_forced,
+    on_tpu,
+    out_struct,
+    pallas_ok,
+    param_cotangent,
+)
 
 __all__ = [
     "fused_layer_norm",
@@ -115,13 +120,10 @@ def _ln_fwd_kernel(rms: bool, affine: bool, has_bias: bool, eps: float,
 def _ln_bwd_kernel(rms: bool, affine: bool, has_bias: bool, *refs):
     """dx plus dγ/dβ accumulated into one revisited (1, hidden) tile.
 
-    A round-4 "split partials" variant wrote per-block dγ/dβ rows for a
-    trailing XLA sum instead; it was deleted in round 5 — Mosaic rejects
-    its (1, hidden) output block over a (n_blocks, hidden) array (last
-    two block dims must be (8k, 128k) or equal the array's), and the
-    revisit kernel it was meant to replace *wins* on silicon anyway
-    (fwd+bwd 16384x768 bf16: 108.8us vs the XLA chain's 150.1us, round-5
-    sweep)."""
+    Per-block dγ/dβ rows for a trailing XLA sum are not an alternative:
+    Mosaic rejects a (1, hidden) output block over a (n_blocks, hidden)
+    array (the last two block dims must be (8k, 128k) or the array's).
+    Against the XLA chain: not measured this round."""
     if affine:
         if has_bias:
             (dy_ref, x_ref, w_ref, mu_ref, rs_ref,
@@ -164,20 +166,13 @@ def _ln_bwd_kernel(rms: bool, affine: bool, has_bias: bool, *refs):
 
 
 def _pallas_ok(hidden: int, dtype) -> bool:
-    import os
-
-    from apex_tpu.ops._pallas_utils import pallas_ok
-
-    if not pallas_ok("fused_layer_norm", hidden, dtype):
+    """Whether the kernels (forward and backward alike) take this width
+    and dtype; the XLA composition runs otherwise.  On a TPU, 16-bit
+    inputs only (fp32 against XLA's fused chain: not measured this
+    round); interpret mode keeps every dtype for test coverage."""
+    if not pallas_ok(hidden, dtype):
         return False
-    # Measured on v5e (bench_kernels.py round 3): the Pallas forward wins
-    # for 16-bit inputs (bf16 16384x768: 36us vs 78us) but loses at fp32
-    # (74us vs 49us — fp32 doubles the VMEM tile traffic while XLA fuses
-    # the fp32 chain).  Interpret mode keeps every dtype for test
-    # coverage.
-    if os.environ.get("APEX_TPU_PALLAS_INTERPRET", "0") == "1":
-        return True
-    return dtype in (jnp.bfloat16, jnp.float16)
+    return interpret_forced() or dtype in (jnp.bfloat16, jnp.float16)
 
 
 def _pad_rows(x2, br):
@@ -344,29 +339,6 @@ def _norm_fwd(x, weight, bias, eps, rms, memory_efficient):
     return y2.reshape(shape), (saved_x, saved_y, weight, bias, mu, rs, shape)
 
 
-def _ln_bwd_mode(hidden, dtype) -> Optional[str]:
-    """Backward backend gate.  Two earlier sweeps on a v5e disagreed on
-    which side wins the fwd+bwd chain at 16384x768 bf16 (the later one
-    favoured the Pallas revisit kernel); not measured on today's code.
-    Default is the Pallas backward wherever the Pallas forward is
-    eligible;
-    ``APEX_TPU_LN_BWD=xla`` opts back into the XLA composition (and is
-    what sweep_r4 measures against)."""
-    import os
-
-    mode = os.environ.get("APEX_TPU_LN_BWD")
-    if mode == "xla":
-        return None
-    if mode not in (None, "", "pallas"):
-        raise ValueError(
-            f"APEX_TPU_LN_BWD={mode!r}: expected pallas|xla (the round-4 "
-            "pallas_split variant was deleted in round 5 — Mosaic rejects "
-            "its partials block spec and the revisit kernel wins on chip)")
-    if _pallas_ok(hidden, dtype):
-        return "pallas"
-    return None
-
-
 def _norm_bwd(eps, rms, memory_efficient, res, dy):
     saved_x, saved_y, weight, bias, mu, rs, shape = res
     hidden = shape[-1]
@@ -391,8 +363,7 @@ def _norm_bwd(eps, rms, memory_efficient, res, dy):
     else:
         x2 = saved_x
 
-    bwd_mode = _ln_bwd_mode(hidden, x2.dtype)
-    if bwd_mode is not None:
+    if _pallas_ok(hidden, x2.dtype):
         with jax.named_scope("layer_norm_bwd"):
             dx, dw, db = _ln_bwd_pallas(
                 dy2, x2, weight, mu, rs, rms, bias is not None

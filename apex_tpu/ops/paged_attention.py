@@ -22,13 +22,11 @@ GQA folds the query heads as ``[groups, rep]`` against the group-width
 pool exactly like the dense decode path — repeated K/V is never
 materialized.
 
-Routing mirrors the rest of ``apex_tpu.ops`` (flash_attention's
-gate specialized to the decode shape): the fused kernel runs on TPU
-(or under ``APEX_TPU_PALLAS_INTERPRET=1``, the 8-virtual-device CI
+Routing is ``_pallas_utils.resolve_backend``'s: the fused kernel runs
+on TPU (or under ``APEX_TPU_PALLAS_INTERPRET=1``, the 8-virtual-device CI
 path); everywhere else the XLA gather-based :func:`paged_attention_
 reference` — always available, numerics oracle for the parity tests —
-executes instead.  ``APEX_TPU_PAGED_ATTENTION=kernel|reference|auto``
-overrides, and the ``backend=`` argument pins a path explicitly
+executes instead.  The ``backend=`` argument pins a path explicitly
 (the kernel parity suite compares the two).
 
 Layout contract (shared with ``serving/paged_cache.py``):
@@ -46,7 +44,6 @@ Layout contract (shared with ``serving/paged_cache.py``):
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -54,7 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from apex_tpu.ops._pallas_utils import LANES as _LANES
-from apex_tpu.utils.registry import on_tpu
+from apex_tpu.ops._pallas_utils import on_tpu, resolve_backend
 
 __all__ = ["ragged_paged_attention", "paged_attention_reference"]
 
@@ -294,19 +291,6 @@ def _paged_pallas(q, k_pool, v_pool, block_tables, lengths, scale,
     )(tbl, lens, *inputs)
 
 
-def _route(backend: Optional[str]) -> str:
-    if backend is None:
-        backend = os.environ.get("APEX_TPU_PAGED_ATTENTION", "auto")
-    if backend not in ("auto", "kernel", "reference"):
-        raise ValueError(
-            f"paged attention backend={backend!r}: expected "
-            "auto|kernel|reference")
-    if backend == "auto":
-        interp = os.environ.get("APEX_TPU_PALLAS_INTERPRET", "0") == "1"
-        backend = "kernel" if (on_tpu() or interp) else "reference"
-    return backend
-
-
 def ragged_paged_attention(
     q: jax.Array,
     k_pool: jax.Array,
@@ -334,8 +318,8 @@ def ragged_paged_attention(
 
     ``backend``: ``None`` routes automatically (fused Pallas kernel on
     TPU or under ``APEX_TPU_PALLAS_INTERPRET=1``; XLA gather reference
-    otherwise; ``APEX_TPU_PAGED_ATTENTION`` overrides), ``"kernel"`` /
-    ``"reference"`` pin a path — the parity suite compares the two.
+    otherwise), ``"kernel"`` / ``"reference"`` pin a path — the parity
+    suite compares the two.
 
     Inference-only by design (no custom VJP): nothing differentiates
     through the serving decode step, and keeping the kernel
@@ -345,7 +329,7 @@ def ragged_paged_attention(
                         k_scale, v_scale)
     dh = q.shape[-1]
     scale = (1.0 / dh ** 0.5) if scale is None else float(scale)
-    if _route(backend) == "reference":
+    if resolve_backend("paged attention", backend) == "reference":
         return paged_attention_reference(
             q, k_pool, v_pool, block_tables, lengths, scale=scale,
             k_scale=k_scale, v_scale=v_scale)

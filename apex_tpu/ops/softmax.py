@@ -31,11 +31,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from apex_tpu.ops._pallas_utils import (
+    on_tpu,
     out_struct,
     pad_rows,
     pallas_ok,
 )
-from apex_tpu.utils.registry import on_tpu
 
 __all__ = [
     "scaled_softmax",
@@ -100,10 +100,6 @@ def _softmax_kernel(scale, causal, sq, has_mask, *refs):
     y_ref[:] = y.astype(y_ref.dtype)
 
 
-def _pallas_ok(sk: int, dtype) -> bool:
-    return pallas_ok("fused_softmax", sk, dtype)
-
-
 def _softmax_fwd_pallas(x, scale, mask, causal):
     from jax.experimental.pallas import tpu as pltpu
 
@@ -151,18 +147,11 @@ def _use_pallas(x, mask, causal):
     # broadcast strides instead, so route those to the reference path.
     if mask is not None and mask.shape != x.shape:
         return False
-    # Measured crossover on v5e (bench_kernels.py, round 3): the Pallas
-    # row kernel wins at sk<=512 (causal fwd 32x16x512x512: 0.65x) but
-    # loses to the XLA composition at sk=1024 (1.19x fwd) — the larger
-    # rows blow past the VMEM-friendly tile and XLA's fusion with the
-    # surrounding matmuls dominates.  APEX_TPU_SOFTMAX=pallas forces the
-    # kernel at any size.
-    import os
-
-    if (x.shape[-1] > 512
-            and os.environ.get("APEX_TPU_SOFTMAX") != "pallas"):
+    # rows past 512 go to the XLA composition, which fuses with the
+    # matmuls around it (the crossover: not measured this round)
+    if x.shape[-1] > 512:
         return False
-    return _pallas_ok(x.shape[-1], x.dtype) and (
+    return pallas_ok(x.shape[-1], x.dtype) and (
         not causal or x.shape[-2] == x.shape[-1]
     )
 
@@ -175,20 +164,11 @@ def _scaled_softmax(x, mask, scale, causal):
 
 
 def _scaled_softmax_fwd(x, mask, scale, causal):
-    # Under differentiation the XLA composition wins outright: the bwd is
-    # pure elementwise+reduce that XLA fuses across the fwd/bwd boundary,
-    # and an opaque Pallas fwd call in the middle forces the y tensor
-    # through HBM twice (an early v5e sweep: 1.96x the XLA chain at
-    # 512^2 causal; not measured on today's code).  The Pallas row
-    # kernel stays the primal (fwd-only) path, where it measured 0.65x.
-    # APEX_TPU_SOFTMAX=pallas forces the kernel here too.
-    import os
-
-    if (os.environ.get("APEX_TPU_SOFTMAX") == "pallas"
-            and _use_pallas(x, mask, causal)):
-        y = _softmax_fwd_pallas(x, scale, mask, causal)
-    else:
-        y = _softmax_fwd_ref(x, scale, mask, causal)
+    # Under differentiation the XLA composition runs: its backward fuses
+    # across the fwd/bwd boundary, where an opaque kernel in the middle
+    # sends y through HBM twice (not measured this round).  The Pallas
+    # row kernel is the primal (forward-only) path.
+    y = _softmax_fwd_ref(x, scale, mask, causal)
     return y, y
 
 

@@ -52,7 +52,7 @@ from apex_tpu.utils.collectives import (
     ppermute as _ppermute,
     vma_of,
 )
-from apex_tpu.utils.registry import on_tpu
+from apex_tpu.ops._pallas_utils import on_tpu
 
 __all__ = ["ring_attention"]
 

@@ -247,7 +247,7 @@ def _expert_matmul(xs, w, offsets, dtype, backend):
 
         # the caller's backend pin carries through (a parity run that
         # pinned the reference must not get the kernel's summation
-        # order); None keeps the APEX_TPU_QUANT_MATMUL/auto routing
+        # order); None keeps the automatic routing
         return grouped_matmul_quantized(
             xs.astype(dtype), w["wire"], w["scale"], offsets,
             backend=backend)

@@ -1,8 +1,1 @@
 from apex_tpu.utils.logging import get_logger, set_logging_level  # noqa: F401
-from apex_tpu.utils.registry import (  # noqa: F401
-    OpImpl,
-    OpRegistry,
-    get_op,
-    registry,
-    register_op,
-)

@@ -43,6 +43,7 @@ memory_stats.
 """
 
 import dataclasses
+import functools
 import json
 
 import jax
@@ -387,20 +388,20 @@ def bench_decode_fused(on_tpu, modes=("off", "on")):
     stages + XLA glue (reference: rope, ragged paged attention, output
     projection — each round-tripping activations through HBM) vs ONE
     fused Pallas launch with one VMEM residency
-    (``ops/decode_step.py``, ``APEX_TPU_DECODE_FUSED``).
+    (``ops/decode_step.py``; each mode pins ``decode_step``'s
+    ``decode_fused=``).
 
-    Two measurements per mode: the end-to-end greedy decode per-token
-    ms (the serving-shaped number, prefill subtracted like
-    ``bench_decode``), and the STRUCTURAL per-layer ledger from the
-    traced jaxprs — total equations (the glue XLA must schedule
+    Two measurements per mode: the greedy decode per-token ms (one
+    jitted ``decode_step`` a token after one prefill, as the serving
+    engine dispatches them), and the STRUCTURAL per-layer ledger from
+    the traced jaxprs — total equations (the glue XLA must schedule
     around) and ``pallas_call`` launch sites.  Off-TPU the kernel runs
     under the Pallas interpreter, so the wall-clock column measures
     interpreter overhead, not fusion wins — the honest CPU signal is
     the op/launch delta; the ms column becomes meaningful on the chip
     (``tools/measure_all.py bench_decode_fused`` runs it there)."""
-    import os as _os
-
-    from apex_tpu.models.generate import generate, init_kv_cache, prefill
+    from apex_tpu.models.generate import (
+        decode_step, init_kv_cache, prefill)
     from apex_tpu.models.transformer_lm import init_gpt_params
     from apex_tpu.ops.decode_step import (
         decode_layer_reference, fused_decode_layer)
@@ -422,11 +423,14 @@ def bench_decode_fused(on_tpu, modes=("off", "on")):
     tokens = jnp.asarray(rng.randint(0, cfg.vocab_size, (batch, prompt)),
                          jnp.int32)
 
-    # prefill is route-independent: time it once, subtract per mode
-    def run_prefill(_):
+    def prefilled():
         cache = init_kv_cache(cfg, batch, prompt + new,
                               cache_layout="paged")
-        lg, _cache = prefill(params, tokens, cfg, cache=cache)
+        return prefill(params, tokens, cfg, cache=cache)
+
+    # prefill is route-independent: time it once, subtract per mode
+    def run_prefill(_):
+        lg, _cache = prefilled()
         return (lg, lg)
 
     pf_sec = _time_fn(run_prefill, n_warmup=1,
@@ -441,21 +445,19 @@ def bench_decode_fused(on_tpu, modes=("off", "on")):
     }
     for mode in modes:
         route = "kernel" if mode == "on" else "reference"
-        old = _os.environ.get("APEX_TPU_DECODE_FUSED")
-        _os.environ["APEX_TPU_DECODE_FUSED"] = route
-        try:
-            def run(_):
-                got = generate(params, tokens, cfg, max_new_tokens=new,
-                               cache_layout="paged")
-                return (got, got)
+        step = jax.jit(functools.partial(decode_step, cfg=cfg,
+                                         decode_fused=route))
 
-            sec = _time_fn(run, n_warmup=1, iters=5 if on_tpu else 2,
-                           name=f"decode_fused_{mode}")
-        finally:
-            if old is None:
-                _os.environ.pop("APEX_TPU_DECODE_FUSED", None)
-            else:
-                _os.environ["APEX_TPU_DECODE_FUSED"] = old
+        def run(_):
+            logits, cache = prefilled()
+            for _i in range(new):
+                logits, cache = step(
+                    params, jnp.argmax(logits, -1).astype(jnp.int32),
+                    cache)
+            return (logits, logits)
+
+        sec = _time_fn(run, n_warmup=1, iters=5 if on_tpu else 2,
+                       name=f"decode_fused_{mode}")
         decode_sec = sec - pf_sec
         noisy = decode_sec <= 0
         if noisy:

@@ -246,15 +246,14 @@ def _kernel_parity(cfg, seed: int) -> None:
 def phase_serve(size: _Size, seed: int) -> None:
     from apex_tpu.models.generate import generate
     from apex_tpu.models.transformer_lm import init_gpt_params
-    from apex_tpu.ops import fused_sampling, paged_attention
     from apex_tpu.ops.decode_step import route_decode_fused
     from apex_tpu.serving import ServingEngine
 
     cfg = _cfg(size)
     params = init_gpt_params(jax.random.PRNGKey(seed), cfg)
-    print(f"serve: routes decode_fused={route_decode_fused(None)} "
-          f"sampler={fused_sampling._route(None)} "
-          f"paged_attention={paged_attention._route(None)}")
+    # one resolver decides all three (ops/_pallas_utils.py)
+    print(f"serve: routes decode_fused, sampler, paged_attention = "
+          f"{route_decode_fused(None)}")
     _kernel_parity(cfg, seed)
 
     rng = np.random.RandomState(seed)

@@ -38,6 +38,8 @@ MODULES = [
     ("apex_tpu.contrib.optimizers.distributed_fused_lamb", "optimizers",
      "contrib.optimizers — ZeRO DistributedFusedLAMB"),
     # ops
+    ("apex_tpu.ops._pallas_utils", "ops",
+     "ops._pallas_utils — which implementation runs, shared helpers"),
     ("apex_tpu.ops.flash_attention", "ops",
      "ops.flash_attention — FlashAttention-2 kernels"),
     ("apex_tpu.ops.layer_norm", "ops", "ops.layer_norm — LN/RMSNorm"),
@@ -229,7 +231,6 @@ MODULES = [
     ("apex_tpu.RNN", "misc", "apex_tpu.RNN"),
     ("apex_tpu.fp16_utils", "misc", "apex_tpu.fp16_utils"),
     ("apex_tpu.multi_tensor", "misc", "apex_tpu.multi_tensor"),
-    ("apex_tpu.utils.registry", "misc", "utils.registry — op registry"),
     ("apex_tpu.utils.checkpoint", "misc",
      "utils.checkpoint — save/resume + AutoResume"),
     ("apex_tpu.utils.collectives", "misc", "utils.collectives"),

@@ -29,3 +29,20 @@ if not _ON_TPU:
 # Keep x64 off (TPU-realistic numerics).
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def flash_bwd(monkeypatch):
+    """``flash_bwd("fused")`` / ``flash_bwd("split")`` run that flash
+    backward at any size by moving ``_bwd_plan``'s key-length constant.
+    A test seam: the program decides from the static shape alone and has
+    no such option."""
+    from apex_tpu.ops import flash_attention as fa
+
+    def pin(plan):
+        monkeypatch.setattr(fa, "_FUSED_BWD_MAX_SK",
+                            {"fused": 1 << 30, "split": 0}[plan])
+
+    return pin

@@ -7,9 +7,8 @@ composition at ragged lengths that straddle block boundaries
 both ``cache_wire`` forms, fp32 tight and bf16 loose; ``generate()``
 routed through the kernel must be greedy token-identical to the
 reference route on both cache layouts, composing with speculative
-decoding and the serving engine's preempt→resume cycle; and the
-``APEX_TPU_DECODE_FUSED`` route must fail loudly by name on a bad
-value."""
+decoding and the serving engine's preempt→resume cycle; and a bad
+``backend=`` must fail loudly by name."""
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +17,7 @@ import pytest
 
 from apex_tpu.models.config import TransformerConfig
 from apex_tpu.models.generate import generate
+from apex_tpu.ops import decode_step as decode_step_mod
 from apex_tpu.models.transformer_lm import init_gpt_params
 from apex_tpu.ops.decode_step import (
     decode_layer_reference, fused_decode_layer, route_decode_fused)
@@ -126,23 +126,11 @@ class TestKernelParity:
 
 
 class TestRouting:
-    def test_bad_backend_raises_by_name(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_DECODE_FUSED", "nonsense")
-        with pytest.raises(ValueError, match="backend"):
-            route_decode_fused(None)
-        with pytest.raises(ValueError, match="backend"):
+    def test_bad_backend_raises_by_name(self):
+        with pytest.raises(ValueError, match="fused decode.*backend"):
             route_decode_fused("fused")
 
-    def test_env_routes(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_DECODE_FUSED", "kernel")
-        assert route_decode_fused(None) == "kernel"
-        monkeypatch.setenv("APEX_TPU_DECODE_FUSED", "reference")
-        assert route_decode_fused(None) == "reference"
-        # explicit argument wins over the env
-        assert route_decode_fused("kernel") == "kernel"
-
     def test_auto_follows_interpret(self, monkeypatch):
-        monkeypatch.delenv("APEX_TPU_DECODE_FUSED", raising=False)
         monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
         assert route_decode_fused("auto") == "kernel"
         monkeypatch.delenv("APEX_TPU_PALLAS_INTERPRET", raising=False)
@@ -193,7 +181,10 @@ class TestShapeChecks:
 class TestGenerateTokenIdentity:
     """The end-to-end acceptance pin: generate() routed through the
     fused kernel is greedy token-identical to the reference route on
-    both cache layouts and both cache_wire forms."""
+    both cache layouts and both cache_wire forms.  ``generate()`` takes
+    no route: the test pins what it resolves (``route_decode_fused``,
+    looked up on each call) and leaves every other kernel interpreted
+    on both sides."""
 
     def _run(self, monkeypatch, route, **gen_kw):
         cfg = _cfg(position_embedding_type="rope", num_query_groups=2)
@@ -204,7 +195,8 @@ class TestGenerateTokenIdentity:
         for i, n in enumerate(lens):
             batch[i, :n] = rng.randint(0, cfg.vocab_size, (n,))
         monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
-        monkeypatch.setenv("APEX_TPU_DECODE_FUSED", route)
+        monkeypatch.setattr(decode_step_mod, "route_decode_fused",
+                            lambda backend: route)
         return np.asarray(generate(
             params, jnp.asarray(batch), cfg, max_new_tokens=7,
             prompt_lens=jnp.asarray(lens), **gen_kw))
@@ -237,7 +229,6 @@ class TestServingComposition:
         from apex_tpu.serving import ServingEngine
 
         monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
-        monkeypatch.setenv("APEX_TPU_DECODE_FUSED", "kernel")
         cfg = _cfg(position_embedding_type="rope", num_query_groups=2)
         params = init_gpt_params(jax.random.PRNGKey(0), cfg)
         rng = np.random.RandomState(7)

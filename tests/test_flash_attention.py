@@ -509,7 +509,7 @@ class TestGroupedKV:
                 np.asarray(a), np.asarray(b_), atol=2e-4, rtol=2e-4,
                 err_msg=f"grouped+seg d{name}")
 
-    def test_fused_backward_matches_split(self, monkeypatch):
+    def test_fused_backward_matches_split(self, flash_bwd):
         """The fused single-pass backward supports grouping too: its
         dk/dv output block stays resident across a group's consecutive
         q-head grid rows.  Must agree with the split pair exactly."""
@@ -519,9 +519,9 @@ class TestGroupedKV:
             return jax.grad(lambda *a: jnp.sum(flash_attention(
                 *a, causal=True)), argnums=(0, 1, 2))(q, k, v)
 
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", "fused")
+        flash_bwd("fused")
         g_fused = grads()
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", "split")
+        flash_bwd("split")
         g_split = grads()
         assert g_fused[1].shape == k.shape   # grouped dk
         for a, b_, name in zip(g_fused, g_split, "qkv"):
@@ -529,7 +529,7 @@ class TestGroupedKV:
                 np.asarray(a), np.asarray(b_), atol=1e-5, rtol=1e-5,
                 err_msg=f"grouped fused d{name}")
 
-    def test_fused_backward_mqa_with_dropout(self, monkeypatch):
+    def test_fused_backward_mqa_with_dropout(self, flash_bwd):
         """MQA extreme through the fused kernel with dropout: the
         reconstructed per-q-head dropout stream must match split."""
         q, k, v = self._grouped(g=1, seed=26)
@@ -540,9 +540,9 @@ class TestGroupedKV:
                 *a, causal=True, dropout_p=0.25, dropout_rng=rng)),
                 argnums=(0, 1, 2))(q, k, v)
 
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", "fused")
+        flash_bwd("fused")
         g_fused = grads()
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", "split")
+        flash_bwd("split")
         g_split = grads()
         for a, b_, name in zip(g_fused, g_split, "qkv"):
             np.testing.assert_allclose(
@@ -559,16 +559,15 @@ class TestGroupedKV:
 
 
 class TestBackwardModeRouting:
-    """auto routes short keys (sk <= APEX_TPU_FLASH_BWD_FUSED_MAX,
-    default 512 — the round-5 measured crossover) to the fused
+    """``_bwd_plan`` sends short keys (padded sk <= 512) to the fused
     single-pass backward and longer keys to the split dq/dkv pair, so
     both kernels get implicit coverage from the other grad tests; the
-    explicit env-forced cases here pin each kernel regardless of where
-    the crossover sits."""
+    ``flash_bwd`` fixture pins each kernel here regardless of where the
+    crossover sits."""
 
     @pytest.mark.parametrize("causal", [False, True])
-    def test_split_backward_matches_reference(self, monkeypatch, causal):
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", "split")
+    def test_split_backward_matches_reference(self, flash_bwd, causal):
+        flash_bwd("split")
         q, k, v = make_qkv(2, 128, 2, 64, seed=11)
         kpm = jnp.asarray(
             np.arange(128)[None, :] >= np.array([96, 128])[:, None])
@@ -583,15 +582,7 @@ class TestBackwardModeRouting:
                 np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4,
                 err_msg=f"split d{name}")
 
-    def test_fused_backward_rejects_non_divisor_bq(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", "fused")
-        monkeypatch.setenv("APEX_TPU_FLASH_FUSED_BQ", "96")
-        q, k, v = make_qkv(1, 256, 2, 32, seed=12)
-        with pytest.raises(ValueError, match="must divide"):
-            jax.grad(lambda *a: jnp.sum(
-                flash_attention(*a, causal=True)))(q, k, v)
-
-    def test_fused_segment_ids_match_split(self, monkeypatch):
+    def test_fused_segment_ids_match_split(self, flash_bwd):
         seg = jnp.asarray(
             np.repeat(np.arange(4), 32)[None].repeat(2, 0), jnp.int32)
         q, k, v = make_qkv(2, 128, 2, 32, seed=13)
@@ -601,9 +592,9 @@ class TestBackwardModeRouting:
                 *a, causal=True, segment_ids=seg)),
                 argnums=(0, 1, 2))(q, k, v)
 
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", "fused")
+        flash_bwd("fused")
         g_fused = grads()
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", "split")
+        flash_bwd("split")
         g_split = grads()
         for a, b, name in zip(g_fused, g_split, "qkv"):
             np.testing.assert_allclose(

@@ -15,8 +15,7 @@ Three layers of pinning:
   Runs through the Pallas interpret path on the 8-virtual-device CPU
   mesh (conftest), the same route the CI uses for the flash/paged
   kernels.
-- **routing**: ``APEX_TPU_FUSED_SAMPLING`` honored, malformed env
-  values warn BY NAME and fall back to auto; malformed explicit
+- **routing**: ``backend=None`` follows interpret mode; a malformed
   ``backend=`` raises.
 
 Plus the greedy short-circuit satellite: ``temperature == 0`` returns
@@ -243,9 +242,9 @@ class TestKernelPath:
 
 
 class TestRouting:
-    def test_env_override_is_honored(self, monkeypatch):
+    def test_auto_follows_interpret_mode(self, monkeypatch):
         """reference vs kernel draw different stochastic streams from
-        the same key — that observable difference proves the env var
+        the same key — that observable difference proves interpret mode
         actually switched the path."""
         rng = np.random.RandomState(10)
         logits = jnp.asarray(rng.randn(64, 256), jnp.float32)
@@ -255,39 +254,12 @@ class TestRouting:
         kern = np.asarray(fused_sample(logits, key, temperature=1.0,
                                        backend="kernel"))
         assert not np.array_equal(ref, kern)
-        monkeypatch.setenv("APEX_TPU_FUSED_SAMPLING", "reference")
+        monkeypatch.delenv("APEX_TPU_PALLAS_INTERPRET", raising=False)
         np.testing.assert_array_equal(
             ref, np.asarray(fused_sample(logits, key, temperature=1.0)))
-        monkeypatch.setenv("APEX_TPU_FUSED_SAMPLING", "kernel")
+        monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
         np.testing.assert_array_equal(
             kern, np.asarray(fused_sample(logits, key, temperature=1.0)))
-
-    def test_malformed_env_warns_by_name_and_falls_back(
-            self, monkeypatch):
-        import io
-        import logging
-
-        from apex_tpu.utils.logging import get_logger
-
-        rng = np.random.RandomState(11)
-        logits = jnp.asarray(rng.randn(2, 32), jnp.float32)
-        key = jax.random.PRNGKey(0)
-        auto = np.asarray(fused_sample(logits, key, temperature=1.0))
-        monkeypatch.setenv("APEX_TPU_FUSED_SAMPLING", "warp-speed")
-        # the library logger does not propagate to the root logger, so
-        # listen with our own handler instead of caplog/capsys
-        stream = io.StringIO()
-        handler = logging.StreamHandler(stream)
-        logger = get_logger("ops")
-        logger.addHandler(handler)
-        try:
-            got = np.asarray(fused_sample(logits, key, temperature=1.0))
-        finally:
-            logger.removeHandler(handler)
-        np.testing.assert_array_equal(auto, got)   # fell back to auto
-        err = stream.getvalue()
-        assert "APEX_TPU_FUSED_SAMPLING" in err    # warns BY NAME
-        assert "warp-speed" in err
 
     def test_malformed_backend_argument_raises(self):
         with pytest.raises(ValueError, match="backend"):

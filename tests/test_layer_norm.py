@@ -143,14 +143,11 @@ def test_pallas_interpret_matches_ref(monkeypatch):
     np.testing.assert_allclose(np.asarray(gb), np.asarray(gb_r), atol=1e-4)
 
 
-@pytest.mark.parametrize("mode", ["pallas"])
-def test_pallas_bwd_kernel_opt_in(monkeypatch, mode):
-    """The Pallas revisit backward is the default (an earlier on-chip
-    sweep favoured it; not measured on today's code); the pallas_split
-    variant was deleted (Mosaic rejects its partials block spec).  Exercise the kernel against the XLA
-    composition so it cannot rot."""
+def test_pallas_bwd_kernel_matches_xla(monkeypatch):
+    """The Pallas revisit backward runs wherever the Pallas forward is
+    eligible (interpret mode here); outside interpret mode the CPU gets
+    the XLA composition, the reference side."""
     monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("APEX_TPU_LN_BWD", mode)
     rng = np.random.RandomState(11)
     # >512 rows -> multiple grid blocks (_rows_block(256, 8) = 512): the
     # revisit accumulator must actually cross block boundaries, not
@@ -165,23 +162,20 @@ def test_pallas_bwd_kernel_opt_in(monkeypatch, mode):
 
     gx, gw, gb = jax.grad(f, argnums=(0, 1, 2))(x, w, b)
 
-    monkeypatch.delenv("APEX_TPU_PALLAS_INTERPRET")
-    monkeypatch.setenv("APEX_TPU_LN_BWD", "xla")  # reference side: XLA composition
+    monkeypatch.delenv("APEX_TPU_PALLAS_INTERPRET")  # reference side
     gx_r, gw_r, gb_r = jax.grad(f, argnums=(0, 1, 2))(x, w, b)
     np.testing.assert_allclose(np.asarray(gx), np.asarray(gx_r), atol=1e-5)
     np.testing.assert_allclose(np.asarray(gw), np.asarray(gw_r), atol=1e-4)
     np.testing.assert_allclose(np.asarray(gb), np.asarray(gb_r), atol=1e-4)
 
-    # RMS variant through the same opt-in
+    # RMS variant through the same kernel
     monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("APEX_TPU_LN_BWD", mode)
 
     def fr(x_, w_):
         return jnp.sum(fused_rms_norm(x_, w_) * dy)
 
     rx, rw = jax.grad(fr, argnums=(0, 1))(x, w)
-    monkeypatch.delenv("APEX_TPU_PALLAS_INTERPRET")
-    monkeypatch.setenv("APEX_TPU_LN_BWD", "xla")  # reference side: XLA composition
+    monkeypatch.delenv("APEX_TPU_PALLAS_INTERPRET")  # reference side
     rx_r, rw_r = jax.grad(fr, argnums=(0, 1))(x, w)
     np.testing.assert_allclose(np.asarray(rx), np.asarray(rx_r), atol=1e-5)
     np.testing.assert_allclose(np.asarray(rw), np.asarray(rw_r), atol=1e-4)

@@ -51,16 +51,16 @@ class TestEnvRules:
     def test_registered_env_read_clean(self):
         assert not run_rule(
             "APX201",
-            'import os\nv = os.environ.get("APEX_TPU_LN_BWD")\n')
+            'import os\nv = os.environ.get("APEX_TPU_PALLAS_INTERPRET")\n')
 
     def test_subscript_read_fires(self):
         assert run_rule(
             "APX201", 'import os\nv = os.environ["APEX_TPU_BOGUS"]\n')
 
-    def test_dynamic_family_prefix_resolves(self):
-        # f"APEX_TPU_DISABLE_{name}" matches the registered
-        # APEX_TPU_DISABLE_* family via its static prefix
-        assert not run_rule(
+    def test_name_built_at_run_time_fires(self):
+        # no family rows: an f-string's static prefix is no row's name,
+        # not even where registered names start with it
+        assert run_rule(
             "APX201",
             'import os\n'
             'v = os.environ.get(f"APEX_TPU_DISABLE_{name}")\n')
@@ -73,11 +73,10 @@ class TestEnvRules:
         assert not run_rule(
             "APX201", 'import os\nv = os.environ.get("HOME")\n')
 
-    def test_lookup_prefers_exact_over_family(self):
+    def test_lookup_is_by_exact_name(self):
         row = env_registry.lookup("APEX_TPU_DISABLE_NATIVE")
         assert row is not None and row.name == "APEX_TPU_DISABLE_NATIVE"
-        fam = env_registry.lookup("APEX_TPU_DISABLE_FLASH_ATTENTION")
-        assert fam is not None and fam.name == "APEX_TPU_DISABLE_*"
+        assert env_registry.lookup("APEX_TPU_DISABLE_FLASH_ATTENTION") is None
         assert env_registry.lookup("APEX_TPU_NOPE") is None
 
     def test_docs_sync_clean_at_head(self):

@@ -218,16 +218,14 @@ class TestGroupedMatmul:
             np.asarray(grouped_matmul_reference(x, w, off)),
             atol=1e-4, rtol=1e-4)
 
-    def test_backend_validation(self, monkeypatch):
-        from apex_tpu.ops.grouped_matmul import _route, grouped_matmul
+    def test_backend_validation(self):
+        from apex_tpu.ops.grouped_matmul import grouped_matmul
 
-        monkeypatch.setenv("APEX_TPU_GROUPED_MATMUL", "reference")
-        assert _route(None) == "reference"
-        monkeypatch.setenv("APEX_TPU_GROUPED_MATMUL", "bogus")
-        with pytest.raises(ValueError, match="bogus"):
-            _route(None)
         x = jnp.zeros((4, 8))
         w = jnp.zeros((2, 8, 8))
+        with pytest.raises(ValueError, match="bogus"):
+            grouped_matmul(x, w, jnp.zeros((3,), jnp.int32),
+                           backend="bogus")
         with pytest.raises(ValueError, match="offsets length"):
             grouped_matmul(x, w, jnp.zeros((2,), jnp.int32))
 

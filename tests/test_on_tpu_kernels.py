@@ -223,8 +223,8 @@ def test_lm_head_ce_on_chip():
             atol=2e-2, rtol=2e-2)
 
 
-def test_fused_flash_backward_on_chip(monkeypatch):
-    """Round-4 fused single-pass backward vs the split kernels and the
+def test_fused_flash_backward_on_chip(flash_bwd):
+    """The fused single-pass backward vs the split kernels and the
     XLA reference, compiled by Mosaic (non-interpret) at the BERT-class
     short-key shape."""
     from apex_tpu.ops.flash_attention import flash_attention, mha_reference
@@ -242,11 +242,10 @@ def test_fused_flash_backward_on_chip(monkeypatch):
             argnums=(0, 1, 2))(q, k, v)
 
     for causal in (True, False):
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", "fused")
+        flash_bwd("fused")
         g_fused = grads(causal)
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", "split")
+        flash_bwd("split")
         g_split = grads(causal)
-        monkeypatch.delenv("APEX_TPU_FLASH_BWD")
         g_ref = jax.grad(lambda *a: jnp.sum(mha_reference(
             *a, causal=causal, key_padding_mask=kpm)),
             argnums=(0, 1, 2))(q, k, v)
@@ -259,9 +258,9 @@ def test_fused_flash_backward_on_chip(monkeypatch):
                 err_msg=f"split d{nm} causal={causal}")
 
 
-def test_ln_backward_split_partials_on_chip(monkeypatch):
-    """Round-4 per-block-partials LN backward under Mosaic at a
-    multi-block shape."""
+def test_ln_backward_on_chip():
+    """The LayerNorm backward kernel under Mosaic at a multi-block
+    shape."""
     from apex_tpu.ops.layer_norm import fused_layer_norm, layer_norm_ref
 
     rs = np.random.RandomState(5)
@@ -282,17 +281,14 @@ def test_ln_backward_split_partials_on_chip(monkeypatch):
     tols = {"dx": dict(atol=1e-2, rtol=2e-2),
             "dw": dict(atol=0.5, rtol=2e-2),
             "db": dict(atol=0.5, rtol=2e-2)}
-    for mode in ("pallas",):
-        monkeypatch.setenv("APEX_TPU_LN_BWD", mode)
-        g = jax.grad(f, argnums=(0, 1, 2))(x, w, b)
-        monkeypatch.delenv("APEX_TPU_LN_BWD")
-        for a, r, nm in zip(g, g_ref, ("dx", "dw", "db")):
-            np.testing.assert_allclose(
-                np.asarray(a, np.float32), np.asarray(r, np.float32),
-                err_msg=f"{mode} {nm}", **tols[nm])
+    g = jax.grad(f, argnums=(0, 1, 2))(x, w, b)
+    for a, r, nm in zip(g, g_ref, ("dx", "dw", "db")):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(r, np.float32),
+            err_msg=nm, **tols[nm])
 
 
-def test_grouped_kv_flash_on_chip(monkeypatch):
+def test_grouped_kv_flash_on_chip(flash_bwd):
     """GQA-aware flash under Mosaic: the grouped index maps (fwd + dq),
     the 4-D dkv accumulation grid, AND the fused kernel's cross-row
     group accumulation only ever ran in interpret mode until a chip is
@@ -311,7 +307,7 @@ def test_grouped_kv_flash_on_chip(monkeypatch):
     g2 = jax.grad(lambda *a: jnp.sum(mha_reference(*a, causal=True)),
                   argnums=(0, 1, 2))(q, k, v)
     for mode in ("split", "fused"):
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", mode)
+        flash_bwd(mode)
         g1 = jax.grad(lambda *a: jnp.sum(flash_attention(
             *a, causal=True)), argnums=(0, 1, 2))(q, k, v)
         for a, b, name in zip(g1, g2, "qkv"):

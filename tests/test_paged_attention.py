@@ -206,9 +206,9 @@ class TestRoutingAndValidation:
         ker = ragged_paged_attention(q, kp, vp, tbl, lens)
         np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
-        monkeypatch.setenv("APEX_TPU_PAGED_ATTENTION", "nonsense")
         with pytest.raises(ValueError, match="backend"):
-            ragged_paged_attention(q, kp, vp, tbl, lens)
+            ragged_paged_attention(q, kp, vp, tbl, lens,
+                                   backend="nonsense")
 
     def test_shape_validation(self):
         q = jnp.zeros((2, 4, 64))

@@ -2,7 +2,7 @@
 block-scaled slab path and ops/grouped_matmul.py's expert-slab path —
 kernel-vs-reference parity (fp32 tight / bf16 loose, interpret path on
 the 8-virtual-device mesh), the high-precision custom VJP, the
-``APEX_TPU_QUANT_MATMUL`` routing, quantize_params over the model
+``backend=`` routing, quantize_params over the model
 family, and the fake-quant oracle pin
 (``generate(quantize_params(p)) == generate(dequantize_params(...))``
 greedy token-for-token — the int8 path computes exactly what it
@@ -142,7 +142,7 @@ class TestDenseParity:
 
 
 class TestRouting:
-    def test_env_routes_and_rejects(self, monkeypatch):
+    def test_auto_routes_and_rejects(self, monkeypatch):
         rng = np.random.RandomState(6)
         x = jnp.asarray(rng.randn(4, 32), jnp.float32)
         qw = quantize_weight(jnp.asarray(rng.randn(32, 8), jnp.float32))
@@ -155,9 +155,9 @@ class TestRouting:
         ker = dense_quantized(x, qw["wire"], qw["scale"])
         np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
-        monkeypatch.setenv("APEX_TPU_QUANT_MATMUL", "nonsense")
         with pytest.raises(ValueError, match="backend"):
-            dense_quantized(x, qw["wire"], qw["scale"])
+            dense_quantized(x, qw["wire"], qw["scale"],
+                            backend="nonsense")
 
 
 class TestGroupedParity:

@@ -77,17 +77,17 @@ def _drop_policy(mp):
 @pytest.mark.parametrize("s, bwd, kernels", [
     pytest.param(64, None, ["flash_fwd", "flash_bwd"], id="fused_s64"),
     pytest.param(64, "split", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
-                 id="split_by_env_s64"),
+                 id="split_pinned_s64"),
     pytest.param(1024, None, ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
                  id="split_s1024"),
 ])
 def test_forward_kernel_is_traced_once(s, bwd, kernels, plain, interpreted,
-                                       monkeypatch):
+                                       monkeypatch, flash_bwd):
     """One forward kernel and the backward ones in the whole gradient;
     the plain checkpoint is the control, with the forward kernel again
     in its rematted body."""
     if bwd:
-        monkeypatch.setenv("APEX_TPU_FLASH_BWD", bwd)
+        flash_bwd(bwd)
     if plain:
         _drop_policy(monkeypatch)
     cfg = _cfg(s)

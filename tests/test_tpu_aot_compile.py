@@ -12,12 +12,11 @@ it cannot be: only one process at a time may load the TPU library, every
 xdist worker imports every test file, so nothing here touches it while
 the module is imported — and all such tests live in this ONE file, so
 one worker holds the library.  ``default_backend`` is steered to "tpu"
-for the module (the kernels' routes ask it), in the test, not through an
-option of the program.
+for the module (the one resolver in ``ops/_pallas_utils.py`` asks it), in
+the test, not through an option of the program.
 """
 
 import dataclasses
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -55,10 +54,11 @@ def as_tpu(topo):
     read back without one)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    registry = sys.modules["apex_tpu.utils.registry"]
+    from apex_tpu.ops import _pallas_utils
+
     was = jax.config.jax_enable_compilation_cache
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(registry, "default_backend", lambda: "tpu")
+        mp.setattr(_pallas_utils, "default_backend", lambda: "tpu")
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:

@@ -1,4 +1,4 @@
-"""One-command measurement campaign: every bench/dryrun/sweep stage in
+"""One-command measurement campaign: every bench/dryrun stage in
 order, each its own subprocess with its log under measure_logs/.
 
     python tools/measure_all.py
@@ -17,7 +17,7 @@ workload matrix) and its ``--decode`` ablations (``--cache-layout``,
 topology rows (``--serve-trace``, ``--serve-trace --controller``,
 ``--cold-start``); ``--tp-overlap``, ``--moe``, ``--ckpt`` with their
 dryrun parity phases; ``tests/test_on_tpu_kernels.py`` on the chip;
-``tools/sweep_r5.py``, ``tools/sweep_r4.py``, ``bench_kernels.py``; and a final
+``bench_kernels.py``; and a final
 ``aggregate_telemetry`` merge into ``measure_logs/fleet_aggregate.json``.
 """
 
@@ -286,47 +286,11 @@ def main():
         "tpu_tier", [sys.executable, "-m", "pytest",
                      "tests/test_on_tpu_kernels.py", "-m", "tpu", "-q"],
         env_extra={"APEX_TPU_TEST_ON_TPU": "1"}, timeout=3600)
-    results["sweep_r5"] = _run(
-        "sweep_r5", [sys.executable, "tools/sweep_r5.py", "--json",
-                     "SWEEP_r5.json"], timeout=3600)
-    results["sweep_r4"] = _run(
-        "sweep_r4", [sys.executable, "tools/sweep_r4.py", "--json",
-                     "SWEEP_r4.json"], timeout=3600)
     results["bench_kernels"] = _run(
         "bench_kernels", [sys.executable, "bench_kernels.py", "--json",
                           "KERNEL_BENCH.json"])
 
     print("\n[measure_all] stage results:", json.dumps(results))
-    sweep_path = os.path.join(ROOT, "SWEEP_r5.json")
-    if os.path.exists(sweep_path) and results.get("sweep_r5") == 0:
-        with open(sweep_path) as f:
-            sweep = json.load(f)
-        print("[measure_all] DECISION CHECKLIST:")
-        rows = {k: v["pallas_over_xla"] for k, v in sweep.items()
-                if "s1024" in k and "fused" in k}
-        split = {k: v["pallas_over_xla"] for k, v in sweep.items()
-                 if "s1024" in k and k.endswith("split")}
-        if rows and split:
-            best_k = min(rows, key=rows.get)
-            best_split = min(split.values())
-            # log the SAME number the comparison uses (min, i.e. the
-            # best split time) in both branches, so the printed
-            # evidence matches the decision
-            if rows[best_k] < best_split:
-                print(f"  flash s1024: best fused {best_k}="
-                      f"{rows[best_k]:.2f} beats best split "
-                      f"({best_split:.2f}) -> raise "
-                      "APEX_TPU_FLASH_BWD_FUSED_MAX to 1024")
-            else:
-                print(f"  flash s1024: split holds "
-                      f"({best_split:.2f} vs best fused "
-                      f"{rows[best_k]:.2f}) -> FUSED_MAX stays 512")
-        for k, v in sweep.items():
-            if "remeasure" in k:
-                print(f"  {k}: {v['pallas_over_xla']:.2f} (ledger "
-                      "s512-fwd row refresh)")
-        print("[measure_all] then: record the decision in PERF.md and "
-              "re-run bench.py if defaults moved.")
     # final stage (ISSUE 7): merge the stages' telemetry streams into
     # the fleet summary — the output format is what a multi-host
     # autoscaler consumes
