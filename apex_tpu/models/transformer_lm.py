@@ -35,6 +35,7 @@ from typing import Callable, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.models.config import TransformerConfig
@@ -630,6 +631,50 @@ def split_qkv_gqa(cfg: TransformerConfig, qkv, b, s, nh):
     return q, k, v
 
 
+def _sequence_minor(t):
+    """Pin ``t`` [b, s, n, d] to the sequence-minor layout that XLA gives
+    the elementwise chain between a GQA projection and the kernels (it
+    keeps arrays whose last axis is 64 or 32 wide out of 128-lane tiles),
+    so that ONE bfloat16 copy a tensor moves the chain's result into the
+    kernels' ``[b, s, n x d]``.  Unpinned, the kernels' layout spreads
+    up the chain as far as its float32 leaves and XLA copies three of
+    those for q and three for k (877 MB of copies in the LFM2 block
+    where this leaves 302 and ``[b x n, s, d]`` kernels had 419: the
+    described-v5e compile, tests/test_tpu_aot_compile.py)."""
+    return with_layout_constraint(t, Layout(major_to_minor=(0, 2, 3, 1)))
+
+
+def _mha_qkv(x, w, bias, nh):
+    """The MHA projection ``[b, s, 3, nh x d]``: the weight's columns
+    (and the bias) gathered from the stored per-head interleave
+    ``[q | k | v] x nh`` into sections ``[Q | K | V]`` before the product,
+    so that q, k and v leave it as the lane ranges ``[b, s, nh x d]`` that
+    the flash kernels block (``ops/flash_attention._heads_a_block``), and
+    not as every head's third of 3d lanes, which XLA transposes into
+    place an activation at a time.  The weight is 1/8 of one activation's
+    bytes at b8 x s1024.  The parameter's stored layout (checkpoints,
+    ``models/generate``'s decode split) does not move: the same
+    ``qkv_kernel`` gives the same q, k, v.  The last axis keeps whole
+    heads contiguous, so it shards over tp like the interleave does.
+
+    Two fences keep the gather on the weight (the described-v5e compile
+    of the cells' steps at two layers): without the barrier XLA folds
+    the gather into the products and transposes d(qkv), 50 MB twice a
+    layer at b8 x s1024, instead of the 6 MB weight gradient; without
+    the pinned layout an unrolled stack (BERT) takes the gathered
+    layout for the whole stacked leaf and copies its float32 master and
+    both moments in and out of every step (6 x 302 MB)."""
+    h = w.shape[0]
+    w = with_layout_constraint(w, Layout(major_to_minor=(0, 1)))
+    w = jax.lax.optimization_barrier(
+        w.reshape(h, nh, 3, -1).transpose(0, 2, 1, 3).reshape(h, 3, -1))
+    qkv = jnp.einsum("bsh,hcm->bscm", x, w)
+    if bias is not None:
+        qkv = qkv + bias.astype(x.dtype).reshape(nh, 3, -1).transpose(
+            1, 0, 2).reshape(3, -1)
+    return qkv
+
+
 def _attention(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
                attention_mask, rope, dropout_rng, return_kv: bool = False):
     """ParallelAttention (reference :358): column-parallel fused QKV,
@@ -645,6 +690,9 @@ def _attention(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
     with jax.named_scope("qkv"):
         xi = ctx.copy_in(x)
         wq = lp["qkv_kernel"]
+        # MHA: q, k, v leave the product as sections [Q | K | V]; GQA and
+        # int8 weights keep the stored interleave
+        sections = not (cfg.is_gqa or _is_quantized(wq))
         if _is_quantized(wq):
             # weight-only int8 serving path (ISSUE 14): single-device by
             # contract — quantize_params is a serving conversion, manual-TP
@@ -657,9 +705,11 @@ def _attention(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
             from apex_tpu.ops.dense import quantized_matmul
 
             qkv = quantized_matmul(xi, wq)
+        elif sections:
+            qkv = _mha_qkv(xi, wq.astype(x.dtype), lp.get("qkv_bias"), nh)
         else:
             qkv = xi @ wq.astype(x.dtype)
-        if "qkv_bias" in lp:
+        if "qkv_bias" in lp and not sections:
             qkv = qkv + lp["qkv_bias"].astype(x.dtype)
         qkv = ctx.constrain_col(qkv)
         if cfg.is_gqa:
@@ -676,13 +726,15 @@ def _attention(cfg: TransformerConfig, lp: dict, x, ctx: TPContext,
                     "the GSPMD context (make_gpt_train_step over a mesh), "
                     "which replicates KV heads as needed")
             q, k, v = split_qkv_gqa(cfg, qkv, b, s, nh)
+        elif sections:
+            q, k, v = (qkv[:, :, i].reshape(b, s, nh, -1) for i in range(3))
         else:
-            qkv = qkv.reshape(b, s, nh, -1)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q, k, v = jnp.split(qkv.reshape(b, s, nh, -1), 3, axis=-1)
         if cfg.qk_norm:
             from apex_tpu.models.hybrid import qk_norm_rope
 
-            q, k = qk_norm_rope(cfg, lp, q, k, rope)
+            q, k = (_sequence_minor(t)
+                    for t in qk_norm_rope(cfg, lp, q, k, rope))
         elif rope is not None:
             cos, sin = rope
             q = _apply_rope(q, cos, sin)

@@ -9,9 +9,23 @@ blockwise kernel set in Pallas: O(sq·d) memory, online softmax, fused causal
 It also serves as the compute core of the ring-attention context-parallel
 path (the reference has no long-context story; SURVEY.md §5).
 
-Layout: [batch, seq, heads, head_dim] (the model's native BSND). The kernel
-grid runs (batch*heads, q-blocks, kv-blocks) with kv innermost; VMEM scratch
-carries the running max / normalizer / accumulator across kv steps.
+Layout: [batch, seq, heads, head_dim] (the model's native BSND) in and
+out.  The kernels read and write its free reshape [batch, seq, heads x
+head_dim], the layout the projections on either side produce and consume,
+in lane-dense blocks of 128 lanes: ``128 // d`` heads a block (two at d =
+64), one head where d is a multiple of 128 (:func:`_heads_a_block`, from
+static shapes alone).  A block's heads are worked one after another on
+full-width operands, the others' lanes zeroed (:func:`_take`: a K = 128
+product on a 128 x 128 MXU costs the passes of a K = 64 one), and their
+128-wide results merged by lane (:func:`_merge`); under GQA a block's
+heads read one K/V head, whose half of its block is a function of
+``program_id``.  The grid runs (batch x head blocks, q-blocks, kv-blocks)
+with kv innermost; VMEM scratch carries the running max / normalizer (a
+head) / accumulator across kv steps.  Shapes the rule cannot block (an
+odd head count, a toy width that leaves a block part empty, MQA's single
+K/V head of 64, a GQA group that a block would straddle) go through the
+same kernels as [batch x heads, seq, head_dim] rows, one head a block,
+and pay XLA's transposes on the way in and out; no cell does.
 
 Variants:
 - ``causal=True`` — upper-triangular mask generated from iota in-kernel;
@@ -51,7 +65,7 @@ fit VMEM whole, one fused pass that computes them once; the static shape
 decides (:func:`_bwd_plan`).
 
 Under remat: the forward rule tags its two kernel-made residuals, the
-output ``o`` and the sliced logsumexp ``lse`` ([b·h, sq] f32), with
+output ``o`` and the logsumexp ``lse`` ([b, sq, h] f32), with
 ``checkpoint_name`` (``REMAT_SAVED_NAMES``), and a rematted transformer
 layer (``TransformerConfig.remat``) keeps exactly those two: 17.3 MB a
 layer at b8 × s1024 × 16 heads of 64, against running the forward
@@ -63,7 +77,7 @@ gone at lowering.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -259,32 +273,42 @@ def _causal_rects(block, sub, by):
     return [(c, block - c, c, sub, True) for c in range(0, block, sub)]
 
 
-def _visit_tile(front, back, causal, block_q, block_k, sub, multi, qi, kj,
-                seg_refs, by="rows"):
+def _visit_tile(front, back, finish, hpb, causal, block_q, block_k, sub,
+                multi, qi, kj, seg_refs, by="rows"):
     """Work off what grid step (qi, kj) has to compute of its (block_q,
-    block_k) score tile, a rectangle ``(row0, rows, col0, cols, crossed)``
-    at a time in two stages: ``front(*rect)`` makes the rectangle's MXU
-    products from the operands, ``back(*rect, made)`` does the rest.
+    block_k) score tile for the block's ``hpb`` heads, a rectangle
+    ``(row0, rows, col0, cols, crossed)`` and a head at a time in two
+    stages: ``front(h, *rect)`` makes head ``h``'s MXU products from the
+    operands, ``back(h, *rect, made)`` does the rest and returns the
+    head's part; ``finish(*rect, parts)`` merges the heads' parts into
+    the outputs or the running state.
 
     Sub-tiled causal (``sub``; block_q == block_k): a tile below the
     diagonal is one whole unmasked rectangle, the tile on it the static
     nest of :func:`_causal_rects` (bands ``by`` rows or columns), a tile
-    above it nothing.  The nest runs the next band's ``front`` before
-    this band's ``back``: the bands share no value, so the MXU can work
-    under the other band's vector arithmetic.  With one tile a head
-    (``multi`` False, s1024) only the nest is emitted.  Otherwise whole
-    tiles: skipped when wholly above the diagonal or when the segment-id
-    ranges of its rows and columns are disjoint."""
+    above it nothing.  The nest runs the next (band, head)'s ``front``
+    before this one's ``back``: they share no value, so the MXU can work
+    under the other's vector arithmetic.  A whole tile's heads follow one
+    another (two heads' [1024, 1024] squares at once do not fit VMEM).
+    With one tile a head (``multi`` False, s1024) only the nest is
+    emitted.  Otherwise whole tiles: skipped when wholly above the
+    diagonal or when the segment-id ranges of its rows and columns are
+    disjoint."""
     def tile(*rect):
-        back(*rect, front(*rect))
+        finish(*rect, [back(h, *rect, front(h, *rect))
+                       for h in range(hpb)])
 
     if sub:
         def on_diagonal():
-            rects = _causal_rects(block_q, sub, by)
-            made = front(*rects[0])
-            for rect, ahead in zip(rects, rects[1:] + [None]):
+            units = [(h, *rect) for rect in _causal_rects(block_q, sub, by)
+                     for h in range(hpb)]
+            made, parts = front(*units[0]), []
+            for unit, ahead in zip(units, units[1:] + [None]):
                 made_ahead = None if ahead is None else front(*ahead)
-                back(*rect, made)
+                parts.append(back(*unit, made))
+                if len(parts) == hpb:
+                    finish(*unit[1:], parts)
+                    parts = []
                 made = made_ahead
 
         if not multi:
@@ -315,8 +339,160 @@ def _visit_tile(front, back, causal, block_q, block_k, sub, multi, qi, kj,
         pl.when(run)(whole)
 
 
+def _div(a, b):
+    """``a // b`` and ``a % b`` of a non-negative traced int32 by a static
+    positive int, as the two primitives: the ``//`` and ``%`` of traced
+    values carry a sign correction that the Mosaic lowering traces anew at
+    every use (250 times a step's three kernels, most of their lowering
+    time)."""
+    return jax.lax.div(a, jnp.int32(b))
+
+
+def _rem(a, b):
+    return jax.lax.rem(a, jnp.int32(b))
+
+
+class _Heads(NamedTuple):
+    """How the kernels' 3-D arrays hold the heads.  ``packed``: the arrays
+    are ``[b, s, heads x d]``, the free reshape of the model's
+    ``[b, s, heads, d]``, and a block is ``hpb`` heads side by side in
+    128 lanes (:func:`_heads_a_block`).  Not packed: ``[b x heads, s, d]``
+    rows, one head a block (``hpb`` 1).  The grid's first axis runs over
+    head blocks, batch-major: ``nb`` query and ``gb`` K/V head blocks a
+    batch row, ``rep = nb // gb`` query blocks sharing a K/V block (GQA;
+    1 for MHA)."""
+    nb: int
+    gb: int
+    hpb: int
+    d: int
+    packed: bool
+
+    @property
+    def width(self):
+        return self.hpb * self.d
+
+    @property
+    def rep(self):
+        return self.nb // self.gb
+
+    def count(self, x3):
+        """Head blocks in a kernel array."""
+        return x3.shape[0] * x3.shape[2] // self.width
+
+    def batch(self, f):
+        """The batch row of query head block ``f``."""
+        return _div(f, self.nb)
+
+    def q_block(self, f, i):
+        """Block index of query head block ``f``, sequence block ``i``."""
+        if not self.packed:
+            return f, i, 0
+        return _div(f, self.nb), i, _rem(f, self.nb)
+
+    def kv_block(self, fk, j):
+        if not self.packed:
+            return fk, j, 0
+        return _div(fk, self.gb), j, _rem(fk, self.gb)
+
+    def kv_of(self, f):
+        """The K/V head block that query head block ``f`` reads (GQA: the
+        index map broadcasts a group to its query heads, the repeated
+        tensor never exists in HBM)."""
+        if self.rep == 1:
+            return f
+        return (_div(f, self.nb) * self.gb
+                + _div(_rem(f, self.nb), self.rep))
+
+    def q_of(self, fk, r):
+        """The ``r``-th query head block that reads K/V head block ``fk``."""
+        if self.rep == 1:
+            return fk
+        return _div(fk, self.gb) * self.nb + _rem(fk, self.gb) * self.rep + r
+
+    def slot(self, f, h):
+        """Which head of its K/V block head ``h`` of query block ``f``
+        reads: its own position for MHA (static); under GQA the block's
+        heads share one K/V head, a function of ``program_id``."""
+        if self.rep == 1:
+            return h
+        return _rem(_div(_rem(f, self.nb), self.rep // self.hpb), self.hpb)
+
+
+def _heads_a_block(n, g, d):
+    """The one rule of the kernels' layout, from static shapes: how many
+    heads share a 128-lane block of ``[b, s, heads x d]``.  ``128 // d``
+    where ``d`` divides 128 and both head counts fill whole blocks (two
+    heads at d = 64), one head where ``d`` is a multiple of 128.  0: the
+    shape cannot be blocked this way (an odd head count, a toy width, a
+    GQA group that a block would straddle) and takes the ``[b x heads, s,
+    d]`` route through the same kernels, paying the transposes."""
+    if d % _LANES == 0:
+        return 1
+    hpb = _LANES // d
+    if _LANES % d or n % hpb or g % hpb:
+        return 0
+    rep = n // g
+    return hpb if rep == 1 or rep % hpb == 0 else 0
+
+
+def _layout(q3, k3, gqa, d):
+    """The :class:`_Heads` of a kernel call: packed arrays of ``d``-wide
+    heads, or (``d`` None) per-head rows with ``gqa = (n, g)`` heads a
+    batch row."""
+    if d is None:
+        return _Heads(*(gqa or (1, 1)), 1, q3.shape[2], False)
+    hpb = max(_LANES // d, 1)
+    return _Heads(q3.shape[2] // (hpb * d), k3.shape[2] // (hpb * d), hpb,
+                  d, True)
+
+
+def _lane_head(shape, heads):
+    return _div(jax.lax.broadcasted_iota(jnp.int32, shape, 1), heads.d)
+
+
+def _move(x, src, dst, heads):
+    """``x`` [rows, width] with head slot ``src``'s lanes moved to slot
+    ``dst``.  MHA: the two are one static position and nothing moves.
+    GQA: a rotation by whole heads, chosen by the traced distance among
+    the static ones (a [rows, 128] operand beside [rows, 1024] scores)."""
+    if heads.hpb == 1 or isinstance(dst, int) and isinstance(src, int):
+        return x
+    from jax.experimental.pallas import tpu as pltpu
+
+    by = _rem(dst - src + heads.hpb, heads.hpb)
+    out = x
+    for c in range(1, heads.hpb):
+        out = jnp.where(by == c, pltpu.roll(x, c * heads.d, 1), out)
+    return out
+
+
+def _take(x, h, slot, heads):
+    """Head ``h`` of the block's heads in ``x`` [rows, width], placed in
+    the lanes of ``slot`` with zeros in every other head's: the operand
+    of a full-width product that contracts this head alone (the zeros
+    change no sum; on a 128 x 128 MXU a K = 128 product costs the passes
+    of a K = 64 one)."""
+    if heads.hpb == 1:
+        return x
+    x = _move(x, h, slot, heads)
+    return jnp.where(_lane_head(x.shape, heads) == slot, x, 0.0)
+
+
+def _merge(parts, heads, shape):
+    """One [rows, width] array from a per-head list: head ``h``'s lanes
+    from ``parts[h]`` ([rows, width] or a [rows, 1] column)."""
+    if heads.hpb == 1:
+        return parts[0]
+    lane = _lane_head(shape, heads)
+    out = parts[-1]
+    for h in range(heads.hpb - 2, -1, -1):
+        out = jnp.where(lane == h, parts[h], out)
+    return out
+
+
 def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
-                has_seg, dropout_p, sub, multi, padded, direct, *refs):
+                has_seg, dropout_p, sub, multi, padded, direct, heads,
+                *refs):
     if dropout_p > 0.0:
         seed_ref, refs = refs[0], refs[1:]
     seg_refs = None
@@ -325,7 +501,9 @@ def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
     q_ref, k_ref, v_ref = refs[:3]
     kpm_ref, refs = (refs[3], refs[4:]) if has_kpm else (None, refs[3:])
     o_ref, lse_ref = refs[:2]
-    bh, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    f, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    hs = range(heads.hpb)
+    slots = [heads.slot(f, h) for h in hs]
     q_start = qi * block_q
     k_start = kj * block_k
     # sub-tiled causal rows always hold an open key (col 0) from their
@@ -333,9 +511,9 @@ def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
     bounded = padded or not sub
     guarded = has_kpm or not sub
 
-    def _scores(r0, rn, c0, cn, crossed):
+    def _scores(h, r0, rn, c0, cn, crossed):
         rows, cols = slice(r0, r0 + rn), slice(c0, c0 + cn)
-        q = q_ref[0, rows, :].astype(jnp.float32)
+        q = _take(q_ref[0, rows, :].astype(jnp.float32), h, slots[h], heads)
         k = k_ref[0, cols, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -353,9 +531,10 @@ def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
             pred = _both(pred, (qseg == kseg) & (kseg >= 0))
         return s if pred is None else jnp.where(pred, s, _NEG_INF)
 
-    def _weights(r0, rn, c0, cn, s, m_prev):
-        """One softmax step over a rectangle: the rows' new maximum, the
-        sums of their weights, and the weights' product with v."""
+    def _weights(h, r0, rn, c0, cn, s, m_prev):
+        """One softmax step of head ``h`` over a rectangle: the rows' new
+        maximum, the sums of their weights, and the weights' product with
+        v, in the head's own lanes."""
         m_new = jnp.max(s, axis=-1, keepdims=True)
         if m_prev is not None:
             m_new = jnp.maximum(m_prev, m_new)
@@ -365,33 +544,42 @@ def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
             p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
         l_new = jnp.sum(p, axis=-1, keepdims=True)
         if dropout_p > 0.0:
-            keep = _keep_mask(seed_ref[0], bh, q_start + r0, k_start + c0,
-                              (rn, cn), 1.0 - dropout_p)
+            keep = _keep_mask(seed_ref[0], f * heads.hpb + h, q_start + r0,
+                              k_start + c0, (rn, cn), 1.0 - dropout_p)
             p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0, c0:c0 + cn, :],
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return m_new, l_new, pv
+        return m_new, l_new, _move(pv, slots[h], h, heads)
 
     def _write(rows, m, l, acc_rows):
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, rows, :] = (acc_rows / safe_l).astype(o_ref.dtype)
-        # logsumexp (fully-masked rows get -inf-ish sentinel)
-        lse = m + jnp.log(safe_l)
+        """``m``, ``l``: a column a head; ``acc_rows`` [rows, width]."""
+        shape = acc_rows.shape
+        safe_l = [jnp.where(x == 0.0, 1.0, x) for x in l]
+        o_ref[0, rows, :] = (
+            acc_rows / _merge(safe_l, heads, shape)).astype(o_ref.dtype)
+        # logsumexp (fully-masked rows get -inf-ish sentinel), a head's
+        # value across the head's own lanes
+        lse = [jnp.where(x == 0.0, _NEG_INF, mx + jnp.log(sx))
+               for x, mx, sx in zip(l, m, safe_l)]
         lse_ref[0, rows, :] = jnp.broadcast_to(
-            jnp.where(l == 0.0, _NEG_INF, lse),
-            (lse.shape[0], lse_ref.shape[2]))
+            _merge(lse, heads, shape), shape)
 
     if direct:
         # one kv tile: a rectangle holds all the keys its rows will see,
         # so its softmax is whole -- no running state, no scratch
-        def _emit(r0, rn, c0, cn, crossed, s):
-            _write(slice(r0, r0 + rn), *_weights(r0, rn, c0, cn, s, None))
+        def _whole(h, r0, rn, c0, cn, crossed, s):
+            return _weights(h, r0, rn, c0, cn, s, None)
 
-        _visit_tile(_scores, _emit, causal, block_q, block_k, sub, multi,
-                    qi, kj, seg_refs)
+        def _emit(r0, rn, c0, cn, crossed, parts):
+            m, l, pv = zip(*parts)
+            _write(slice(r0, r0 + rn), m, l, _merge(pv, heads, pv[0].shape))
+
+        _visit_tile(_scores, _whole, _emit, heads.hpb, causal, block_q,
+                    block_k, sub, multi, qi, kj, seg_refs)
         return
 
+    # running state: acc by lane like the output, m and l a head
     acc, m_s, l_s = refs[2:]
 
     @pl.when(kj == 0)
@@ -400,111 +588,137 @@ def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
-    def _absorb(r0, rn, c0, cn, crossed, s):
+    def _step(h, r0, rn, c0, cn, crossed, s):
         rows = slice(r0, r0 + rn)
-        m_prev = m_s[rows, :1]
-        m_new, l_new, pv = _weights(r0, rn, c0, cn, s, m_prev)
+        m_prev = m_s[h, rows, :1]
+        m_new, l_new, pv = _weights(h, r0, rn, c0, cn, s, m_prev)
         alpha = jnp.exp(m_prev - m_new)
         if guarded:
             alpha = jnp.where(m_new > _NEG_INF / 2, alpha, 0.0)
-        l_s[rows, :] = l_s[rows, :] * alpha + l_new
-        acc[rows, :] = acc[rows, :] * alpha + pv
-        m_s[rows, :] = jnp.broadcast_to(m_new, (rn, m_s.shape[1]))
+        l_s[h, rows, :] = l_s[h, rows, :] * alpha + l_new
+        m_s[h, rows, :] = jnp.broadcast_to(m_new, (rn, m_s.shape[2]))
+        return alpha, pv
 
-    _visit_tile(_scores, _absorb, causal, block_q, block_k, sub, multi,
-                qi, kj, seg_refs)
+    def _absorb(r0, rn, c0, cn, crossed, parts):
+        rows = slice(r0, r0 + rn)
+        alpha, pv = zip(*parts)
+        shape = pv[0].shape
+        acc[rows, :] = (acc[rows, :] * _merge(alpha, heads, shape)
+                        + _merge(pv, heads, shape))
+
+    _visit_tile(_scores, _step, _absorb, heads.hpb, causal, block_q,
+                block_k, sub, multi, qi, kj, seg_refs)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
-        _write(slice(None), m_s[:, :1], l_s[:, :1], acc[:])
+        _write(slice(None), [m_s[h, :, :1] for h in hs],
+               [l_s[h, :, :1] for h in hs], acc[:])
 
 
-def _kv_of(bq_flat, n, g):
-    """Flat kv-head row for flat q-head row ``bq_flat`` under GQA: the
-    [b, s, heads, d] → [b*heads, s, d] flattening is batch-major, so
-    batch = bq // n and the q head's group is (bq % n) // (n // g)."""
-    return (bq_flat // n) * g + (bq_flat % n) // (n // g)
+# Scoped VMEM a kernel may take.  Mosaic's default (16 MB on a v5e) holds
+# one head's whole [1024, 1024] tile below the diagonal (s, dp, p, ds in
+# float32); the second head of a block follows the first, yet the dk/dv
+# kernel's stack reaches 22.5 MB at two heads of 64 (the described-v5e
+# compile in tests/test_tpu_aot_compile.py), of 128 MiB on the chip.
+_VMEM_LIMIT = 32 * 1024 * 1024
 
 
-def _fwd_pallas(q3, k3, v3, kpm, seg, seed, scale, causal, sk_real,
-                block_q, block_k, dropout_p, interpret, out_dtype=None,
-                gqa=None):
+def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, sqp, d = q3.shape
-    skp = k3.shape[1]
-    grid = (bh, sqp // block_q, skp // block_k)
-    sub = _sub_tile(block_q, block_k) if causal and seg is None else 0
-    direct = bool(sub) and grid[2] == 1
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-    if gqa is not None:
-        # grouped K/V (GQA): the index map broadcasts each group head to
-        # its rep query heads — the repeated tensor never exists in HBM
-        n, g = gqa
-        k_spec = pl.BlockSpec(
-            (1, block_k, d),
-            lambda b, i, j, n=n, g=g: (_kv_of(b, n, g), j, 0),
-            memory_space=pltpu.VMEM)
-    else:
-        k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
-                              memory_space=pltpu.VMEM)
-    in_specs = []
-    args = []
+
+def _jit_once(driver):
+    """A kernel driver under ``jax.jit``, its arrays (and the ``None`` of
+    an absent mask, segment ids or seed) dynamic and the rest static: the
+    layers of a stack that call it alike share one trace of the kernel's
+    body and one lowering, where an unrolled 24-layer step traced and
+    lowered 72 (BERT's trace-and-lower 6.2 s against 13.4 without this,
+    on the sandbox's CPU, and 6.2 on [b x heads, s, d] kernels)."""
+    import inspect
+
+    names = list(inspect.signature(driver).parameters)
+    arrays = {"q3", "k3", "v3", "do3", "lse3", "o3", "kpm", "seg", "seed"}
+    return jax.jit(driver, static_argnames=[
+        n for n in names if n not in arrays])
+
+
+def _specs(heads, block_q, block_k, at, seg, seed, dropout_p):
+    """The block specs of a kernel whose grid points ``at`` maps to
+    ``(query head block, K/V head block, q block, kv block)``: the spec
+    of a q-like and of a k-like array, the key-padding mask's ([b, 1,
+    skp] additive f32), and the operands every kernel takes first -- the
+    dropout seed and the segment ids of the tile's rows and columns
+    ([b, sqp] / [b, skp] int32) -- as (in_specs, args)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, lambda *g: index(*at(*g)),
+                            memory_space=pltpu.VMEM)
+
+    w = heads.width
+    in_specs, args = [], []
     if dropout_p > 0.0:
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         args.append(seed)
     if seg is not None:
-        # (seg_q, seg_k): [b, sqp]/[b, skp] int32, indexed by batch
-        heads = bh // seg[0].shape[0]
-        in_specs.append(pl.BlockSpec(
-            (1, block_q), lambda b, i, j, h=heads: (b // h, i),
-            memory_space=pltpu.VMEM))
-        args.append(seg[0])
-        in_specs.append(pl.BlockSpec(
-            (1, block_k), lambda b, i, j, h=heads: (b // h, j),
-            memory_space=pltpu.VMEM))
-        args.append(seg[1])
+        in_specs += [
+            spec((1, block_q), lambda f, fk, i, j: (heads.batch(f), i)),
+            spec((1, block_k), lambda f, fk, i, j: (heads.batch(f), j))]
+        args += list(seg)
+    return (spec((1, block_q, w), lambda f, fk, i, j: heads.q_block(f, i)),
+            spec((1, block_k, w), lambda f, fk, i, j: heads.kv_block(fk, j)),
+            spec((1, 1, block_k), lambda f, fk, i, j: (heads.batch(f), 0, j)),
+            in_specs, args)
+
+
+@_jit_once
+def _fwd_pallas(q3, k3, v3, kpm, seg, seed, scale, causal, sk_real,
+                block_q, block_k, dropout_p, interpret, out_dtype=None,
+                gqa=None, d=None):
+    """The forward kernel on kernel arrays (:func:`_layout`: packed
+    ``[b, s, heads x d]`` when ``d`` is given, else ``[b x heads, s, d]``
+    rows with ``gqa = (n, g)``).  Returns ``o`` and the logsumexp, both
+    shaped like ``q3``: a head's logsumexp rides the head's own lanes."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    heads = _layout(q3, k3, gqa, d)
+    sqp, skp = q3.shape[1], k3.shape[1]
+    grid = (heads.count(q3), sqp // block_q, skp // block_k)
+    sub = _sub_tile(block_q, block_k) if causal and seg is None else 0
+    direct = bool(sub) and grid[2] == 1
+
+    q_spec, k_spec, kpm_spec, in_specs, args = _specs(
+        heads, block_q, block_k, lambda f, i, j: (f, heads.kv_of(f), i, j),
+        seg, seed, dropout_p)
     in_specs += [q_spec, k_spec, k_spec]
     args += [q3, k3, v3]
     if kpm is not None:
-        # [b, 1, skp] additive f32, indexed by batch = bh // heads
-        heads = bh // kpm.shape[0]
-        in_specs.append(pl.BlockSpec(
-            (1, 1, block_k),
-            lambda b, i, j, h=heads: (b // h, 0, j),
-            memory_space=pltpu.VMEM))
+        in_specs.append(kpm_spec)
         args.append(kpm)
 
-    out_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0),
-                     memory_space=pltpu.VMEM),
-    ]
-    out_shape = [
-        out_struct((bh, sqp, d), out_dtype or q3.dtype, q3),
-        out_struct((bh, sqp, _LANES), jnp.float32, q3),
-    ]
     with jax.named_scope("flash_fwd"):
         o, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, scale, causal, sk_real,
                               block_q, block_k, kpm is not None,
                               seg is not None, dropout_p, sub,
-                              grid[1:] != (1, 1), skp != sk_real, direct),
+                              grid[1:] != (1, 1), skp != sk_real, direct,
+                              heads),
             grid=grid,
             in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
+            out_specs=[q_spec, q_spec],
+            out_shape=[out_struct(q3.shape, out_dtype or q3.dtype, q3),
+                       out_struct(q3.shape, jnp.float32, q3)],
             scratch_shapes=[] if direct else [
-                pltpu.VMEM((block_q, d), jnp.float32),
-                pltpu.VMEM((block_q, _LANES), jnp.float32),
-                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, heads.width), jnp.float32),
+                pltpu.VMEM((heads.hpb, block_q, _LANES), jnp.float32),
+                pltpu.VMEM((heads.hpb, block_q, _LANES), jnp.float32),
             ],
+            compiler_params=_compiler_params(),
             interpret=interpret,
         )(*args)
-    return o, lse[:, :, 0]
+    return o, lse
 
 
 # ---------------------------------------------------------------------------
@@ -512,57 +726,57 @@ def _fwd_pallas(q3, k3, v3, kpm, seg, seed, scale, causal, sk_real,
 # ---------------------------------------------------------------------------
 
 
-def _scores_and_dp(q_ref, k_ref, v_ref, do_ref, kpm_ref, scale, rows, cols):
-    """The backward kernels' first stage over a score rectangle: the
-    scaled scores ``q k^T`` (plus the additive key mask) and ``do v^T``."""
-    q = q_ref[0, rows, :].astype(jnp.float32)
-    k = k_ref[0, cols, :].astype(jnp.float32)
+def _scores_and_dp(q, k, v, do, kpm, scale):
+    """The backward kernels' first stage for one head over a score
+    rectangle: the scaled scores ``q k^T`` (plus the additive key mask)
+    and ``do v^T``, ``q`` and ``do`` the head's :func:`_take`."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    if kpm_ref is not None:
-        s = s + kpm_ref[0, :, cols]
-    do = do_ref[0, rows, :].astype(jnp.float32)
+    if kpm is not None:
+        s = s + kpm
     dp = jax.lax.dot_general(
-        do, v_ref[0, cols, :].astype(jnp.float32),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     return s, dp
 
 
-def _bwd_dq_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
-                   has_seg, dropout_p, sub, multi, padded, *refs):
+def _bwd_tile(refs, has_kpm, has_seg, dropout_p, scale, heads, f, q_start,
+              k_start, sk_real, sq_real, guarded):
+    """What the backward kernels share: ``products(h, *rect)``, the first
+    stage of head ``h`` over a rectangle, and ``grads(h, *rect, made)``,
+    the head's ``p`` for dv, its ``ds`` and their operands."""
     if dropout_p > 0.0:
         seed_ref, refs = refs[0], refs[1:]
     seg_refs = None
     if has_seg:
         seg_refs, refs = refs[:2], refs[2:]
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref = refs[:6]
     kpm_ref, refs = (refs[6], refs[7:]) if has_kpm else (None, refs[6:])
-    dq_ref, dq_acc = refs
-    bh, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    slots = [heads.slot(f, h) for h in range(heads.hpb)]
 
-    @pl.when(kj == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+    def products(h, r0, rn, c0, cn, crossed):
+        rows, cols = slice(r0, r0 + rn), slice(c0, c0 + cn)
+        q = _take(q_ref[0, rows, :].astype(jnp.float32), h, slots[h], heads)
+        do = _take(do_ref[0, rows, :].astype(jnp.float32), h, slots[h],
+                   heads)
+        return (*_scores_and_dp(
+            q, k_ref[0, cols, :].astype(jnp.float32),
+            v_ref[0, cols, :].astype(jnp.float32), do,
+            kpm_ref[0, :, cols] if has_kpm else None, scale), q, do)
 
-    q_start, k_start = qi * block_q, kj * block_k
-    bounded = padded or not sub     # see _fwd_kernel
-    guarded = has_kpm or not sub
-
-    def _products(r0, rn, c0, cn, crossed):
-        return _scores_and_dp(q_ref, k_ref, v_ref, do_ref, kpm_ref, scale,
-                              slice(r0, r0 + rn), slice(c0, c0 + cn))
-
-    def _accumulate(r0, rn, c0, cn, crossed, made):
-        s, dp = made
+    def grads(h, r0, rn, c0, cn, crossed, made):
+        """``(p_acc, ds, q, do)``: the probabilities that weigh ``do``
+        into dv (dropped and rescaled under dropout), the score gradient
+        and the head's two operands from ``products``."""
+        s, dp, q, do = made
         rows, cols = slice(r0, r0 + rn), slice(c0, c0 + cn)
         pred = _open_pairs(q_start + r0, k_start + c0, (rn, cn), crossed,
-                           sk_real if bounded else None)
+                           sk_real, sq_real)
         if has_seg:
             qseg = seg_refs[0][0, rows].reshape(rn, 1)
             kseg = seg_refs[1][0, cols].reshape(1, cn)
             pred = _both(pred, (qseg == kseg) & (kseg >= 0))
-        lse = lse_ref[0, rows, :1]
+        lse = lse_ref[0, rows, h * heads.d:h * heads.d + 1]
         if guarded:
             # fully-masked rows carry the -inf lse sentinel: s - lse would
             # be ~0 there (additive -1e30 mask == -1e30 sentinel), not
@@ -572,101 +786,103 @@ def _bwd_dq_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
         p = jnp.exp(s - lse)
         if pred is not None:
             p = jnp.where(pred, p, 0.0)
+        p_acc = p
         if dropout_p > 0.0:
-            keep = _keep_mask(seed_ref[0], bh, q_start + r0, k_start + c0,
-                              (rn, cn), 1.0 - dropout_p)
-            dp = jnp.where(keep, dp / (1.0 - dropout_p), 0.0)
-        delta = delta_ref[0, rows, :1]
-        ds = p * (dp - delta) * scale
-        dq_acc[rows, :] += jax.lax.dot_general(
-            ds, k_ref[0, cols, :].astype(jnp.float32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            keep = _keep_mask(seed_ref[0], f * heads.hpb + h, q_start + r0,
+                              k_start + c0, (rn, cn), 1.0 - dropout_p)
+            inv = 1.0 / (1.0 - dropout_p)
+            p_acc = jnp.where(keep, p * inv, 0.0)
+            dp = jnp.where(keep, dp * inv, 0.0)
+        # delta = rowsum(do * o) of the head, from the forward's output
+        do_o = (do_ref[0, rows, :].astype(jnp.float32)
+                * o_ref[0, rows, :].astype(jnp.float32))
+        if heads.hpb > 1:
+            do_o = jnp.where(_lane_head(do_o.shape, heads) == h, do_o, 0.0)
+        delta = jnp.sum(do_o, axis=-1, keepdims=True)
+        return p_acc, p * (dp - delta) * scale, q, do
 
-    _visit_tile(_products, _accumulate, causal, block_q, block_k, sub,
-                multi, qi, kj, seg_refs)
+    return products, grads, seg_refs, k_ref, refs, slots
+
+
+def _dq_of(ds, k, h, slots, heads):
+    """Head ``h``'s ``ds k`` in the head's own lanes."""
+    return _move(jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32), slots[h], h, heads)
+
+
+def _dkv_of(p_acc, ds, q, do):
+    """A head's ``(p^T do, ds^T q)``: in its K/V head's lanes, zeros in
+    the others' (``q`` and ``do`` are zero there), so heads add up."""
+    return tuple(jax.lax.dot_general(
+        a, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        for a, x in ((p_acc, do), (ds, q)))
+
+
+def _bwd_dq_kernel(scale, causal, sk_real, padded, block_q, block_k,
+                   has_kpm, has_seg, dropout_p, sub, multi, heads, *refs):
+    f, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    q_start, k_start = qi * block_q, kj * block_k
+    bounded = padded or not sub     # see _fwd_kernel
+    products, grads, seg_refs, k_ref, (dq_ref, dq_acc), slots = _bwd_tile(
+        refs, has_kpm, has_seg, dropout_p, scale, heads, f, q_start,
+        k_start, sk_real if bounded else None, None, has_kpm or not sub)
+
+    @pl.when(kj == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def _dq(h, r0, rn, c0, cn, crossed, made):
+        _, ds, _, _ = grads(h, r0, rn, c0, cn, crossed, made)
+        return _dq_of(ds, k_ref[0, c0:c0 + cn, :].astype(jnp.float32), h,
+                      slots, heads)
+
+    def _accumulate(r0, rn, c0, cn, crossed, parts):
+        dq_acc[r0:r0 + rn, :] += _merge(parts, heads, parts[0].shape)
+
+    _visit_tile(products, _dq, _accumulate, heads.hpb, causal, block_q,
+                block_k, sub, multi, qi, kj, seg_refs)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(scale, causal, sq_real, sk_real, block_q, block_k,
-                    has_kpm, has_seg, dropout_p, gqa, sub, multi, padded,
+def _bwd_dkv_kernel(scale, causal, sq_real, sk_real, padded, block_q,
+                    block_k, has_kpm, has_seg, dropout_p, sub, multi, heads,
                     *refs):
-    if dropout_p > 0.0:
-        seed_ref, refs = refs[0], refs[1:]
-    seg_refs = None
-    if has_seg:
-        seg_refs, refs = refs[:2], refs[2:]
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    kpm_ref, refs = (refs[6], refs[7:]) if has_kpm else (None, refs[6:])
-    dk_ref, dv_ref, dk_acc, dv_acc = refs
-    if gqa is not None:
-        # grid (b*g, kv, rep, q): one dk/dv row accumulates all rep query
-        # heads of its group; bh reconstructs the flat q-head row so the
-        # dropout hash matches the forward bit-for-bit
-        n, g = gqa
-        rep = n // g
-        bkv, kj = pl.program_id(0), pl.program_id(1)
-        r, qi = pl.program_id(2), pl.program_id(3)
-        bh = (bkv // g) * n + (bkv % g) * rep + r
-        first = (r == 0) & (qi == 0)
-        last = (r == rep - 1) & (qi == pl.num_programs(3) - 1)
-    else:
-        bh, kj, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-        first = qi == 0
-        last = qi == pl.num_programs(2) - 1
+    # grid (K/V head blocks, kv, rep, q): one dk/dv block accumulates the
+    # rep query head blocks that read it (GQA; rep 1 for MHA); f is the
+    # query head block, so the dropout hash matches the forward's bit for
+    # bit
+    fk, kj = pl.program_id(0), pl.program_id(1)
+    r, qi = pl.program_id(2), pl.program_id(3)
+    f = heads.q_of(fk, r)
+    first = (r == 0) & (qi == 0)
+    last = (r == heads.rep - 1) & (qi == pl.num_programs(3) - 1)
+    q_start, k_start = qi * block_q, kj * block_k
+    bounded = padded or not sub     # see _fwd_kernel
+    products, grads, seg_refs, _, outs, _ = _bwd_tile(
+        refs, has_kpm, has_seg, dropout_p, scale, heads, f, q_start,
+        k_start, sk_real if bounded else None,
+        sq_real if bounded else None, has_kpm or not sub)
+    dk_ref, dv_ref, dk_acc, dv_acc = outs
 
     @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_start, k_start = qi * block_q, kj * block_k
-    bounded = padded or not sub     # see _fwd_kernel
-    guarded = has_kpm or not sub
+    def _dkv(h, *rect_made):
+        return _dkv_of(*grads(h, *rect_made))
 
-    def _products(r0, rn, c0, cn, crossed):
-        return _scores_and_dp(q_ref, k_ref, v_ref, do_ref, kpm_ref, scale,
-                              slice(r0, r0 + rn), slice(c0, c0 + cn))
+    def _accumulate(r0, rn, c0, cn, crossed, parts):
+        dv, dk = (sum(x) for x in zip(*parts))
+        dv_acc[c0:c0 + cn, :] += dv
+        dk_acc[c0:c0 + cn, :] += dk
 
-    def _accumulate(r0, rn, c0, cn, crossed, made):
-        s, dp = made
-        rows, cols = slice(r0, r0 + rn), slice(c0, c0 + cn)
-        pred = _open_pairs(q_start + r0, k_start + c0, (rn, cn), crossed,
-                           sk_real if bounded else None,
-                           sq_real if bounded else None)
-        if has_seg:
-            qseg = seg_refs[0][0, rows].reshape(rn, 1)
-            kseg = seg_refs[1][0, cols].reshape(1, cn)
-            pred = _both(pred, (qseg == kseg) & (kseg >= 0))
-        lse = lse_ref[0, rows, :1]
-        if guarded:
-            # see _bwd_dq_kernel: zero fully-masked rows (lse sentinel)
-            pred = _both(pred, lse > _NEG_INF / 2)
-        p = jnp.exp(s - lse)
-        if pred is not None:
-            p = jnp.where(pred, p, 0.0)
-        do = do_ref[0, rows, :].astype(jnp.float32)
-        if dropout_p > 0.0:
-            keep = _keep_mask(seed_ref[0], bh, q_start + r0, k_start + c0,
-                              (rn, cn), 1.0 - dropout_p)
-            inv = 1.0 / (1.0 - dropout_p)
-            p_acc = jnp.where(keep, p * inv, 0.0)
-            dp = jnp.where(keep, dp * inv, 0.0)
-        else:
-            p_acc = p
-        dv_acc[cols, :] += jax.lax.dot_general(
-            p_acc, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        delta = delta_ref[0, rows, :1]
-        ds = p * (dp - delta) * scale
-        dk_acc[cols, :] += jax.lax.dot_general(
-            ds, q_ref[0, rows, :].astype(jnp.float32),
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    _visit_tile(_products, _accumulate, causal, block_q, block_k, sub,
-                multi, qi, kj, seg_refs, by="cols")
+    _visit_tile(products, _dkv, _accumulate, heads.hpb, causal, block_q,
+                block_k, sub, multi, qi, kj, seg_refs, by="cols")
 
     @pl.when(last)
     def _finalize():
@@ -675,90 +891,41 @@ def _bwd_dkv_kernel(scale, causal, sq_real, sk_real, block_q, block_k,
 
 
 def _bwd_fused_kernel(scale, causal, sq_real, sk_real, block_q, skp,
-                      has_kpm, has_seg, dropout_p, gqa, *refs):
+                      has_kpm, has_seg, dropout_p, heads, *refs):
     """Single-pass backward for short key sequences: K/V stay fully
     VMEM-resident, the probability tile is computed ONCE, and dq/dk/dv
     all fall out of the same pass — where the split dq + dkv kernels
     recompute p twice and traverse HBM twice.  This is the class the
     reference serves with its small-seqlen fmha variants
     (fmha_api.cpp:358 `_nl` kernels)."""
-    if dropout_p > 0.0:
-        seed_ref, refs = refs[0], refs[1:]
-    if has_seg:
-        qseg_ref, kseg_ref, refs = refs[0], refs[1], refs[2:]
-    if has_kpm:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, kpm_ref,
-         dq_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    bh, qi = pl.program_id(0), pl.program_id(1)
-    if gqa is None:
-        first = qi == 0
-        last = qi == pl.num_programs(1) - 1
-    else:
-        # grouped K/V: the grid still walks q-head rows (batch-major, so
-        # a group's rep heads are consecutive in bh) while the dk/dv
-        # output block is the group row — init on the group's first
-        # (head, q-block) step, flush on its last
-        n, g = gqa
-        rep = n // g
-        r = (bh % n) % rep
-        first = (r == 0) & (qi == 0)
-        last = (r == rep - 1) & (qi == pl.num_programs(1) - 1)
+    f, qi = pl.program_id(0), pl.program_id(1)
+    # the grid walks query head blocks (batch-major, so the rep blocks
+    # that read one K/V block are consecutive) while the dk/dv output is
+    # the K/V block -- init on its first (block, q-block) step, flush on
+    # its last
+    r = _rem(_rem(f, heads.nb), heads.rep)
+    first = (r == 0) & (qi == 0)
+    last = (r == heads.rep - 1) & (qi == pl.num_programs(1) - 1)
+    products, grads, _, k_ref, outs, slots = _bwd_tile(
+        refs, has_kpm, has_seg, dropout_p, scale, heads, f, qi * block_q,
+        0, sk_real, sq_real, True)
+    dq_ref, dk_ref, dv_ref, dk_acc, dv_acc = outs
 
     @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_start = qi * block_q
-    q = q_ref[0].astype(jnp.float32)
+    tile = (0, block_q, 0, skp, causal)
     k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    if has_kpm:
-        s = s + kpm_ref[0]
-    col = jax.lax.broadcasted_iota(jnp.int32, (block_q, skp), 1)
-    row = q_start + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, skp), 0)
-    pred = (col < sk_real) & (row < sq_real)
-    if causal:
-        pred &= col <= row
-    if has_seg:
-        qseg = qseg_ref[0].reshape(block_q, 1)
-        kseg = kseg_ref[0].reshape(1, skp)
-        pred &= (qseg == kseg) & (kseg >= 0)
-    lse = lse_ref[0][:, :1]
-    # see _bwd_dq_kernel: zero fully-masked rows (lse sentinel)
-    pred &= lse > _NEG_INF / 2
-    p = jnp.where(pred, jnp.exp(s - lse), 0.0)
-    do = do_ref[0].astype(jnp.float32)
-    if dropout_p > 0.0:
-        keep = _keep_mask(seed_ref[0], bh, q_start, 0,
-                          (block_q, skp), 1.0 - dropout_p)
-        inv = 1.0 / (1.0 - dropout_p)
-        p_acc = jnp.where(keep, p * inv, 0.0)
-    else:
-        p_acc = p
-    dv_acc[:] += jax.lax.dot_general(
-        p_acc, do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if dropout_p > 0.0:
-        dp = jnp.where(keep, dp * (1.0 / (1.0 - dropout_p)), 0.0)
-    delta = delta_ref[0][:, :1]
-    ds = p * (dp - delta) * scale
-    dq_ref[0] = jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dk_acc[:] += jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    dq = []
+    for h in range(heads.hpb):
+        p_acc, ds, q, do = grads(h, *tile, products(h, *tile))
+        dq.append(_dq_of(ds, k, h, slots, heads))
+        dv, dk = _dkv_of(p_acc, ds, q, do)
+        dv_acc[:] += dv
+        dk_acc[:] += dk
+    dq_ref[0] = _merge(dq, heads, dq[0].shape).astype(dq_ref.dtype)
 
     @pl.when(last)
     def _finalize():
@@ -766,202 +933,110 @@ def _bwd_fused_kernel(scale, causal, sq_real, sk_real, block_q, skp,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_pallas_fused(q3, k3, v3, do3, lse, delta, kpm, seg, seed, scale,
+@_jit_once
+def _bwd_pallas_fused(q3, k3, v3, do3, lse3, o3, kpm, seg, seed, scale,
                       causal, sq_real, sk_real, block_q, dropout_p,
-                      interpret, out_dtype=None, gqa=None):
-    """Driver for :func:`_bwd_fused_kernel` — grid (bh, q-blocks), K/V
-    full-width per group (call only when the padded key length fits
-    VMEM).  Under ``gqa`` the k/v (and dk/dv) rows are group-width; the
-    group's rep consecutive q-head rows accumulate into one output
-    block, which stays resident across their grid steps."""
+                      interpret, out_dtype=None, gqa=None, d=None):
+    """Driver for :func:`_bwd_fused_kernel` — grid (query head blocks,
+    q-blocks), K/V full-length a block (call only when the padded key
+    length fits VMEM).  ``lse3`` and ``o3`` are the forward's two outputs
+    (:func:`_fwd_pallas`), shaped like ``q3``.  Under GQA the rep
+    consecutive query head blocks of a K/V block accumulate into one
+    dk/dv output block, which stays resident across their grid steps."""
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, sqp, d = q3.shape
-    skp = k3.shape[1]
-    lse3 = jnp.broadcast_to(lse[:, :, None], (bh, sqp, _LANES))
-    delta3 = jnp.broadcast_to(delta[:, :, None], (bh, sqp, _LANES))
-    qmap = lambda b, i: (b, i, 0)
-    if gqa is not None:
-        n, g = gqa
-        kmap = lambda b, i, n=n, g=g: (_kv_of(b, n, g), 0, 0)
-    else:
-        kmap = lambda b, i: (b, 0, 0)
-    qspec = pl.BlockSpec((1, block_q, d), qmap, memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((1, skp, d), kmap, memory_space=pltpu.VMEM)
-    rowspec = pl.BlockSpec((1, block_q, _LANES), qmap,
-                           memory_space=pltpu.VMEM)
-    in_specs = []
-    args = []
-    if dropout_p > 0.0:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.append(seed)
-    if seg is not None:
-        heads = bh // seg[0].shape[0]
-        in_specs.append(pl.BlockSpec(
-            (1, block_q), lambda b, i, h=heads: (b // h, i),
-            memory_space=pltpu.VMEM))
-        args.append(seg[0])
-        in_specs.append(pl.BlockSpec(
-            (1, skp), lambda b, i, h=heads: (b // h, 0),
-            memory_space=pltpu.VMEM))
-        args.append(seg[1])
-    in_specs += [qspec, kspec, kspec, qspec, rowspec, rowspec]
-    args += [q3, k3, v3, do3, lse3, delta3]
+    heads = _layout(q3, k3, gqa, d)
+    sqp, skp = q3.shape[1], k3.shape[1]
+    qspec, kspec, kpm_spec, in_specs, args = _specs(
+        heads, block_q, skp, lambda f, i: (f, heads.kv_of(f), i, 0),
+        seg, seed, dropout_p)
+    in_specs += [qspec, kspec, kspec, qspec, qspec, qspec]
+    args += [q3, k3, v3, do3, lse3, o3]
     if kpm is not None:
-        heads = bh // kpm.shape[0]
-        in_specs.append(pl.BlockSpec(
-            (1, 1, skp), lambda b, i, h=heads: (b // h, 0, 0),
-            memory_space=pltpu.VMEM))
+        in_specs.append(kpm_spec)
         args.append(kpm)
-    nkv = k3.shape[0]
     with jax.named_scope("flash_bwd"):
         dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, scale, causal, sq_real,
                               sk_real, block_q, skp, kpm is not None,
-                              seg is not None, dropout_p, gqa),
-            grid=(bh, sqp // block_q),
+                              seg is not None, dropout_p, heads),
+            grid=(heads.count(q3), sqp // block_q),
             in_specs=in_specs,
             out_specs=[qspec, kspec, kspec],
-            out_shape=[out_struct((bh, sqp, d), out_dtype or q3.dtype, q3),
-                       out_struct((nkv, skp, d), out_dtype or k3.dtype, k3),
-                       out_struct((nkv, skp, d), out_dtype or v3.dtype, k3)],
-            scratch_shapes=[pltpu.VMEM((skp, d), jnp.float32),
-                            pltpu.VMEM((skp, d), jnp.float32)],
+            out_shape=[out_struct(q3.shape, out_dtype or q3.dtype, q3),
+                       out_struct(k3.shape, out_dtype or k3.dtype, k3),
+                       out_struct(k3.shape, out_dtype or v3.dtype, k3)],
+            scratch_shapes=[pltpu.VMEM((skp, heads.width), jnp.float32),
+                            pltpu.VMEM((skp, heads.width), jnp.float32)],
+            compiler_params=_compiler_params(),
             interpret=interpret,
         )(*args)
     return dq, dk, dv
 
 
-def _bwd_pallas(q3, k3, v3, do3, lse, delta, kpm, seg, seed, scale,
+@_jit_once
+def _bwd_pallas(q3, k3, v3, do3, lse3, o3, kpm, seg, seed, scale,
                 causal, sq_real, sk_real, block_q, block_k, dropout_p,
-                interpret, out_dtype=None, gqa=None):
+                interpret, out_dtype=None, gqa=None, d=None):
+    """The split backward pair on kernel arrays, with the forward's two
+    outputs ``lse3`` and ``o3`` (:func:`_fwd_pallas`; shaped like ``q3``):
+    the kernels make ``delta = rowsum(do * o)`` themselves."""
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, sqp, d = q3.shape
-    skp = k3.shape[1]
-    lse3 = jnp.broadcast_to(lse[:, :, None], (bh, sqp, _LANES))
-    delta3 = jnp.broadcast_to(delta[:, :, None], (bh, sqp, _LANES))
+    heads = _layout(q3, k3, gqa, d)
+    sqp, skp = q3.shape[1], k3.shape[1]
     sub = _sub_tile(block_q, block_k) if causal and seg is None else 0
     multi = (sqp, skp) != (block_q, block_k)
 
-    def qspec(f):
-        return pl.BlockSpec((1, block_q, d), f, memory_space=pltpu.VMEM)
-
-    def kspec(f):
-        return pl.BlockSpec((1, block_k, d), f, memory_space=pltpu.VMEM)
-
-    def rowspec(f):
-        return pl.BlockSpec((1, block_q, _LANES), f,
-                            memory_space=pltpu.VMEM)
-
-    if gqa is not None:
-        n, g = gqa   # bound once for both the dq and dkv sections
-
-    # --- dq: grid (bh, q, kv) ------------------------------------------
-    qmap = lambda b, i, j: (b, i, 0)
-    if gqa is not None:
-        kmap = lambda b, i, j, n=n, g=g: (_kv_of(b, n, g), j, 0)
-    else:
-        kmap = lambda b, i, j: (b, j, 0)
-    in_specs = []
-    args = []
-    if dropout_p > 0.0:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.append(seed)
-    if seg is not None:
-        heads = bh // seg[0].shape[0]
-        in_specs.append(pl.BlockSpec(
-            (1, block_q), lambda b, i, j, h=heads: (b // h, i),
-            memory_space=pltpu.VMEM))
-        args.append(seg[0])
-        in_specs.append(pl.BlockSpec(
-            (1, block_k), lambda b, i, j, h=heads: (b // h, j),
-            memory_space=pltpu.VMEM))
-        args.append(seg[1])
-    in_specs += [qspec(qmap), kspec(kmap), kspec(kmap), qspec(qmap),
-                 rowspec(qmap), rowspec(qmap)]
-    args += [q3, k3, v3, do3, lse3, delta3]
-    if kpm is not None:
-        heads = bh // kpm.shape[0]
-        in_specs.append(pl.BlockSpec(
-            (1, 1, block_k), lambda b, i, j, h=heads: (b // h, 0, j),
-            memory_space=pltpu.VMEM))
-        args.append(kpm)
-    with jax.named_scope("flash_bwd_dq"):
-        dq = pl.pallas_call(
-            functools.partial(_bwd_dq_kernel, scale, causal, sk_real,
-                              block_q, block_k, kpm is not None,
-                              seg is not None, dropout_p, sub, multi,
-                              skp != sk_real),
-            grid=(bh, sqp // block_q, skp // block_k),
-            in_specs=in_specs,
-            out_specs=qspec(qmap),
-            out_shape=out_struct((bh, sqp, d), out_dtype or q3.dtype, q3),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+    def call(kernel, grid, at, outs):
+        """One kernel over ``grid`` (:func:`_specs` has ``at``); ``outs``
+        says what each output is like, "q" or "k": its shape and blocks,
+        and the rows of its float32 accumulator."""
+        qspec, kspec, kpm_spec, in_specs, args = _specs(
+            heads, block_q, block_k, at, seg, seed, dropout_p)
+        in_specs += [qspec, kspec, kspec, qspec, qspec, qspec]
+        args += [q3, k3, v3, do3, lse3, o3]
+        if kpm is not None:
+            in_specs.append(kpm_spec)
+            args.append(kpm)
+        like = {"q": (q3, qspec, block_q), "k": (k3, kspec, block_k)}
+        return pl.pallas_call(
+            functools.partial(
+                kernel, block_q, block_k, kpm is not None, seg is not None,
+                dropout_p, sub, multi, heads),
+            grid=grid, in_specs=in_specs,
+            out_specs=[like[x][1] for x in outs],
+            out_shape=[out_struct(like[x][0].shape,
+                                  out_dtype or like[x][0].dtype, like[x][0])
+                       for x in outs],
+            scratch_shapes=[
+                pltpu.VMEM((like[x][2], heads.width), jnp.float32)
+                for x in outs],
+            compiler_params=_compiler_params(),
             interpret=interpret,
         )(*args)
 
-    # --- dk/dv ---------------------------------------------------------
-    # Classic: grid (bh, kv, q), one q-head per dk/dv row.  GQA: grid
-    # (b*g, kv, rep, q) — the rep query heads of a group are a grid dim
-    # OUTSIDE the q-block dim, so the (b*g)-row dk/dv output block stays
-    # fixed across (rep × q-blocks) consecutive steps while the kernel
+    # --- dq: grid (query head blocks, q, kv) ---------------------------
+    with jax.named_scope("flash_bwd_dq"):
+        dq, = call(
+            functools.partial(_bwd_dq_kernel, scale, causal, sk_real,
+                              skp != sk_real),
+            (heads.count(q3), sqp // block_q, skp // block_k),
+            lambda f, i, j: (f, heads.kv_of(f), i, j), "q")
+
+    # --- dk/dv: grid (K/V head blocks, kv, rep, q) ---------------------
+    # The rep query head blocks of a K/V block (GQA; 1 for MHA) are a
+    # grid dim OUTSIDE the q-block dim, so the dk/dv output block stays
+    # fixed across (rep x q-blocks) consecutive steps while the kernel
     # accumulates all of the group's query heads into it; the repeated
     # dk/dv tensor (and the jnp.repeat forward tensor whose autodiff
     # would sum it) never exists in HBM.
-    if gqa is not None:
-        rep = n // g
-        qmap2 = lambda b, j, r, i, n=n, g=g, rp=rep: (
-            (b // g) * n + (b % g) * rp + r, i, 0)
-        kmap2 = lambda b, j, r, i: (b, j, 0)
-        grid2 = (k3.shape[0], skp // block_k, rep, sqp // block_q)
-        seg_qmap = lambda b, j, r, i, g=g: (b // g, i)
-        seg_kmap = lambda b, j, r, i, g=g: (b // g, j)
-        kpm_map = lambda b, j, r, i, g=g: (b // g, 0, j)
-    else:
-        qmap2 = lambda b, j, i: (b, i, 0)
-        kmap2 = lambda b, j, i: (b, j, 0)
-        grid2 = (bh, skp // block_k, sqp // block_q)
-        heads_s = bh // seg[0].shape[0] if seg is not None else 1
-        seg_qmap = lambda b, j, i, h=heads_s: (b // h, i)
-        seg_kmap = lambda b, j, i, h=heads_s: (b // h, j)
-        heads_m = bh // kpm.shape[0] if kpm is not None else 1
-        kpm_map = lambda b, j, i, h=heads_m: (b // h, 0, j)
-    in_specs = []
-    args = []
-    if dropout_p > 0.0:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.append(seed)
-    if seg is not None:
-        in_specs.append(pl.BlockSpec(
-            (1, block_q), seg_qmap, memory_space=pltpu.VMEM))
-        args.append(seg[0])
-        in_specs.append(pl.BlockSpec(
-            (1, block_k), seg_kmap, memory_space=pltpu.VMEM))
-        args.append(seg[1])
-    in_specs += [qspec(qmap2), kspec(kmap2), kspec(kmap2), qspec(qmap2),
-                 rowspec(qmap2), rowspec(qmap2)]
-    args += [q3, k3, v3, do3, lse3, delta3]
-    if kpm is not None:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, block_k), kpm_map, memory_space=pltpu.VMEM))
-        args.append(kpm)
-    nkv = k3.shape[0]
     with jax.named_scope("flash_bwd_dkv"):
-        dk, dv = pl.pallas_call(
+        dk, dv = call(
             functools.partial(_bwd_dkv_kernel, scale, causal, sq_real,
-                              sk_real, block_q, block_k, kpm is not None,
-                              seg is not None, dropout_p, gqa, sub, multi,
-                              (sqp, skp) != (sq_real, sk_real)),
-            grid=grid2,
-            in_specs=in_specs,
-            out_specs=[kspec(kmap2), kspec(kmap2)],
-            out_shape=[out_struct((nkv, skp, d), out_dtype or k3.dtype, k3),
-                       out_struct((nkv, skp, d), out_dtype or v3.dtype, k3)],
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
-            interpret=interpret,
-        )(*args)
+                              sk_real, (sqp, skp) != (sq_real, sk_real)),
+            (heads.count(k3), skp // block_k, heads.rep, sqp // block_q),
+            lambda fk, j, r, i: (heads.q_of(fk, r), fk, i, j), "kk")
     return dq, dk, dv
 
 
@@ -979,6 +1054,48 @@ def _to_bh(x):
 def _from_bh(x3, b, n):
     bh, s, d = x3.shape
     return x3.reshape(b, n, s, d).transpose(0, 2, 1, 3)
+
+
+def _to_kernel(x, hpb):
+    """[b, s, n, d] → the kernels' array: the free reshape [b, s, n x d]
+    where :func:`_heads_a_block` packs, else [b x n, s, d] rows."""
+    b, s, n, d = x.shape
+    return x.reshape(b, s, n * d) if hpb else _to_bh(x)
+
+
+def _from_kernel(x3, b, n, hpb):
+    return x3.reshape(b, x3.shape[1], n, -1) if hpb else _from_bh(x3, b, n)
+
+
+def _head_lanes(n, d):
+    """[n, n x d] float32, 1 where a lane belongs to the head."""
+    return (jnp.arange(n * d)[None, :] // d
+            == jnp.arange(n)[:, None]).astype(jnp.float32)
+
+
+def _lse_to_kernel(lse, d, hpb):
+    """The saved logsumexp [b, s, n] → shaped like the kernels' q array,
+    a head's value across the head's ``d`` lanes.  Packed, the spread is
+    a product with a 0/1 matrix at full precision (exact: one term a
+    sum): as a broadcast XLA lays the [b, s, n, d] array out sequence-
+    minor after its small operand and transposes all of it back."""
+    b, s, n = lse.shape
+    if hpb:
+        return jnp.einsum("bsn,nl->bsl", lse, _head_lanes(n, d),
+                          precision=jax.lax.Precision.HIGHEST)
+    return jnp.broadcast_to(
+        lse.transpose(0, 2, 1).reshape(b * n, s, 1), (b * n, s, d))
+
+
+def _lse_from_kernel(lse3, b, n, hpb):
+    """The kernel's logsumexp → [b, s, n], the first lane of every head
+    (packed: picked by the same kind of product, for the same reason)."""
+    if hpb:
+        first = (jnp.arange(lse3.shape[2])[None, :]
+                 == jnp.arange(n)[:, None] * (lse3.shape[2] // n))
+        return jnp.einsum("bsl,nl->bsn", lse3, first.astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)
+    return lse3[:, :, 0].reshape(b, n, -1).transpose(0, 2, 1)
 
 
 def _blocks(sq, sk):
@@ -1001,6 +1118,16 @@ def _blocks(sq, sk):
     1024 x 1024 in bands of 128     344      601      822
     512 x 512 grid tiles            1023     968      1251
     256 x 512 grid tiles            1052     1205     1351
+    ==============================  =======  =======  =======
+
+    Since PR 33 a grid step holds two heads of 64 in blocks [1024, 128]
+    of ``[b, s, heads x d]`` and the backward makes ``delta`` from ``o``
+    itself (no widened ``lse``/``delta``).  A layer call inside the GPT
+    cell's step, bands of 256 (``scope_times``, my chip runs, PR 33;
+    PR 31's kernels read 299 / 391 / 561 the same way):
+
+    ==============================  =======  =======  =======
+    two heads a block, bands 256    303 us   363 us   522 us
     ==============================  =======  =======  =======
 
     At b2 x 32/8 heads x s8192 (8 x 8 tiles, 8 of the 36 executed on the
@@ -1099,13 +1226,13 @@ def _flash_fwd(q, k, v, kpm, seg, seed, causal, scale, dropout_p):
     b, sq, n, d = q.shape
     sk = k.shape[1]
     g = k.shape[2]
-    gqa = (n, g) if g != n else None
+    hpb = _heads_a_block(n, g, d)
     block_q, block_k = _blocks(sq, sk)
     sqp = pl.cdiv(sq, block_q) * block_q
     skp = pl.cdiv(sk, block_k) * block_k
-    q3 = _pad_to(_to_bh(q), sqp, 1)
-    k3 = _pad_to(_to_bh(k), skp, 1)
-    v3 = _pad_to(_to_bh(v), skp, 1)
+    q3 = _pad_to(_to_kernel(q, hpb), sqp, 1)
+    k3 = _pad_to(_to_kernel(k, hpb), skp, 1)
+    v3 = _pad_to(_to_kernel(v, hpb), skp, 1)
     kpm3 = (None if kpm is None
             else _pad_to(kpm.astype(jnp.float32)[:, None, :], skp, 2))
     seg3 = _seg_pads(seg, sqp, skp)
@@ -1114,12 +1241,15 @@ def _flash_fwd(q, k, v, kpm, seg, seed, causal, scale, dropout_p):
         None if seg3 is None else seg3[0],
         None if seg3 is None else seg3[1], seed)
     seg3 = None if seg3 is None else (seg3q, seg3k)
-    o3, lse = _fwd_pallas(q3, k3, v3, kpm3, seg3, seed, scale, causal,
-                          sk, block_q, block_k, dropout_p,
-                          interpret=not on_tpu(), gqa=gqa)
+    o3, lse3 = _fwd_pallas(q3, k3, v3, kpm3, seg3, seed, scale, causal,
+                           sk, block_q, block_k, dropout_p,
+                           interpret=not on_tpu(), gqa=(n, g),
+                           d=d if hpb else None)
     # the two arrays a rematted layer keeps (module docstring)
-    o = checkpoint_name(_from_bh(o3, b, n)[:, :sq], REMAT_SAVED_NAMES[0])
-    lse = checkpoint_name(lse, REMAT_SAVED_NAMES[1])
+    o = checkpoint_name(_from_kernel(o3, b, n, hpb)[:, :sq],
+                        REMAT_SAVED_NAMES[0])
+    lse = checkpoint_name(_lse_from_kernel(lse3, b, n, hpb)[:, :sq],
+                          REMAT_SAVED_NAMES[1])
     return o, (q, k, v, kpm, seg, seed, o, lse)
 
 
@@ -1128,40 +1258,37 @@ def _flash_bwd(causal, scale, dropout_p, res, do):
     b, sq, n, d = q.shape
     sk = k.shape[1]
     g = k.shape[2]
-    gqa = (n, g) if g != n else None
+    hpb = _heads_a_block(n, g, d)
     block_q, block_k = _blocks(sq, sk)
     sqp = pl.cdiv(sq, block_q) * block_q
     skp = pl.cdiv(sk, block_k) * block_k
-    q3 = _pad_to(_to_bh(q), sqp, 1)
-    k3 = _pad_to(_to_bh(k), skp, 1)
-    v3 = _pad_to(_to_bh(v), skp, 1)
-    do3 = _pad_to(_to_bh(do), sqp, 1)
-    o3 = _pad_to(_to_bh(o), sqp, 1)
-    lse3 = _pad_to(lse, sqp, 1)
-    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1)
+    q3 = _pad_to(_to_kernel(q, hpb), sqp, 1)
+    k3 = _pad_to(_to_kernel(k, hpb), skp, 1)
+    v3 = _pad_to(_to_kernel(v, hpb), skp, 1)
+    do3 = _pad_to(_to_kernel(do, hpb), sqp, 1)
+    o3 = _pad_to(_to_kernel(o, hpb), sqp, 1)
+    lse3 = _pad_to(_lse_to_kernel(lse, d, hpb), sqp, 1)
     kpm3 = (None if kpm is None
             else _pad_to(kpm.astype(jnp.float32)[:, None, :], skp, 2))
     seg3 = _seg_pads(seg, sqp, skp)
-    q3, k3, v3, do3, lse3, delta, kpm3, seg3q, seg3k, seed = _unify_vma(
-        q3, k3, v3, do3, lse3, delta, kpm3,
+    q3, k3, v3, do3, lse3, o3, kpm3, seg3q, seg3k, seed = _unify_vma(
+        q3, k3, v3, do3, lse3, o3, kpm3,
         None if seg3 is None else seg3[0],
         None if seg3 is None else seg3[1], seed)
     seg3 = None if seg3 is None else (seg3q, seg3k)
     plan, fused_bq = _bwd_plan(sqp, skp, block_q)
+    layout = dict(interpret=not on_tpu(), gqa=(n, g), d=d if hpb else None)
     if plan == "fused":
         dq3, dk3, dv3 = _bwd_pallas_fused(
-            q3, k3, v3, do3, lse3, delta, kpm3, seg3, seed, scale,
-            causal, sq, sk, fused_bq, dropout_p,
-            interpret=not on_tpu(), gqa=gqa)
+            q3, k3, v3, do3, lse3, o3, kpm3, seg3, seed, scale,
+            causal, sq, sk, fused_bq, dropout_p, **layout)
     else:
         dq3, dk3, dv3 = _bwd_pallas(
-            q3, k3, v3, do3, lse3, delta, kpm3, seg3, seed, scale,
-            causal, sq, sk, block_q, block_k, dropout_p,
-            interpret=not on_tpu(), gqa=gqa)
-    dq = _from_bh(dq3, b, n)[:, :sq]
-    dk = _from_bh(dk3, b, g)[:, :sk]
-    dv = _from_bh(dv3, b, g)[:, :sk]
+            q3, k3, v3, do3, lse3, o3, kpm3, seg3, seed, scale,
+            causal, sq, sk, block_q, block_k, dropout_p, **layout)
+    dq = _from_kernel(dq3, b, n, hpb)[:, :sq]
+    dk = _from_kernel(dk3, b, g, hpb)[:, :sk]
+    dv = _from_kernel(dv3, b, g, hpb)[:, :sk]
     # The kernel treats the (float) mask as a constant: the wrapper
     # stop-gradients it, so a zero cotangent is the user-visible truth.
     # Learned additive masks/biases belong on the differentiable XLA

@@ -153,7 +153,7 @@ def _chunk_fwd(q3, k3, v3, scale, causal_mode, s_local, block_q,
             o, lse = _fwd_pallas(q3, k3, v3, None, None, None, scale,
                                  causal, s_local, block_q, block_k, 0.0,
                                  False, out_dtype=jnp.float32, gqa=gqa)
-            return o, lse
+            return o, lse[:, :, 0]
         return _chunk_fwd_ref(q3, _expand_groups(k3, gqa),
                               _expand_groups(v3, gqa), scale, causal,
                               s_local)
@@ -170,17 +170,23 @@ def _chunk_fwd(q3, k3, v3, scale, causal_mode, s_local, block_q,
         None)
 
 
-def _chunk_bwd(q3, k3, v3, do3, lse, delta, scale, causal_mode, s_local,
+def _chunk_bwd(q3, k3, v3, do3, lse, o3, scale, causal_mode, s_local,
                block_q, block_k, gqa=None):
+    """``lse`` [bh, s] and ``o3`` are the ring's FINAL logsumexp and
+    output: the kernels take the logsumexp across the head's lanes and
+    make ``delta = rowsum(do * o)`` themselves."""
     use_pallas = on_tpu()
 
     def run(causal):
         if use_pallas:
             dq, dk, dv = _bwd_pallas(
-                q3, k3, v3, do3, lse, delta, None, None, None, scale,
-                causal, s_local, s_local, block_q, block_k, 0.0, False,
-                out_dtype=jnp.float32, gqa=gqa)
+                q3, k3, v3, do3,
+                jnp.broadcast_to(lse[:, :, None], q3.shape), o3, None,
+                None, None, scale, causal, s_local, s_local, block_q,
+                block_k, 0.0, False, out_dtype=jnp.float32, gqa=gqa)
             return dq, dk, dv
+        delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
+                        axis=-1)
         dq, dk, dv = _chunk_bwd_ref(
             q3, _expand_groups(k3, gqa), _expand_groups(v3, gqa), do3,
             lse, delta, scale, causal, s_local)
@@ -292,15 +298,13 @@ def _ring_vjp_bwd(axis_name, causal, scale, res, do):
     v3 = _pad_to(_to_bh(v), sp, 1)
     do3 = _pad_to(_to_bh(do), sp, 1)
     o3 = _pad_to(_to_bh(o), sp, 1)
-    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1)
 
     def step(t, carry):
         k_cur, v_cur, dk_cur, dv_cur, dq_acc = carry
         src = (my - t) % ndev
         mode = _mode(my, src, causal)
         dq_c, dk_c, dv_c = _chunk_bwd(
-            q3, k_cur, v_cur, do3, lse, delta, scale, mode, s_local,
+            q3, k_cur, v_cur, do3, lse, o3, scale, mode, s_local,
             block_q, block_k, gqa=gqa)
         dq_acc = dq_acc + dq_c
         dk_cur = dk_cur + dk_c

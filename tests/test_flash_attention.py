@@ -671,3 +671,134 @@ class TestCausalSubTiles:
 
         assert causal_work_share(s, s) == share
         assert causal_work_share(s, s, causal=False) == 1.0
+
+
+def _fwd_and_grads(fn, q, k, v, **kw):
+    def loss(q, k, v):
+        o = fn(q, k, v, **kw)
+        return jnp.sum(o * jnp.cos(o)), o
+
+    (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                   has_aux=True)(q, k, v)
+    return (o, *g)
+
+
+def _assert_same(got, want, tol=1e-4):
+    for a, b, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=tol,
+                                   rtol=tol, err_msg=name)
+
+
+class TestHeadsABlock:
+    """The kernels' boundary is ``[b, s, heads x d]`` in 128-lane blocks
+    of ``128 // d`` heads (``_heads_a_block``): every head of a block is
+    worked on masked full-width operands and merged by lane; under GQA a
+    block's heads read one K/V head, in either half of its block."""
+
+    @pytest.fixture
+    def packed_only(self, monkeypatch):
+        """The per-head route's transposes refuse to run."""
+        from apex_tpu.ops import flash_attention as fa
+
+        def refuse(*a):
+            raise AssertionError("took the [b x heads, s, d] route")
+
+        monkeypatch.setattr(fa, "_to_bh", refuse)
+
+    @pytest.mark.parametrize("n,g,d,hpb", [
+        (16, 16, 64, 2), (2, 2, 64, 2), (32, 8, 64, 2), (4, 2, 64, 2),
+        (4, 4, 128, 1), (2, 1, 128, 1), (2, 2, 256, 1), (8, 8, 32, 4),
+        (16, 4, 32, 4),
+        # fall back: an odd head count, a toy width that leaves a block
+        # part empty, a K/V head count that does (MQA), a group that a
+        # block would straddle
+        (3, 3, 64, 0), (2, 2, 32, 0), (8, 1, 64, 0), (6, 2, 64, 0),
+        (4, 4, 48, 0),
+    ])
+    def test_rule(self, n, g, d, hpb):
+        from apex_tpu.ops.flash_attention import _heads_a_block
+
+        assert _heads_a_block(n, g, d) == hpb
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("n,g,d", [
+        (2, 2, 64), (4, 4, 64), (16, 16, 64),   # MHA, two heads a block
+        (2, 2, 128),                            # one head a block
+        (8, 4, 64),     # GQA rep 2: a q block's K/V head in half 0 and 1
+        (16, 4, 64),    # GQA rep 4
+        (8, 8, 32),     # four heads a block
+        (16, 4, 32),    # four heads a block, GQA: three rotations
+    ])
+    def test_matches_reference(self, n, g, d, causal, packed_only):
+        rng = np.random.RandomState(n * d + g)
+        q = jnp.asarray(rng.randn(1, 128, n, d), jnp.float32) * 0.5
+        k = jnp.asarray(rng.randn(1, 128, g, d), jnp.float32) * 0.5
+        v = jnp.asarray(rng.randn(1, 128, g, d), jnp.float32) * 0.5
+        _assert_same(_fwd_and_grads(flash_attention, q, k, v, causal=causal),
+                     _fwd_and_grads(mha_reference, q, k, v, causal=causal))
+
+    @pytest.mark.parametrize("plan", ["fused", "split"])
+    @pytest.mark.parametrize("case", [
+        "cross_lengths", "key_padding", "segment_ids", "unaligned"])
+    def test_variants(self, case, plan, flash_bwd, packed_only):
+        flash_bwd(plan)
+        n, g, d, sq, sk = 4, 2, 64, 128, 128
+        kw = {}
+        if case == "cross_lengths":
+            sq, sk = 128, 320
+        elif case == "key_padding":
+            kw["key_padding_mask"] = jnp.asarray(
+                np.arange(sk)[None, :] >= np.array([80, 128])[:, None])
+        elif case == "segment_ids":
+            kw["segment_ids"] = jnp.asarray(
+                np.repeat([[0, 1, 2, -1], [0, 0, 1, 1]], sk // 4, axis=1))
+            kw["causal"] = True
+        else:
+            sq = sk = 100
+            kw["causal"] = True
+        rng = np.random.RandomState(len(case))
+        q = jnp.asarray(rng.randn(2, sq, n, d), jnp.float32) * 0.5
+        k = jnp.asarray(rng.randn(2, sk, g, d), jnp.float32) * 0.5
+        v = jnp.asarray(rng.randn(2, sk, g, d), jnp.float32) * 0.5
+        _assert_same(_fwd_and_grads(flash_attention, q, k, v, **kw),
+                     _fwd_and_grads(mha_reference, q, k, v, **kw))
+
+    @pytest.mark.parametrize("sq,n,g", [
+        (1024, 2, 2),        # the direct forward, bands of two heads
+        (1024 + 40, 2, 2),   # 2 x 2 tiles, whole / banded / skipped, padded
+        (2048, 4, 2),        # the same under GQA
+    ])
+    def test_causal_sub_tiles(self, sq, n, g, packed_only):
+        rng = np.random.RandomState(sq + n)
+        q = jnp.asarray(rng.randn(1, sq, n, 64), jnp.float32) * 0.5
+        k = jnp.asarray(rng.randn(1, sq, g, 64), jnp.float32) * 0.5
+        v = jnp.asarray(rng.randn(1, sq, g, 64), jnp.float32) * 0.5
+        _assert_same(_fwd_and_grads(flash_attention, q, k, v, causal=True),
+                     _fwd_and_grads(mha_reference, q, k, v, causal=True))
+
+    @pytest.mark.parametrize("plan", ["fused", "split"])
+    @pytest.mark.parametrize("n,g", [(4, 4), (8, 2)])
+    def test_dropout_is_the_per_head_routes(self, n, g, plan, flash_bwd,
+                                            monkeypatch):
+        """The keep mask hashes (seed, batch x heads + head, row, col):
+        a seed gives the bits it gave when every head was a grid row."""
+        from apex_tpu.ops import flash_attention as fa
+
+        flash_bwd(plan)
+        rng = np.random.RandomState(n)
+        q = jnp.asarray(rng.randn(2, 128, n, 64), jnp.float32) * 0.5
+        k = jnp.asarray(rng.randn(2, 128, g, 64), jnp.float32) * 0.5
+        v = jnp.asarray(rng.randn(2, 128, g, 64), jnp.float32) * 0.5
+        kw = dict(causal=True, dropout_p=0.3,
+                  dropout_rng=jax.random.PRNGKey(5))
+        packed = _fwd_and_grads(flash_attention, q, k, v, **kw)
+        monkeypatch.setattr(fa, "_heads_a_block", lambda n, g, d: 0)
+        _assert_same(packed, _fwd_and_grads(flash_attention, q, k, v, **kw),
+                     tol=1e-5)
+
+    def test_fallback_shape_matches_reference(self):
+        """Three heads of 64 fill no whole number of blocks: the rule
+        sends them through the same kernels a head a row."""
+        q, k, v = make_qkv(2, 128, 3, 64, seed=7)
+        _assert_same(_fwd_and_grads(flash_attention, q, k, v, causal=True),
+                     _fwd_and_grads(mha_reference, q, k, v, causal=True))

@@ -431,3 +431,67 @@ class TestTopP:
                        rng=jax.random.PRNGKey(7))
         assert out.shape == (1, 8)
         assert bool(jnp.all((out >= 0) & (out < cfg.vocab_size)))
+
+
+class TestQkvSectionsKeepTheParameterLayout:
+    """The training forward gathers the MHA projection's columns into
+    ``[Q | K | V]`` sections before the product (``_mha_qkv``), so that
+    the flash kernels read q, k, v as lane ranges; the stored
+    ``qkv_kernel`` keeps its per-head interleave.  Heads of 64 take the
+    kernels' ``[b, s, heads x d]`` route (two heads a 128-lane block)."""
+
+    VARIANTS = [
+        pytest.param({}, id="mha"),
+        pytest.param({"position_embedding_type": "rope",
+                      "num_query_groups": 2}, id="gqa_rope"),
+    ]
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_prefill_kv_is_the_stepwise_decodes(self, variant):
+        """``prefill`` returns the training forward's K/V (it shares
+        ``_attention``); the decode step splits the stored layout
+        itself: the two caches agree, at the cache-parity test's b, s."""
+        cfg = _cfg(hidden_size=256, **variant)
+        params = init_gpt_params(jax.random.PRNGKey(0), cfg)
+        tokens = jnp.asarray(np.random.RandomState(0).randint(
+            0, cfg.vocab_size, (2, 10)), jnp.int32)
+        cache = init_kv_cache(cfg, 2, 10)
+        for i in range(10):
+            _, cache = decode_step(params, tokens[:, i], cache, cfg)
+        _, pcache = prefill(params, tokens, cfg)
+        for name in "kv":
+            np.testing.assert_allclose(
+                np.asarray(pcache[name]), np.asarray(cache[name]),
+                atol=2e-4, rtol=2e-4, err_msg=name)
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_loss_and_grads_match_the_dense_backend(self, variant):
+        """``gpt_loss`` and its gradients in bfloat16 on one seed, flash
+        kernels against the XLA scores-softmax-values composition: equal
+        to bfloat16 rounding, the ``qkv_kernel`` gradient in the stored
+        layout included."""
+        import dataclasses
+
+        from apex_tpu.models.transformer_lm import gpt_loss
+
+        cfg = _cfg(hidden_size=256, compute_dtype=jnp.bfloat16, **variant)
+        params = init_gpt_params(jax.random.PRNGKey(1), cfg)
+        rng = np.random.RandomState(1)
+        tokens = jnp.asarray(rng.randint(0, cfg.vocab_size, (2, 24)),
+                             jnp.int32)
+        labels = jnp.asarray(rng.randint(0, cfg.vocab_size, (2, 24)),
+                             jnp.int32)
+
+        def run(backend):
+            c = dataclasses.replace(cfg, attention_backend=backend)
+            return jax.value_and_grad(
+                lambda p: gpt_loss(p, tokens, labels, c))(params)
+
+        (l1, g1), (l2, g2) = run("flash"), run("fused_softmax")
+        np.testing.assert_allclose(float(l1), float(l2), rtol=1e-2)
+        for (path, a), b in zip(
+                jax.tree_util.tree_leaves_with_path(g1),
+                jax.tree_util.tree_leaves(g2)):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert np.linalg.norm(a - b) <= 2e-2 * np.linalg.norm(b) + 1e-6, (
+                jax.tree_util.keystr(path))

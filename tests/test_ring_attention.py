@@ -165,10 +165,11 @@ def test_ring_kernel_call_signature_interpret():
     q3 = jnp.asarray(rng.randn(bh, s, d), jnp.float32)
     o, lse = _fwd_pallas(q3, q3, q3, None, None, None, 0.125, True,
                          s, 128, 128, 0.0, True, out_dtype=jnp.float32)
-    assert o.shape == q3.shape
-    delta = jnp.sum(o * o, axis=-1)
+    # the logsumexp comes back, and goes in, across the head's lanes;
+    # the backward takes the forward's output and makes delta itself
+    assert o.shape == q3.shape and lse.shape == q3.shape
     dq, dk, dv = _bwd_pallas(
-        q3, q3, q3, o, lse, delta, None, None, None, 0.125, True,
+        q3, q3, q3, o, lse, o, None, None, None, 0.125, True,
         s, s, 128, 128, 0.0, True, out_dtype=jnp.float32)
     assert dq.shape == q3.shape and dk.shape == q3.shape
 
@@ -181,9 +182,8 @@ def test_ring_kernel_call_signature_interpret():
                              s, 128, 128, 0.0, True,
                              out_dtype=jnp.float32, gqa=(2, 1))
     assert o_g.shape == q3.shape
-    delta_g = jnp.sum(o_g * o_g, axis=-1)
     dq_g, dk_g, dv_g = _bwd_pallas(
-        q3, k3, k3, o_g, lse_g, delta_g, None, None, None, 0.125, True,
+        q3, k3, k3, o_g, lse_g, o_g, None, None, None, 0.125, True,
         s, s, 128, 128, 0.0, True, out_dtype=jnp.float32, gqa=(2, 1))
     assert dq_g.shape == q3.shape
     assert dk_g.shape == k3.shape and dv_g.shape == k3.shape

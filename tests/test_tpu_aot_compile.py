@@ -226,6 +226,95 @@ def test_causal_flash_kernels_compile(b, s, heads, kv_heads, one_chip,
         assert len(_kernels(text, scope)) == 1, scope
 
 
+def _attention_block(kind):
+    """One attention block of a cell as ``_attention`` writes it
+    (projection, flash attention, out projection), its configuration,
+    batch and sequence."""
+    from apex_tpu.models.config import lfm2_moe
+
+    if kind == "lfm2":
+        cfg = lfm2_moe(
+            hidden_size=2048, num_hidden_layers=2,
+            layer_types=["conv", "full_attention"],
+            num_attention_heads=32, num_key_value_heads=8,
+            intermediate_size=11776, moe_intermediate_size=1536,
+            num_dense_layers=1, num_experts=64, num_experts_per_tok=4,
+            vocab_size=8192, experts_held=(0, 8))
+        return cfg, 2, 8192
+    cfg = gpt_125m(num_layers=1, hidden_size=1024, num_attention_heads=16,
+                   max_position_embeddings=1024, scan_layers=False)
+    if kind == "bert":
+        return dataclasses.replace(cfg, attn_mask_type="padding"), 8, 512
+    return cfg, 8, 1024
+
+
+def _layout_ops(text, b, s, elements):
+    """The entry computation's ``copy`` / ``transpose`` instructions of
+    an activation (``b`` leading, ``s`` among the axes) of more than
+    ``elements`` elements whose ``op_name`` lies under ``core_attention``
+    or ``qkv``."""
+    import math
+    import re
+
+    entry = text[text.index("ENTRY"):]
+    found = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if not m or not re.search(
+                r'op_name="[^"]*[/(](core_attention|qkv)[/)]', line):
+            continue
+        dims = [int(x) for x in m.group(2).split(",") if x]
+        if dims[0] == b and s in dims and math.prod(dims) > elements:
+            found.append((m.group(1), line.strip()[:160]))
+    return found
+
+
+@pytest.mark.parametrize("kind", ["gpt", "bert", "lfm2"])
+def test_attention_block_holds_no_activation_copy(kind, one_chip, as_tpu):
+    """The counter of the kernels' layout, read at compile time: q, k, v,
+    o and their gradients cross the flash kernels' boundary as ``[b, s,
+    heads x d]``, the layout the projections on either side produce and
+    consume, so forward and backward of a cell's attention block hold no
+    ``copy`` or ``transpose`` of an activation-sized array (more than
+    b x s x heads x d / 2 elements) under ``qkv`` or ``core_attention``
+    (with ``[b x heads, s, d]`` kernels the GPT block held 9).  LFM2's
+    q/k norm and rope sit between projection and kernel in the sequence-
+    minor layout XLA gives them (``transformer_lm._sequence_minor``):
+    what is left there is the chain's two ends, q in and dq out, and the
+    assembly of the projection's gradient, in bfloat16; o, do and every
+    float32 copy are gone."""
+    from apex_tpu.models.transformer_lm import (
+        _attention, init_gpt_params, rope_cos_sin, single_device_ctx)
+
+    cfg, b, s = _attention_block(kind)
+    layers = jax.eval_shape(lambda k: init_gpt_params(k, cfg),
+                            jax.random.PRNGKey(0))["layers"]
+    lp = layers[-1] if isinstance(layers, (list, tuple)) else {
+        name: jax.ShapeDtypeStruct(x.shape[1:], x.dtype)
+        for name, x in layers.items()}
+    lp = {name: x for name, x in lp.items() if name.split("_")[0] in (
+        "qkv", "proj", "q", "k")}
+
+    def loss(lp, x):
+        rope = (rope_cos_sin(s, cfg.kv_channels, cfg.rope_theta)
+                if cfg.qk_norm else None)
+        return _attention(cfg, lp, x, single_device_ctx(), None, rope,
+                          None).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        _like(lp, one_chip, BF16),
+        _spec((b, s, cfg.hidden_size), BF16, one_chip)).compile().as_text()
+    scopes = (("flash_fwd", "flash_bwd") if kind == "bert" else
+              ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    for scope in scopes:
+        assert len(_kernels(text, scope)) == 1, scope
+    heads_x_d = cfg.num_attention_heads * cfg.kv_channels
+    found = _layout_ops(text, b, s, b * s * heads_x_d // 2)
+    assert len(found) <= (3 if kind == "lfm2" else 0), found
+    assert all(dtype == "bf16" for dtype, _ in found), found
+
+
 def test_grouped_products_are_three_kernels(one_chip, as_tpu):
     """Forward, input gradient and weight gradient of a grouped product
     each compile to a kernel of their own name (no masked XLA product over
