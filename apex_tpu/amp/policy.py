@@ -99,6 +99,13 @@ class Properties:
         return f"amp.Properties({self._asdict()})"
 
 
+# a state-space mixer's per-head constants (models/hybrid.py): the decay
+# ``exp(dt * -exp(A_log))`` multiplies a state over thousands of positions,
+# so ``A_log``, ``dt_bias`` and ``D`` stay float32 wherever the norms'
+# scales do (Mamba-2 keeps them so under mixed precision)
+_FP32_CONSTANTS = ("ssm_a_log", "ssm_dt_bias", "ssm_d")
+
+
 def _is_norm_param(path: tuple) -> bool:
     """Heuristic: does this param path belong to a normalization layer?
 
@@ -106,14 +113,15 @@ def _is_norm_param(path: tuple) -> bool:
     ``nn.modules.batchnorm._BatchNorm`` during the model cast
     (apex/amp/_initialize.py:178-184, fp16_utils ``convert_network``).
     In a pytree we go by path naming, which matches flax's
-    BatchNorm/LayerNorm/GroupNorm module naming conventions.
+    BatchNorm/LayerNorm/GroupNorm module naming conventions.  The leaves
+    named in ``_FP32_CONSTANTS`` are kept with them.
     """
     keywords = ("batchnorm", "batch_norm", "bn", "layernorm", "layer_norm",
                 "groupnorm", "group_norm", "norm")
     for key in path:
         name = getattr(key, "key", getattr(key, "name", str(key)))
         low = str(name).lower()
-        if any(k in low for k in keywords):
+        if any(k in low for k in keywords) or low in _FP32_CONSTANTS:
             return True
     return False
 

@@ -15,9 +15,9 @@ from typing import Optional
 import jax.numpy as jnp
 
 __all__ = ["TransformerConfig", "gpt_tiny", "gpt_125m", "bert_large",
-           "lfm2_moe"]
+           "lfm2_moe", "nemotron_h"]
 
-LAYER_KINDS = ("attention", "conv")
+LAYER_KINDS = ("attention", "conv", "mamba", "moe")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,8 +48,12 @@ class TransformerConfig:
 
     # architecture switches
     attn_mask_type: str = "causal"                # 'causal' | 'padding'
-    activation: str = "gelu"            # 'gelu' | 'gelu_tanh' | 'swiglu'
-    position_embedding_type: str = "learned"      # 'learned' | 'rope'
+    # 'gelu' | 'gelu_tanh' | 'swiglu' | 'relu2' (relu(x)^2, not gated:
+    # the ragged expert path and the shared expert of a hybrid stack)
+    activation: str = "gelu"
+    # 'learned' | 'rope' | 'none' (no positions: a stack whose
+    # state-space layers carry the order)
+    position_embedding_type: str = "learned"
     normalization: str = "layernorm"              # 'layernorm' | 'rmsnorm'
     untie_embeddings_and_output_weights: bool = False
     layernorm_epsilon: float = 1e-5
@@ -80,11 +84,35 @@ class TransformerConfig:
     # without its exchange.  None = all experts are held
     moe_experts_held: Optional[tuple] = None
 
+    # gates of the sigmoid router: chosen / (sum of the chosen +
+    # moe_gate_epsilon) * moe_routed_scaling.  The epsilon is a field
+    # only to pin lowerings: 1e-6 keeps LFM2's step the program the
+    # benchmark accepted, 1e-20 is Nemotron-H's; the two differ by some
+    # 4 float32 ulp of a gate.  One constant, once LFM2 is re-baselined
+    moe_routed_scaling: float = 1.0
+    moe_gate_epsilon: float = 1e-6
+    # width of one ungated expert that every token passes, added to the
+    # routed sum (hybrid stacks); None = no shared expert
+    moe_shared_expert_size: Optional[int] = None
+
     # stacks whose layers differ in kind (models/hybrid.py): one of
     # LAYER_KINDS per layer ('conv' = gated short convolution in place
     # of attention); None = attention everywhere, the homogeneous stack
     layer_types: Optional[tuple] = None
     conv_kernel_size: int = 3
+    # a 'mamba' or 'moe' kind makes the stack one of single mixers (the
+    # mixer_only property).  The Mamba-2 mixer: heads x head size is its
+    # inner width, B and C are shared by heads // ssm_groups heads, a
+    # state is [head size, ssm_state_size], the scan works chunks of
+    # ssm_chunk_size positions (ops/ssd_scan.py); conv_kernel_size is
+    # its taps
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    ssm_groups: int = 1
+    ssm_chunk_size: int = 128
+    # (min, max, floor) of the time step that dt_bias is drawn for
+    mamba_time_step: tuple = (1e-3, 1e-1, 1e-4)
     # with num_experts set, the first num_dense_layers layers keep a
     # dense FFN of width dense_ffn_hidden_size
     num_dense_layers: int = 0
@@ -157,6 +185,24 @@ class TransformerConfig:
                 raise ValueError(
                     f"layer_types must name one of {LAYER_KINDS} for "
                     f"each of the {self.num_layers} layers, got {kinds}")
+            if "mamba" in kinds and (
+                    self.mamba_num_heads < 1
+                    or self.mamba_num_heads % self.ssm_groups):
+                raise ValueError(
+                    f"mamba_num_heads ({self.mamba_num_heads}) must be a "
+                    f"positive multiple of ssm_groups ({self.ssm_groups})")
+        if self.mixer_only and (
+                "conv" in self.layer_types or self.num_dense_layers
+                or ("moe" in self.layer_types) != bool(self.num_experts)):
+            raise ValueError(
+                "'mamba' and 'moe' layers are single mixers: beside them "
+                "only 'attention', num_experts exactly where a 'moe' "
+                "layer is, and no num_dense_layers")
+        if self.moe_shared_expert_size is not None and (
+                self.activation != "relu2" or not self.num_experts):
+            raise ValueError(
+                "a shared expert stands beside routed 'relu2' experts "
+                "(num_experts, activation='relu2')")
         if self.moe_experts_held is not None:
             first, count = (int(v) for v in self.moe_experts_held)
             object.__setattr__(self, "moe_experts_held", (first, count))
@@ -177,11 +223,14 @@ class TransformerConfig:
         if not self.is_hybrid and (
                 self.moe_router != "softmax" or self.qk_norm
                 or self.moe_experts_held is not None
-                or not self.use_bias):
+                or not self.use_bias or self.activation == "relu2"
+                or self.moe_shared_expert_size is not None
+                or self.position_embedding_type == "none"):
             raise ValueError(
-                "moe_router='sigmoid', moe_experts_held, qk_norm and "
-                "use_bias=False belong to the hybrid stack: set "
-                "layer_types")
+                "moe_router='sigmoid', moe_experts_held, qk_norm, "
+                "use_bias=False, activation='relu2', a shared expert and "
+                "position_embedding_type='none' belong to the hybrid "
+                "stack: set layer_types")
         if self.num_query_groups is not None:
             if (self.num_query_groups < 1
                     or self.num_attention_heads % self.num_query_groups):
@@ -196,6 +245,14 @@ class TransformerConfig:
         before expert layers): the stack is built layer by layer
         (models/hybrid.py) instead of one scanned ``[L, ...]`` tree."""
         return self.layer_types is not None or self.num_dense_layers > 0
+
+    @property
+    def mixer_only(self) -> bool:
+        """True when a layer is of kind 'mamba' or 'moe': every layer is
+        then ONE mixer, ``x + mixer(norm(x))``, of its kind (the third is
+        'attention') and no FFN follows an operator.  False: operator,
+        then dense FFN or experts."""
+        return bool({"mamba", "moe"} & set(self.layer_types or ()))
 
     @property
     def held_experts(self) -> tuple:
@@ -300,3 +357,67 @@ def lfm2_moe(*, hidden_size: int, num_hidden_layers: int, layer_types,
         position_embedding_type="rope", rope_theta=float(rope_theta),
         qk_norm=True, use_bias=False, layernorm_epsilon=norm_eps,
         scan_layers=False, **kw)
+
+
+def nemotron_h(*, hidden_size: int, num_hidden_layers: int,
+               hybrid_override_pattern: str, num_attention_heads: int,
+               num_key_value_heads: int, head_dim: int,
+               mamba_num_heads: int, mamba_head_dim: int,
+               ssm_state_size: int, n_groups: int, conv_kernel: int,
+               chunk_size: int, moe_intermediate_size: int,
+               moe_shared_expert_intermediate_size: int,
+               n_routed_experts: int, num_experts_per_tok: int,
+               routed_scaling_factor: float, vocab_size: int,
+               norm_eps: float = 1e-5, time_step_min: float = 1e-3,
+               time_step_max: float = 1e-1, time_step_floor: float = 1e-4,
+               max_position_embeddings: int = 262144, experts_held=None,
+               **kw) -> TransformerConfig:
+    """The Nemotron-H family (``model_type`` ``nemotron_h``) from its
+    published ``config.json`` keys: one mixer a layer by
+    ``hybrid_override_pattern`` (``M`` a Mamba-2 mixer, ``E`` an expert
+    layer, ``*`` grouped-query attention without positions), RMSNorm,
+    bias-free projections, sigmoid-routed ``relu2`` experts (selection
+    bias, gates normalised over the chosen and scaled by
+    ``routed_scaling_factor``) beside one shared expert, an untied head.
+    ``n_routed_experts`` is the router's width; ``experts_held=(first,
+    count)`` makes the expert layers one chip's share of an
+    expert-parallel deployment.  Further keywords go to
+    :class:`TransformerConfig` (``remat``, ``fused_head_ce`` ...)."""
+    kinds = {"M": "mamba", "E": "moe", "*": "attention"}
+    if (len(hybrid_override_pattern) != num_hidden_layers
+            or set(hybrid_override_pattern) - set(kinds)
+            or not set(hybrid_override_pattern) & {"M", "E"}):
+        raise ValueError(
+            f"hybrid_override_pattern {hybrid_override_pattern!r} must "
+            f"name M, E or * for each of the {num_hidden_layers} layers, "
+            "an M or an E among them (they make the layers single "
+            "mixers: cfg.mixer_only)")
+    kw.setdefault("moe_aux_loss_coeff", 0.0)
+    experts = {}
+    if "E" in hybrid_override_pattern:
+        experts = dict(
+            num_experts=n_routed_experts,
+            moe_shared_expert_size=moe_shared_expert_intermediate_size,
+            moe_experts_held=(tuple(experts_held)
+                              if experts_held is not None else None))
+    return TransformerConfig(
+        num_layers=num_hidden_layers, hidden_size=hidden_size,
+        num_attention_heads=num_attention_heads,
+        num_query_groups=num_key_value_heads, kv_channels=head_dim,
+        ffn_hidden_size=moe_intermediate_size,
+        moe_top_k=num_experts_per_tok, moe_routing="ragged",
+        moe_router="sigmoid",
+        moe_routed_scaling=float(routed_scaling_factor),
+        moe_gate_epsilon=1e-20,
+        layer_types=tuple(kinds[c] for c in hybrid_override_pattern),
+        mamba_num_heads=mamba_num_heads,
+        mamba_head_dim=mamba_head_dim, ssm_state_size=ssm_state_size,
+        ssm_groups=n_groups, ssm_chunk_size=chunk_size,
+        conv_kernel_size=conv_kernel,
+        mamba_time_step=(time_step_min, time_step_max, time_step_floor),
+        vocab_size=vocab_size,
+        max_position_embeddings=max_position_embeddings,
+        activation="relu2", normalization="rmsnorm",
+        position_embedding_type="none", use_bias=False,
+        untie_embeddings_and_output_weights=True,
+        layernorm_epsilon=norm_eps, scan_layers=False, **experts, **kw)

@@ -1,6 +1,7 @@
 """Stacks whose layers differ in kind: the LFM2 family's gated short
 convolutions between grouped-query attention layers, dense SwiGLU layers
-before sigmoid-routed expert layers.
+before sigmoid-routed expert layers; and the Nemotron-H family's single
+mixers, a Mamba-2 mixer, an expert layer or attention a layer.
 
 ``TransformerConfig.is_hybrid`` (``layer_types`` or ``num_dense_layers``)
 sends ``init_gpt_params`` and ``transformer_backbone`` here.  The parameters
@@ -22,6 +23,25 @@ One layer, ``u = norm(x)``:
 - then ``x + y``, and the dense FFN (the first ``num_dense_layers``) or the
   expert layer on ``norm(x)``.
 
+A ``cfg.mixer_only`` stack has no FFN after an operator: every layer is
+``x + mixer(norm(x))`` with one norm (``ln1``), the mixer by its kind:
+
+- ``mamba`` (:func:`mamba_mixer`; ``H`` heads of ``P``, ``d_in = H P``,
+  ``G`` groups, state size ``N``): ``[z | x | B | C | dt] = u W_in`` of
+  widths ``d_in | d_in | G N | G N | H``; ``[x | B | C] = silu(conv([x | B |
+  C]) + b_conv)``, depthwise and causal as above; ``dt = softplus(dt +
+  dt_bias)``, ``A = -exp(A_log)`` in float32 (``A_log``, ``dt_bias`` and
+  ``D`` stay float32 in a mixed-precision model's copy of the weights
+  too: ``amp/policy.py`` keeps them with the norms' scales); the
+  state-space scan
+  (``ops/ssd_scan.py``: per head ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x)
+  B_t``, ``y_t = S_t C_t + D x_t``); ``y = RMSNorm_groups(y * silu(z))``
+  over ``G`` groups of ``d_in / G`` channels; ``y W_out``;
+- ``moe``: the expert layer itself (:func:`expert_layer`: sigmoid router,
+  the held experts' ``relu(u W_1)^2 W_2``, the shared expert added once);
+- ``attention``: the homogeneous stack's, without positions where
+  ``cfg.position_embedding_type`` is ``'none'``.
+
 One device (or data parallel around it): no tp/pp partitioning, no dropout,
 no attention mask.
 """
@@ -37,7 +57,8 @@ from apex_tpu.models.config import TransformerConfig
 from apex_tpu.ops.flash_attention import REMAT_SAVED_NAMES
 
 __all__ = ["init_hybrid_params", "hybrid_backbone", "short_conv",
-           "qk_norm_rope", "moe_counters", "MOE_COUNTERS"]
+           "mamba_mixer", "expert_layer", "qk_norm_rope", "moe_counters",
+           "MOE_COUNTERS"]
 
 # the expert layers' counters, summed over the layers: assignments on held
 # experts and in all, the largest and the mean count over held experts
@@ -45,18 +66,28 @@ MOE_COUNTERS = ("moe_assignments_held", "moe_assignments",
                 "moe_held_load_max", "moe_held_load_mean")
 
 
+def _kind(cfg: TransformerConfig, layer: int) -> str:
+    return cfg.layer_types[layer] if cfg.layer_types else "attention"
+
+
 def _has_experts(cfg: TransformerConfig, layer: int) -> bool:
+    if cfg.mixer_only:
+        return _kind(cfg, layer) == "moe"
     return bool(cfg.num_experts) and layer >= cfg.num_dense_layers
 
 
-def _kind(cfg: TransformerConfig, layer: int) -> str:
-    return cfg.layer_types[layer] if cfg.layer_types else "attention"
+def _mamba_widths(cfg: TransformerConfig) -> tuple:
+    """``(d_in, G N)``: the mixer's inner width and the width of B (and
+    of C)."""
+    return (cfg.mamba_num_heads * cfg.mamba_head_dim,
+            cfg.ssm_groups * cfg.ssm_state_size)
 
 
 def init_hybrid_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     """The parameter tree of a hybrid stack: ``layers`` is a list, one tree
     a layer.  N(0, std) kernels, output projections narrower by sqrt(2L),
-    norm weights 1, the router's selection bias 0."""
+    norm weights 1, the router's selection bias and the convolution's
+    bias 0, a Mamba-2 mixer's time constants as ``mamba_leaves`` says."""
     h, L = cfg.hidden_size, cfg.num_layers
     p, kvp = cfg.projection_size, cfg.kv_projection_size
     std = cfg.init_method_std
@@ -71,39 +102,77 @@ def init_hybrid_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     def zeros(*shape):
         return jnp.zeros(shape, dt)
 
+    def mamba_leaves(ks):
+        """``A_log = log U(1, 16)``; ``dt_bias`` the inverse softplus of a
+        log-uniform time step; ``D = 1`` (Mamba-2's own draw)."""
+        d_in, gn = _mamba_widths(cfg)
+        heads = cfg.mamba_num_heads
+        lo, hi, floor = cfg.mamba_time_step
+        step = jnp.maximum(floor, jnp.exp(jax.random.uniform(
+            ks[3], (heads,), jnp.float32, jnp.log(lo), jnp.log(hi))))
+        return dict(
+            ssm_in_kernel=nrm(ks[0], (h, 2 * d_in + 2 * gn + heads), std),
+            conv_kernel=nrm(ks[1], (d_in + 2 * gn, cfg.conv_kernel_size),
+                            cfg.conv_kernel_size ** -0.5),
+            conv_bias=zeros(d_in + 2 * gn),
+            ssm_dt_bias=(step + jnp.log(-jnp.expm1(-step))).astype(dt),
+            ssm_a_log=jnp.log(jax.random.uniform(
+                ks[4], (heads,), jnp.float32, 1.0, 16.0)).astype(dt),
+            ssm_d=jnp.ones((heads,), dt),
+            ssm_norm_scale=jnp.ones((d_in,), dt),
+            ssm_out_kernel=nrm(ks[2], (d_in, h), out_std))
+
+    def attention_leaves(ks):
+        lp = dict(qkv_kernel=nrm(ks[0], (h, p + 2 * kvp), std),
+                  proj_kernel=nrm(ks[2], (p, h), out_std))
+        if cfg.qk_norm:
+            lp.update(q_norm_scale=jnp.ones((cfg.kv_channels,), dt),
+                      k_norm_scale=jnp.ones((cfg.kv_channels,), dt))
+        if cfg.use_bias:
+            lp.update(qkv_bias=zeros(p + 2 * kvp), proj_bias=zeros(h))
+        return lp
+
+    def expert_leaves(ks):
+        G, E, f = (cfg.held_experts[1], cfg.num_experts,
+                   cfg.ffn_hidden_size)
+        f1 = 2 * f if swiglu else f
+        lp = dict(router_kernel=nrm(ks[3], (h, E), std),
+                  moe_fc1=nrm(ks[4], (G, h, f1), std),
+                  moe_fc2=nrm(ks[5], (G, f, h), out_std))
+        if cfg.moe_router == "sigmoid":
+            lp["router_bias"] = zeros(E)
+        if cfg.use_bias:
+            lp.update(moe_fc1_bias=zeros(G, f1), moe_fc2_bias=zeros(G, h))
+        if cfg.moe_shared_expert_size:
+            fs = cfg.moe_shared_expert_size
+            lp.update(shared_fc1_kernel=nrm(ks[0], (h, fs), std),
+                      shared_fc2_kernel=nrm(ks[2], (fs, h), out_std))
+        return lp
+
     layers = []
     for i, key in enumerate(jax.random.split(rng, L + 1)[1:]):
         ks = jax.random.split(key, 6)
-        lp = {"ln1_scale": jnp.ones((h,), dt),
-              "ln2_scale": jnp.ones((h,), dt)}
+        kind = _kind(cfg, i)
+        lp = {"ln1_scale": jnp.ones((h,), dt)}
         if layernorm:
-            lp.update(ln1_bias=zeros(h), ln2_bias=zeros(h))
-        if _kind(cfg, i) == "conv":
+            lp["ln1_bias"] = zeros(h)
+        if not cfg.mixer_only:
+            lp["ln2_scale"] = jnp.ones((h,), dt)
+            if layernorm:
+                lp["ln2_bias"] = zeros(h)
+        if kind == "conv":
             lp.update(
                 conv_in_kernel=nrm(ks[0], (h, 3 * h), std),
                 conv_kernel=nrm(ks[1], (h, cfg.conv_kernel_size),
                                 cfg.conv_kernel_size ** -0.5),
                 conv_out_kernel=nrm(ks[2], (h, h), out_std))
-        else:
-            lp.update(qkv_kernel=nrm(ks[0], (h, p + 2 * kvp), std),
-                      proj_kernel=nrm(ks[2], (p, h), out_std))
-            if cfg.qk_norm:
-                lp.update(q_norm_scale=jnp.ones((cfg.kv_channels,), dt),
-                          k_norm_scale=jnp.ones((cfg.kv_channels,), dt))
-            if cfg.use_bias:
-                lp.update(qkv_bias=zeros(p + 2 * kvp), proj_bias=zeros(h))
+        elif kind == "mamba":
+            lp.update(mamba_leaves(ks))
+        elif kind == "attention":
+            lp.update(attention_leaves(ks))
         if _has_experts(cfg, i):
-            G, E, f = (cfg.held_experts[1], cfg.num_experts,
-                       cfg.ffn_hidden_size)
-            f1 = 2 * f if swiglu else f
-            lp.update(router_kernel=nrm(ks[3], (h, E), std),
-                      moe_fc1=nrm(ks[4], (G, h, f1), std),
-                      moe_fc2=nrm(ks[5], (G, f, h), out_std))
-            if cfg.moe_router == "sigmoid":
-                lp["router_bias"] = zeros(E)
-            if cfg.use_bias:
-                lp.update(moe_fc1_bias=zeros(G, f1), moe_fc2_bias=zeros(G, h))
-        else:
+            lp.update(expert_leaves(ks))
+        elif not cfg.mixer_only:
             f = (cfg.dense_ffn_hidden_size if cfg.num_experts
                  else cfg.ffn_hidden_size)
             lp.update(
@@ -131,6 +200,18 @@ def init_hybrid_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     return params
 
 
+def causal_taps(v, w, bias=None):
+    """The depthwise causal convolution of ``v`` [b, s, c] (float32) with
+    ``w`` [c, K], the last tap on the current position and zeros before
+    the sequence: ``sum_j w[:, j] * v[t - (K-1) + j]``, plus ``bias``
+    [c]."""
+    taps, s = w.shape[1], v.shape[1]
+    v = jnp.pad(v, ((0, 0), (taps - 1, 0), (0, 0)))
+    w = w.astype(jnp.float32)
+    conv = sum(w[:, j] * v[:, j:j + s] for j in range(taps))
+    return conv if bias is None else conv + bias.astype(jnp.float32)
+
+
 def short_conv(cfg: TransformerConfig, lp: dict, x):
     """The gated short convolution on ``x`` [b, s, h]: in-projection to
     ``[B | C | z]``, ``B * z`` through a depthwise causal convolution of
@@ -138,17 +219,51 @@ def short_conv(cfg: TransformerConfig, lp: dict, x):
     the current position), gated by ``C``, out-projection.  The products
     run in ``x``'s dtype, the gates and the taps in float32."""
     dt = x.dtype
-    taps, s = cfg.conv_kernel_size, x.shape[1]
     with jax.named_scope("conv_in"):
         bcz = x @ lp["conv_in_kernel"].astype(dt)
     with jax.named_scope("conv_gate"):
         b_, c_, z = (t.astype(jnp.float32) for t in jnp.split(bcz, 3, -1))
-        v = jnp.pad(b_ * z, ((0, 0), (taps - 1, 0), (0, 0)))
-        w = lp["conv_kernel"].astype(jnp.float32)
-        conv = sum(w[:, j] * v[:, j:j + s] for j in range(taps))
-        y = (c_ * conv).astype(dt)
+        y = (c_ * causal_taps(b_ * z, lp["conv_kernel"])).astype(dt)
     with jax.named_scope("conv_out"):
         return y @ lp["conv_out_kernel"].astype(dt)
+
+
+def mamba_mixer(cfg: TransformerConfig, lp: dict, u):
+    """The Mamba-2 mixer on ``u`` [b, s, h] (the module docstring has the
+    equations).  The two projections and the scan's products run in
+    ``u``'s dtype; the convolution, ``silu``, ``softplus``, the decays and
+    the grouped norm in float32."""
+    from apex_tpu.ops.ssd_scan import ssd_scan
+
+    dt = u.dtype
+    f32 = jnp.float32
+    b, s, _ = u.shape
+    heads, groups = cfg.mamba_num_heads, cfg.ssm_groups
+    d_in, gn = _mamba_widths(cfg)
+    with jax.named_scope("ssm_in"):
+        zxbcdt = u @ lp["ssm_in_kernel"].astype(dt)
+        z, xbc, step = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * gn], -1)
+    with jax.named_scope("ssm_conv"):
+        xbc = jax.nn.silu(causal_taps(
+            xbc.astype(f32), lp["conv_kernel"], lp["conv_bias"])).astype(dt)
+        x, b_, c_ = jnp.split(xbc, [d_in, d_in + gn], -1)
+    with jax.named_scope("ssd_scan"):
+        step = jax.nn.softplus(step.astype(f32)
+                               + lp["ssm_dt_bias"].astype(f32))
+        y = ssd_scan(
+            x.reshape(b, s, heads, -1), step,
+            -jnp.exp(lp["ssm_a_log"].astype(f32)),
+            b_.reshape(b, s, groups, -1), c_.reshape(b, s, groups, -1),
+            lp["ssm_d"], chunk=cfg.ssm_chunk_size)
+    with jax.named_scope("ssm_gate_norm"):
+        y = y.reshape(b, s, d_in).astype(f32) * jax.nn.silu(z.astype(f32))
+        y = y.reshape(b, s, groups, -1)
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                              + cfg.layernorm_epsilon)
+        y = (y.reshape(b, s, d_in)
+             * lp["ssm_norm_scale"].astype(f32)).astype(dt)
+    with jax.named_scope("ssm_out"):
+        return y @ lp["ssm_out_kernel"].astype(dt)
 
 
 def _head_rms(t, weight, eps):
@@ -183,9 +298,50 @@ def moe_counters(cfg: TransformerConfig, load) -> dict:
         jnp.mean(held))))
 
 
+def expert_layer(cfg: TransformerConfig, lp: dict, m):
+    """The expert layer on ``m`` [b, s, h]: the routed sum of the held
+    experts (``transformer_lm._moe_mlp``) and, where the layer has one
+    (``cfg.moe_shared_expert_size``), the shared expert ``relu(m W_1)^2
+    W_2``, which every token passes and no gate weighs, added once.
+    Returns ``(out, load)``, the router's per-expert assignment counts
+    [E] beside the output."""
+    from apex_tpu.models.transformer_lm import _moe_mlp
+
+    out, _, load = _moe_mlp(cfg, lp, m, with_load=True)
+    if "shared_fc1_kernel" in lp:
+        with jax.named_scope("shared_expert"):
+            y = (m @ lp["shared_fc1_kernel"].astype(m.dtype)).astype(
+                jnp.float32)
+            y = jnp.square(jax.nn.relu(y)).astype(m.dtype)
+            out = out + y @ lp["shared_fc2_kernel"].astype(m.dtype)
+    return out, load
+
+
+def _mixer_layer(cfg: TransformerConfig, layer: int, ctx, lp: dict, x,
+                 rope):
+    """One layer of a ``cfg.mixer_only`` stack: ``x + mixer(norm(x))``."""
+    from apex_tpu.models.transformer_lm import _attention, apply_norm
+
+    with jax.named_scope("ln1"):
+        u = apply_norm(cfg, x, lp["ln1_scale"], lp.get("ln1_bias"))
+    kind, counters = _kind(cfg, layer), None
+    if kind == "mamba":
+        with jax.named_scope("mamba_mixer"):
+            y = mamba_mixer(cfg, lp, u)
+    elif kind == "moe":
+        with jax.named_scope("mlp"):
+            y, load = expert_layer(cfg, lp, u)
+        counters = moe_counters(cfg, load)
+    else:
+        with jax.named_scope("attention"):
+            y = _attention(cfg, lp, u, ctx, None, rope, None)
+    with jax.named_scope("residual"):
+        x = x + y
+    return ctx.constrain_hidden(x), counters
+
+
 def _layer(cfg: TransformerConfig, layer: int, ctx, lp: dict, x, rope):
-    from apex_tpu.models.transformer_lm import (
-        _attention, _mlp, _moe_mlp, apply_norm)
+    from apex_tpu.models.transformer_lm import _attention, _mlp, apply_norm
 
     with jax.named_scope("ln1"):
         u = apply_norm(cfg, x, lp["ln1_scale"], lp.get("ln1_bias"))
@@ -202,7 +358,7 @@ def _layer(cfg: TransformerConfig, layer: int, ctx, lp: dict, x, rope):
     counters = None
     if _has_experts(cfg, layer):
         with jax.named_scope("mlp"):
-            f, _, load = _moe_mlp(cfg, lp, m, with_load=True)
+            f, load = expert_layer(cfg, lp, m)
         counters = moe_counters(cfg, load)
     else:
         with jax.named_scope("dense_ffn"):
@@ -228,7 +384,8 @@ def hybrid_backbone(params: dict, hidden, cfg: TransformerConfig, ctx,
     total = {}
     with jax.named_scope("backbone"):
         for i, lp in enumerate(params["layers"]):
-            fn = functools.partial(_layer, cfg, i, ctx)
+            fn = functools.partial(
+                _mixer_layer if cfg.mixer_only else _layer, cfg, i, ctx)
             if cfg.remat:
                 fn = jax.checkpoint(fn, policy=policy)
             hidden, counters = fn(lp, hidden, rope)

@@ -804,7 +804,9 @@ def _moe_mlp(cfg: TransformerConfig, lp: dict, x, with_load: bool = False):
         routing=cfg.moe_routing,
         moe_comm=cfg.moe_comm,
         router=cfg.moe_router,
-        experts_held=cfg.moe_experts_held)
+        experts_held=cfg.moe_experts_held,
+        routed_scaling=cfg.moe_routed_scaling,
+        gate_epsilon=cfg.moe_gate_epsilon)
     return (o.out, o.aux_loss, o.expert_load) if with_load else (
         o.out, o.aux_loss)
 

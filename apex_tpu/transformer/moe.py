@@ -168,11 +168,11 @@ def _topk_routing(scores, top_k):
     return jnp.stack(choices, axis=-1), jnp.stack(picked, axis=-1)
 
 
-def _sigmoid_routing(router, bias, x2, top_k, scaling=1.0):
+def _sigmoid_routing(router, bias, x2, top_k, scaling=1.0, epsilon=1e-6):
     """The sigmoid router: scores ``r = sigmoid(x W_g)`` in float32 (the
     product too: ``HIGHEST``, no bf16 passes on a TPU), the ``top_k``
     largest of ``r + bias`` chosen (the bias selects, it never weighs),
-    gates ``r_e / (sum of the chosen r + 1e-6) * scaling``.  Returns
+    gates ``r_e / (sum of the chosen r + epsilon) * scaling``.  Returns
     ``(choice [T, k], gates [T, k], r [T, E])``."""
     r = jax.nn.sigmoid(jnp.dot(
         x2.astype(jnp.float32), router.astype(jnp.float32),
@@ -181,7 +181,7 @@ def _sigmoid_routing(router, bias, x2, top_k, scaling=1.0):
     choice, _ = _topk_routing(jax.lax.stop_gradient(select), top_k)
     hot = jax.nn.one_hot(choice, r.shape[-1], dtype=jnp.bool_)  # [T, k, E]
     picked = jnp.sum(jnp.where(hot, r[:, None, :], 0.0), axis=-1)
-    gates = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6)
+    gates = picked / (jnp.sum(picked, axis=-1, keepdims=True) + epsilon)
     return choice, gates * scaling, r
 
 
@@ -292,9 +292,10 @@ def _grouped_ffn(xs, offsets, fc1, b1, fc2, b2, activation, dtype,
     else:
         if b1e is not None:
             h1 = h1 + b1e.astype(dtype)
-        h1 = jax.nn.gelu(h1.astype(jnp.float32),
-                         approximate=activation == "gelu_tanh"
-                         ).astype(dtype)
+        h1 = h1.astype(jnp.float32)
+        h1 = (jnp.square(jax.nn.relu(h1)) if activation == "relu2" else
+              jax.nn.gelu(h1, approximate=activation == "gelu_tanh")
+              ).astype(dtype)
     h2 = _expert_matmul(h1, fc2, offsets, dtype, backend)
     return h2 if b2e is None else h2 + b2e.astype(dtype)
 
@@ -790,6 +791,8 @@ def switch_moe_mlp(
     gmm_backend: Optional[str] = None,
     router: str = "softmax",
     experts_held: Optional[tuple] = None,
+    routed_scaling: float = 1.0,
+    gate_epsilon: float = 1e-6,
 ) -> MoEOutput:
     """Token-choice top-k MoE FFN over ``x`` [b, s, h].
 
@@ -819,13 +822,15 @@ def switch_moe_mlp(
     ``activation='swiglu'`` expects ``fc1``/``fc1_bias`` with a doubled
     trailing dim ``2f`` ([gate ‖ up] concatenated) and applies the fused
     bias-SwiGLU epilogue (ops/swiglu.py) inside each expert.  The bias
-    leaves may be absent (bias-free experts).
+    leaves may be absent (bias-free experts).  ``activation='relu2'``
+    (ragged routing only) is ``relu(x)^2`` on an ``f``-wide ``fc1``.
 
     ``router="sigmoid"`` (ragged routing only): float32 sigmoid scores,
     the ``top_k`` largest of ``score + params["router_bias"]`` chosen
     (the bias selects and never weighs; it gets no gradient), gates
-    normalised over the chosen (:func:`_sigmoid_routing`); no auxiliary
-    loss is defined for it (``aux_loss`` is 0).
+    normalised over the chosen, ``r / (sum + gate_epsilon) *
+    routed_scaling`` (:func:`_sigmoid_routing`); no auxiliary loss is
+    defined for it (``aux_loss`` is 0).
 
     ``experts_held=(first, count)`` (ragged routing only): ``fc1``/
     ``fc2`` hold experts ``first .. first + count`` of the router's
@@ -857,9 +862,11 @@ def switch_moe_mlp(
                 "quantized expert slabs are a single-device serving "
                 "path; run them outside an expert-parallel mesh")
     if routing != "ragged" and (router != "softmax"
-                                or experts_held is not None):
+                                or experts_held is not None
+                                or activation == "relu2"):
         raise ValueError(
-            "router='sigmoid' and experts_held need routing='ragged'")
+            "router='sigmoid', experts_held and activation='relu2' need "
+            "routing='ragged'")
     if router not in MOE_ROUTERS:
         raise ValueError(
             f"router={router!r}: expected one of {MOE_ROUTERS}")
@@ -891,7 +898,7 @@ def switch_moe_mlp(
             if router == "sigmoid":
                 choice, gates, _ = _sigmoid_routing(
                     params["router"], params.get("router_bias"), x2,
-                    top_k)
+                    top_k, routed_scaling, gate_epsilon)
                 probs = None
             else:
                 probs = _router_probs(params["router"], x2,

@@ -55,6 +55,8 @@ MODULES = [
      "ops.collective_matmul — overlapped ring TP collectives"),
     ("apex_tpu.ops.grouped_matmul", "ops",
      "ops.grouped_matmul — ragged expert segment matmul"),
+    ("apex_tpu.ops.ssd_scan", "ops",
+     "ops.ssd_scan — chunked state-space scan (Mamba-2)"),
     ("apex_tpu.ops.paged_attention", "ops",
      "ops.paged_attention — ragged paged-attention decode kernel"),
     ("apex_tpu.ops.fused_sampling", "ops",

@@ -16,7 +16,8 @@ import numpy as np
 import pytest
 
 from apex_tpu.models.bert import make_bert_train_step
-from apex_tpu.models.config import bert_large, gpt_125m, lfm2_moe
+from apex_tpu.models.config import (
+    bert_large, gpt_125m, lfm2_moe, nemotron_h)
 from apex_tpu.models.gpt import make_gpt_train_step
 from apex_tpu.optimizers import fused_adam, fused_lamb
 
@@ -44,12 +45,16 @@ NOT_RECOMPUTED = {"flash_fwd", "core_attention"}
 HYBRID = {"short_conv", "conv_in", "conv_gate", "conv_out", "qk_norm",
           "rope", "router", "moe_dispatch", "expert_ffn", "moe_combine",
           "dense_ffn"}
+# a stack of single mixers (Nemotron-H): no ``ln2``, no dense FFN
+MIXERS = {"mamba_mixer", "ssm_in", "ssm_conv", "ssd_scan", "ssm_gate_norm",
+          "ssm_out", "shared_expert", "router", "moe_dispatch",
+          "expert_ffn", "moe_combine"}
 HYBRID_KERNELS_FWD = {"gmm_fwd"}
 HYBRID_KERNELS_BWD = {"gmm_dx", "gmm_dw"}
 ALL = (BOTH_WAYS | STEP | KERNELS_FWD | KERNELS_BWD
        | {"residual", "lm_head_ce", "embedding_ln", "mlm_head", "nsp_head",
           "trust_ratio", "flash_bwd_dq", "flash_bwd_dkv", "grad_reduce"}
-       | HYBRID | HYBRID_KERNELS_FWD | HYBRID_KERNELS_BWD)
+       | HYBRID | MIXERS | HYBRID_KERNELS_FWD | HYBRID_KERNELS_BWD)
 
 
 def _gpt():
@@ -87,6 +92,23 @@ def _lfm2():
     return init, step, (ids, ids), {"lm_head_ce"}, set(), True, HYBRID
 
 
+def _nemotron():
+    """The Nemotron-H pattern at toy widths: expert layers (4 of 16 held,
+    beside a shared expert), Mamba-2 mixers and one attention layer, one
+    mixer a layer, each layer its own checkpoint."""
+    cfg = nemotron_h(
+        hidden_size=128, num_hidden_layers=4, hybrid_override_pattern="EM*M",
+        num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+        mamba_num_heads=8, mamba_head_dim=16, ssm_state_size=16, n_groups=2,
+        conv_kernel=4, chunk_size=16, moe_intermediate_size=128,
+        moe_shared_expert_intermediate_size=128, n_routed_experts=16,
+        num_experts_per_tok=6, routed_scaling_factor=2.5, vocab_size=512,
+        experts_held=(4, 4), fused_head_ce=True, remat=True)
+    init, step = make_gpt_train_step(cfg, fused_adam(lr=1e-4), "O2")
+    ids = np.zeros((B, S), np.int32)
+    return init, step, (ids, ids), {"lm_head_ce"}, set(), True, MIXERS
+
+
 def _words(op_name: str) -> set:
     """The scope words of an ``op_name``: its parts less the transforms
     around them (``transpose(jvp(model))`` -> ``model``)."""
@@ -94,9 +116,9 @@ def _words(op_name: str) -> set:
             for part in op_name.split("/")}
 
 
-@pytest.mark.parametrize("build", [_gpt, _bert, _lfm2],
+@pytest.mark.parametrize("build", [_gpt, _bert, _lfm2, _nemotron],
                          ids=["gpt_scan_remat", "bert_unrolled",
-                              "lfm2_hybrid_remat"])
+                              "lfm2_hybrid_remat", "nemotron_mixers_remat"])
 def test_every_part_of_the_step_is_scoped(build, monkeypatch):
     monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
     init, step, batch, heads, optimizer_words, remat, hybrid = build()
@@ -124,20 +146,27 @@ def test_every_part_of_the_step_is_scoped(build, monkeypatch):
         if m.group(2) in ("dot", "convolution", "custom-call") and not words:
             unscoped_work.append(line.strip()[:200])
 
-    # (a) forward, backward and, with remat, recomputed forward
-    assert BOTH_WAYS | heads | KERNELS_FWD | {"residual"} <= seen["forward"]
-    assert BOTH_WAYS | heads | KERNELS_BWD <= seen["backward"]
+    # (a) forward, backward and, with remat, recomputed forward; a stack
+    # of single mixers has one norm a layer and no dense FFN
+    absent = {"ln2", "fc1", "fc2"} if hybrid is MIXERS else set()
+    assert (BOTH_WAYS | heads | KERNELS_FWD | {"residual"}) - absent <= seen[
+        "forward"]
+    assert (BOTH_WAYS | heads | KERNELS_BWD) - absent <= seen["backward"]
     if remat:
-        assert RECOMPUTED <= seen["recompute"]
+        assert RECOMPUTED - absent <= seen["recompute"]
         assert not NOT_RECOMPUTED & seen["recompute"]
     else:
         assert not seen["recompute"]
+    assert not absent & set().union(*seen.values())
     if hybrid:
         assert hybrid | HYBRID_KERNELS_FWD <= seen["forward"]
         assert hybrid | HYBRID_KERNELS_BWD <= seen["backward"]
-        # the expert layer is recomputed with the rest of its layer
-        assert {"router", "expert_ffn", "gmm_fwd",
-                "conv_in"} <= seen["recompute"]
+        # the expert layer is recomputed with the rest of its layer, and
+        # so are the mixer and its scan
+        own = ({"ssm_in", "ssd_scan", "shared_expert"} if hybrid is MIXERS
+               else {"conv_in"})
+        assert {"router", "expert_ffn", "gmm_fwd"} | own <= seen[
+            "recompute"]
     # (b) no matmul or kernel without a word of the program's
     assert not unscoped_work
     # (c) the step's own phases, outside the differentiated function
