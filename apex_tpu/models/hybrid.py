@@ -233,12 +233,12 @@ def mamba_mixer(cfg: TransformerConfig, lp: dict, u):
     equations).  The two projections and the scan's products run in
     ``u``'s dtype; the convolution, ``silu``, ``softplus``, the decays and
     the grouped norm in float32."""
-    from apex_tpu.ops.ssd_scan import ssd_scan
+    from apex_tpu.ops.ssd_scan import ssd_scan_packed
 
     dt = u.dtype
     f32 = jnp.float32
     b, s, _ = u.shape
-    heads, groups = cfg.mamba_num_heads, cfg.ssm_groups
+    groups = cfg.ssm_groups
     d_in, gn = _mamba_widths(cfg)
     with jax.named_scope("ssm_in"):
         zxbcdt = u @ lp["ssm_in_kernel"].astype(dt)
@@ -246,17 +246,16 @@ def mamba_mixer(cfg: TransformerConfig, lp: dict, u):
     with jax.named_scope("ssm_conv"):
         xbc = jax.nn.silu(causal_taps(
             xbc.astype(f32), lp["conv_kernel"], lp["conv_bias"])).astype(dt)
-        x, b_, c_ = jnp.split(xbc, [d_in, d_in + gn], -1)
     with jax.named_scope("ssd_scan"):
+        # [x | B | C] stay one array: the scan's kernels take each part
+        # by column block
         step = jax.nn.softplus(step.astype(f32)
                                + lp["ssm_dt_bias"].astype(f32))
-        y = ssd_scan(
-            x.reshape(b, s, heads, -1), step,
-            -jnp.exp(lp["ssm_a_log"].astype(f32)),
-            b_.reshape(b, s, groups, -1), c_.reshape(b, s, groups, -1),
-            lp["ssm_d"], chunk=cfg.ssm_chunk_size)
+        y = ssd_scan_packed(
+            xbc, step, -jnp.exp(lp["ssm_a_log"].astype(f32)), lp["ssm_d"],
+            groups=groups, state=gn // groups, chunk=cfg.ssm_chunk_size)
     with jax.named_scope("ssm_gate_norm"):
-        y = y.reshape(b, s, d_in).astype(f32) * jax.nn.silu(z.astype(f32))
+        y = y.astype(f32) * jax.nn.silu(z.astype(f32))
         y = y.reshape(b, s, groups, -1)
         y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
                               + cfg.layernorm_epsilon)

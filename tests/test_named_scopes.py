@@ -173,3 +173,41 @@ def test_every_part_of_the_step_is_scoped(build, monkeypatch):
     assert STEP - {"cast_params"} | optimizer_words <= seen["update"]
     assert "cast_params" in seen["update"]
     assert not (STEP - {"cast_params"}) & (seen["forward"] | seen["backward"])
+
+
+def test_the_scan_kernels_are_named_under_ssd_scan(monkeypatch):
+    """At a shape the state-space scan's kernels take (2 heads of 64 in one
+    group, a state of 128, chunk 128) the Mamba-2 mixer's scope
+    ``ssd_scan`` holds the kernels ``ssd_fwd`` (forward, and again where
+    the layer's checkpoint recomputes it) and ``ssd_bwd`` (backward): the
+    innermost word of the benchmark's ``scope_words`` in their
+    ``op_name`` is ``ssd_scan``, which ``ssd_scan_ms`` and
+    ``ssd_scan_roofline`` read."""
+    from apex_tpu.models import hybrid
+
+    monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
+    cfg = nemotron_h(
+        hidden_size=128, num_hidden_layers=1, hybrid_override_pattern="M",
+        num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+        mamba_num_heads=2, mamba_head_dim=64, ssm_state_size=128, n_groups=1,
+        conv_kernel=4, chunk_size=128, moe_intermediate_size=128,
+        moe_shared_expert_intermediate_size=128, n_routed_experts=16,
+        num_experts_per_tok=6, routed_scaling_factor=2.5, vocab_size=512)
+    lp = hybrid.init_hybrid_params(jax.random.key(0), cfg)["layers"][0]
+    u = np.zeros((1, 128, 128), np.float32)
+
+    def loss(lp, u):
+        with jax.named_scope("mamba_mixer"):
+            return hybrid.mamba_mixer(cfg, lp, u).sum()
+
+    text = jax.jit(jax.grad(jax.checkpoint(loss))).lower(
+        lp, u).compile().as_text()
+    # (interpret mode hoists a constant or two out of a kernel's loop
+    # under the kernel's bare name)
+    names = re.findall(r'op_name="(jit[^"]*ssd_[fb]wd[^"]*)"', text)
+    assert names and all("/mamba_mixer/ssd_scan/" in n for n in names)
+    fwd = [n for n in names if "/ssd_fwd/" in n]
+    bwd = [n for n in names if "/ssd_bwd/" in n]
+    assert fwd and all("rematted_computation" in n for n in fwd)
+    assert bwd and all("transpose(" in n
+                       and "rematted_computation" not in n for n in bwd)

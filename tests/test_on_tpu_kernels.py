@@ -343,3 +343,42 @@ def test_ring_attention_on_chip():
     want = mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=5e-3, rtol=5e-3)
+
+
+def test_ssd_scan_kernels_on_chip():
+    """The state-space scan's kernels as Mosaic compiles them, bfloat16,
+    two groups of eight heads over four chunks: ``y`` and the six
+    gradients against the einsum form, and each no further than it from
+    the same scan in float32 at full precision."""
+    from apex_tpu.ops.ssd_scan import ssd_scan
+
+    ks = jax.random.split(jax.random.key(0), 7)
+    bt, s, heads, p, g, n = 1, 512, 16, 64, 2, 128
+    args = (jax.random.normal(ks[0], (bt, s, heads, p), jnp.bfloat16),
+            jax.nn.softplus(jax.random.normal(ks[1], (bt, s, heads)) - 2.0),
+            -jnp.exp(jax.random.uniform(ks[2], (heads,), maxval=2.7)),
+            jax.random.normal(ks[3], (bt, s, g, n), jnp.bfloat16),
+            jax.random.normal(ks[4], (bt, s, g, n), jnp.bfloat16),
+            jax.random.normal(ks[5], (heads,)))
+    w = jax.random.normal(ks[6], (bt, s, heads, p))
+
+    def y_and_grads(backend, args):
+        def loss(*a):
+            y = ssd_scan(*a, backend=backend).astype(jnp.float32)
+            return jnp.vdot(y, w), y
+        grads, y = jax.jit(jax.grad(loss, argnums=range(6),
+                                    has_aux=True))(*args)
+        return (y,) + grads
+
+    def rel(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    got, form = y_and_grads("kernel", args), y_and_grads("reference", args)
+    with jax.default_matmul_precision("highest"):
+        exact = y_and_grads("reference", tuple(
+            t.astype(jnp.float32) for t in args))
+    for name, k, e, x in zip(("y", "x", "dt", "A", "B", "C", "D"),
+                             got, form, exact):
+        assert rel(k, e) < 1e-2, name
+        assert rel(k, x) < 1.5 * rel(e, x) + 1e-4, name

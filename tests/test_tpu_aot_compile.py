@@ -401,9 +401,72 @@ def test_nemotron_h_step_compiles_at_published_widths(
     for scope in ("gmm_fwd", "gmm_dx", "gmm_dw", "flash_fwd",
                   "flash_bwd_dq", "flash_bwd_dkv"):
         assert _kernels(text, scope), scope
+    # the scan's kernels under the scope ``ssd_scan``: a Mamba layer runs
+    # the forward twice (the layer's checkpoint recomputes it) and the
+    # backward once, and nothing of the einsum form's intermediates is
+    # left in HBM (its decay mask and scores: float32 [.., 128, 128] for
+    # each of 64 chunks x 64 heads)
+    mamba = pattern.count("M")
+    scan = _kernels(text, "ssd_scan")
+    assert sum("/ssd_fwd/" in line for line in scan) == 2 * mamba
+    assert sum("/ssd_bwd/" in line for line in scan) == mamba
+    assert not _score_buffers(text, "ssd_scan", 64 * 64 * 128 * 128)
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes) < fits_in
+
+
+def _score_buffers(text, scope, elements):
+    """The float32 shapes ``[.., 128, 128]`` of ``elements`` elements or
+    more that an instruction under ``scope`` (any, if empty) produces or
+    reads."""
+    import math
+    import re
+
+    found = []
+    for line in text.splitlines():
+        if scope and f"/{scope}/" not in line:
+            continue
+        for dims in re.findall(r"f32\[([\d,]+)\]", line):
+            shape = [int(d) for d in dims.split(",")]
+            if shape[-2:] == [128, 128] and math.prod(shape) >= elements:
+                found.append(shape)
+    return found
+
+
+def test_ssd_scan_kernels_compile_at_the_cell_shape(one_chip, as_tpu):
+    """The state-space scan's forward, residual-producing forward and
+    backward kernels at the Nemotron cell's shape (b1 x s8192, 64 heads of
+    64 in 8 groups, state 128, chunk 128), on arrays of their own and on
+    the mixer's one ``[x | B | C]``."""
+    from apex_tpu.ops.ssd_scan import ssd_scan, ssd_scan_packed
+
+    b, s, heads, p, g, n = 1, 8192, 64, 64, 8, 128
+    x = _spec((b, s, heads, p), BF16, one_chip)
+    dt = _spec((b, s, heads), jnp.float32, one_chip)
+    a = _spec((heads,), jnp.float32, one_chip)
+    bc = _spec((b, s, g, n), BF16, one_chip)
+    loss = lambda *t: ssd_scan(*t).astype(jnp.float32).sum()  # noqa: E731
+    text = jax.jit(jax.grad(loss, argnums=range(6))).lower(
+        x, dt, a, bc, bc, a).compile().as_text()
+    assert len(_kernels(text, "ssd_fwd")) == 1
+    assert len(_kernels(text, "ssd_bwd")) == 1
+    assert not _score_buffers(text, "", 64 * 64 * 128 * 128)
+    einsums = lambda *t: ssd_scan(                             # noqa: E731
+        *t, backend="reference").astype(jnp.float32).sum()
+    assert _score_buffers(
+        jax.jit(einsums).lower(x, dt, a, bc, bc, a).compile().as_text(),
+        "", 64 * 64 * 128 * 128)        # what the check is looking for
+    xbc = _spec((b, s, heads * p + 2 * g * n), BF16, one_chip)
+    packed = lambda *t: ssd_scan_packed(                       # noqa: E731
+        *t, groups=g, state=n).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(packed, argnums=range(4))).lower(
+        xbc, dt, a, a).compile().as_text()
+    assert len(_kernels(text, "ssd_fwd")) == 1
+    assert len(_kernels(text, "ssd_bwd")) == 1
+    # forward alone: the kernel that keeps no states
+    text = jax.jit(packed).lower(xbc, dt, a, a).compile().as_text()
+    assert len(_kernels(text, "ssd_fwd")) == 1
 
 
 def test_ddp_step_compiles_for_four_chips(topo, as_tpu):
