@@ -114,7 +114,10 @@ def _is_norm_param(path: tuple) -> bool:
     (apex/amp/_initialize.py:178-184, fp16_utils ``convert_network``).
     In a pytree we go by path naming, which matches flax's
     BatchNorm/LayerNorm/GroupNorm module naming conventions.  The leaves
-    named in ``_FP32_CONSTANTS`` are kept with them.
+    named in ``_FP32_CONSTANTS`` are kept with them, and ``norm`` in a
+    name also keeps a latent-attention layer's two latent norms
+    (``q_a_norm_scale``, ``kv_a_norm_scale``) and a multi-token-prediction
+    module's three (models/hybrid.py).
     """
     keywords = ("batchnorm", "batch_norm", "bn", "layernorm", "layer_norm",
                 "groupnorm", "group_norm", "norm")

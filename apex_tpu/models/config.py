@@ -15,9 +15,9 @@ from typing import Optional
 import jax.numpy as jnp
 
 __all__ = ["TransformerConfig", "gpt_tiny", "gpt_125m", "bert_large",
-           "lfm2_moe", "nemotron_h"]
+           "lfm2_moe", "nemotron_h", "joyai_llm_flash"]
 
-LAYER_KINDS = ("attention", "conv", "mamba", "moe")
+LAYER_KINDS = ("attention", "conv", "mamba", "moe", "mla")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,8 +91,9 @@ class TransformerConfig:
     # 4 float32 ulp of a gate.  One constant, once LFM2 is re-baselined
     moe_routed_scaling: float = 1.0
     moe_gate_epsilon: float = 1e-6
-    # width of one ungated expert that every token passes, added to the
-    # routed sum (hybrid stacks); None = no shared expert
+    # width of one expert that every token passes and no gate of the
+    # router weighs, added to the routed sum (hybrid stacks); of the
+    # experts' own form ('relu2', or gated 'swiglu'); None = none
     moe_shared_expert_size: Optional[int] = None
 
     # stacks whose layers differ in kind (models/hybrid.py): one of
@@ -120,6 +121,25 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     # RMSNorm over each head's channels of q and k, before rope
     qk_norm: bool = False
+    # an 'mla' layer (multi-head latent attention, models/hybrid.py
+    # mla_attention): queries through a latent of mla_q_rank, keys and
+    # values through one of mla_kv_rank, both RMS-normed; a head's q and
+    # k are [mla_nope_dim without position | mla_rope_dim rotary], its v
+    # mla_v_dim wide; the rotary key is one head shared by all (its
+    # width, kv_channels, sizes the rope table), turned in pairs
+    # (2i, 2i+1)
+    mla_q_rank: int = 0
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 128
+    mla_rope_dim: int = 64
+    mla_v_dim: int = 128
+    # multi-token prediction (arXiv 2412.19437, section 2.2): mtp_layers
+    # (0 or 1) modules after the stack, each one more block over
+    # [norm(embedding of the next token) ; norm(hidden)] and the same
+    # head, trained on the token two ahead; the loss adds
+    # mtp_loss_weight times theirs.  The step takes a third input
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
     # False: no bias leaf on any projection (hybrid stacks only)
     use_bias: bool = True
 
@@ -199,10 +219,24 @@ class TransformerConfig:
                 "only 'attention', num_experts exactly where a 'moe' "
                 "layer is, and no num_dense_layers")
         if self.moe_shared_expert_size is not None and (
-                self.activation != "relu2" or not self.num_experts):
+                self.activation not in ("relu2", "swiglu")
+                or not self.num_experts):
             raise ValueError(
-                "a shared expert stands beside routed 'relu2' experts "
-                "(num_experts, activation='relu2')")
+                "a shared expert stands beside routed 'relu2' or "
+                "'swiglu' experts (num_experts, activation)")
+        if "mla" in (self.layer_types or ()) and (
+                self.mla_q_rank < 1 or self.mla_kv_rank < 1
+                or self.mla_rope_dim % 2
+                or self.kv_channels != self.mla_rope_dim
+                or self.position_embedding_type != "rope"):
+            raise ValueError(
+                "an 'mla' layer needs mla_q_rank, mla_kv_rank, an even "
+                "mla_rope_dim that kv_channels repeats, and "
+                "position_embedding_type='rope'")
+        if self.mtp_layers not in (0, 1) or (
+                self.mtp_layers and not self.is_hybrid):
+            raise ValueError(
+                "mtp_layers is 0 or 1, on a hybrid stack (layer_types)")
         if self.moe_experts_held is not None:
             first, count = (int(v) for v in self.moe_experts_held)
             object.__setattr__(self, "moe_experts_held", (first, count))
@@ -421,3 +455,61 @@ def nemotron_h(*, hidden_size: int, num_hidden_layers: int,
         position_embedding_type="none", use_bias=False,
         untie_embeddings_and_output_weights=True,
         layernorm_epsilon=norm_eps, scan_layers=False, **experts, **kw)
+
+
+def joyai_llm_flash(*, hidden_size: int, num_hidden_layers: int,
+                    num_attention_heads: int, q_lora_rank: int,
+                    kv_lora_rank: int, qk_nope_head_dim: int,
+                    qk_rope_head_dim: int, v_head_dim: int,
+                    intermediate_size: int, moe_intermediate_size: int,
+                    first_k_dense_replace: int, n_routed_experts: int,
+                    n_shared_experts: int, num_experts_per_tok: int,
+                    routed_scaling_factor: float, vocab_size: int,
+                    num_nextn_predict_layers: int = 0,
+                    mtp_loss_weight: float = 0.3,
+                    rms_norm_eps: float = 1e-6, rope_theta: float = 3.2e7,
+                    max_position_embeddings: int = 131072,
+                    experts_held=None, **kw) -> TransformerConfig:
+    """The DeepSeek-V3 layer as JoyAI-LLM-Flash publishes it
+    (``model_type`` ``joyai_llm_flash``) from its ``config.json`` keys:
+    multi-head latent attention in every layer (rope in interleaved pairs
+    on the rotary channels, no rope scaling), RMSNorm, bias-free
+    projections, ``first_k_dense_replace`` SwiGLU layers and then
+    sigmoid-routed gated experts (``noaux_tc`` with one group: selection
+    bias, gates normalised over the chosen and scaled by
+    ``routed_scaling_factor``) beside ``n_shared_experts`` gated shared
+    experts (one expert of their joint width), an untied head, and
+    ``num_nextn_predict_layers`` multi-token-prediction modules in the
+    loss (``mtp_loss_weight`` is not a published key).
+    ``n_routed_experts`` is the router's width; ``experts_held=(first,
+    count)`` makes the expert layers one chip's share of an
+    expert-parallel deployment.  Further keywords go to
+    :class:`TransformerConfig` (``remat``, ``fused_head_ce`` ...)."""
+    kw.setdefault("moe_aux_loss_coeff", 0.0)
+    return TransformerConfig(
+        num_layers=num_hidden_layers, hidden_size=hidden_size,
+        num_attention_heads=num_attention_heads,
+        kv_channels=qk_rope_head_dim,
+        mla_q_rank=q_lora_rank, mla_kv_rank=kv_lora_rank,
+        mla_nope_dim=qk_nope_head_dim, mla_rope_dim=qk_rope_head_dim,
+        mla_v_dim=v_head_dim,
+        ffn_hidden_size=moe_intermediate_size,
+        dense_ffn_hidden_size=intermediate_size,
+        num_dense_layers=first_k_dense_replace,
+        num_experts=n_routed_experts,
+        moe_shared_expert_size=(n_shared_experts * moe_intermediate_size
+                                or None),
+        moe_top_k=num_experts_per_tok, moe_routing="ragged",
+        moe_router="sigmoid",
+        moe_routed_scaling=float(routed_scaling_factor),
+        moe_gate_epsilon=1e-20,
+        moe_experts_held=(tuple(experts_held) if experts_held is not None
+                          else None),
+        layer_types=("mla",) * num_hidden_layers,
+        mtp_layers=num_nextn_predict_layers,
+        mtp_loss_weight=float(mtp_loss_weight), vocab_size=vocab_size,
+        max_position_embeddings=max_position_embeddings,
+        activation="swiglu", normalization="rmsnorm",
+        position_embedding_type="rope", rope_theta=float(rope_theta),
+        use_bias=False, untie_embeddings_and_output_weights=True,
+        layernorm_epsilon=rms_norm_eps, scan_layers=False, **kw)

@@ -77,9 +77,12 @@ def make_gpt_train_step(
     backward reduce-scatters.  Beyond the reference: apex stops at
     ZeRO-2 (DistributedFusedAdam's optimizer-state sharding).
 
-    Batch signature grows with the config: ``attn_mask_type='padding'``
-    appends an ``attention_mask`` (True = masked) element, dropout appends
-    a PRNG key — ``step(state, tokens, labels[, mask][, rng])``.
+    Batch signature grows with the config: ``cfg.mtp_layers`` appends
+    ``mtp_labels`` (the tokens two ahead, for the multi-token-prediction
+    module; ``main_loss`` and ``mtp_loss`` then come out in the metrics
+    beside ``loss``), ``attn_mask_type='padding'`` appends an
+    ``attention_mask`` (True = masked) element, dropout appends a PRNG
+    key — ``step(state, tokens, labels[, mtp_labels][, mask][, rng])``.
 
     ``context_parallel`` (requires ``seq_axis``) keeps core attention
     sequence-sharded — the long-context mode.  ``True``/``"ring"``
@@ -154,11 +157,12 @@ def make_gpt_train_step(
 
     def loss_fn(params, tokens, labels, *rest):
         rest = list(rest)
+        mtp_labels = rest.pop(0) if cfg.mtp_layers else None
         mask = rest.pop(0) if has_mask else None
         rng = rest.pop(0) if has_dropout else None
         return gpt_loss(params, tokens, labels, cfg, ctx,
                         attention_mask=mask, dropout_rng=rng,
-                        with_counters=counted)
+                        with_counters=counted, mtp_labels=mtp_labels)
 
     init_fn, step_fn = make_train_step(
         loss_fn, optimizer, policy_or_amp,

@@ -1,7 +1,10 @@
 """Stacks whose layers differ in kind: the LFM2 family's gated short
 convolutions between grouped-query attention layers, dense SwiGLU layers
-before sigmoid-routed expert layers; and the Nemotron-H family's single
-mixers, a Mamba-2 mixer, an expert layer or attention a layer.
+before sigmoid-routed expert layers; the Nemotron-H family's single
+mixers, a Mamba-2 mixer, an expert layer or attention a layer; and the
+DeepSeek-V3 layer, multi-head latent attention before a dense FFN or gated
+experts beside a gated shared expert, with a multi-token-prediction module
+after the stack.
 
 ``TransformerConfig.is_hybrid`` (``layer_types`` or ``num_dense_layers``)
 sends ``init_gpt_params`` and ``transformer_backbone`` here.  The parameters
@@ -20,6 +23,13 @@ One layer, ``u = norm(x)``:
 - ``attention``: the homogeneous stack's, with ``cfg.qk_norm`` an RMSNorm
   over each head's channels of q and k (one weight for all heads) before
   rope;
+- ``mla`` (:func:`mla_attention`; ``n`` heads, ranks ``r_q`` and ``r_kv``):
+  ``c_q = norm(u W_qa)``; ``[q_nope | q_rope] = c_q W_qb``, ``n`` heads of
+  ``mla_nope_dim`` and ``n`` of ``mla_rope_dim``; ``[c_kv | k_r] = u
+  W_kva``, ``c_kv = norm(c_kv)``; ``[k_nope | v] = c_kv W_kvb``; rope in
+  pairs ``(2i, 2i+1)`` on ``q_rope`` of every head and on the ONE ``k_r``;
+  ``softmax((q_nope k_nope^T + q_rope k_r^T) / sqrt(nope + rope)) v``
+  (``ops/flash_attention.flash_attention_mla``); ``W_o``;
 - then ``x + y``, and the dense FFN (the first ``num_dense_layers``) or the
   expert layer on ``norm(x)``.
 
@@ -57,7 +67,8 @@ from apex_tpu.models.config import TransformerConfig
 from apex_tpu.ops.flash_attention import REMAT_SAVED_NAMES
 
 __all__ = ["init_hybrid_params", "hybrid_backbone", "short_conv",
-           "mamba_mixer", "expert_layer", "qk_norm_rope", "moe_counters",
+           "mamba_mixer", "mla_attention", "rope_interleaved",
+           "expert_layer", "mtp_hidden", "mtp_loss", "qk_norm_rope", "moe_counters",
            "MOE_COUNTERS"]
 
 # the expert layers' counters, summed over the layers: assignments on held
@@ -67,7 +78,11 @@ MOE_COUNTERS = ("moe_assignments_held", "moe_assignments",
 
 
 def _kind(cfg: TransformerConfig, layer: int) -> str:
-    return cfg.layer_types[layer] if cfg.layer_types else "attention"
+    """Layer ``layer``'s kind; an index past the stack (the MTP module's
+    block) is of the last layer's."""
+    if not cfg.layer_types:
+        return "attention"
+    return cfg.layer_types[min(layer, cfg.num_layers - 1)]
 
 
 def _has_experts(cfg: TransformerConfig, layer: int) -> bool:
@@ -132,7 +147,24 @@ def init_hybrid_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             lp.update(qkv_bias=zeros(p + 2 * kvp), proj_bias=zeros(h))
         return lp
 
-    def expert_leaves(ks):
+    def mla_leaves(ks):
+        """The columns of ``q_b_kernel`` are all heads' parts without
+        position, then all heads' rotary parts; those of ``kv_b_kernel``
+        all heads' keys, then all heads' values; ``kv_a_kernel``'s last
+        ``mla_rope_dim`` columns make the one rotary key."""
+        n, rq, rkv = (cfg.num_attention_heads, cfg.mla_q_rank,
+                      cfg.mla_kv_rank)
+        dn, dr, dv = cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim
+        return dict(
+            q_a_kernel=nrm(ks[0], (h, rq), std),
+            q_a_norm_scale=jnp.ones((rq,), dt),
+            q_b_kernel=nrm(ks[1], (rq, n * (dn + dr)), std),
+            kv_a_kernel=nrm(ks[2], (h, rkv + dr), std),
+            kv_a_norm_scale=jnp.ones((rkv,), dt),
+            kv_b_kernel=nrm(ks[3], (rkv, n * (dn + dv)), std),
+            proj_kernel=nrm(ks[4], (n * dv, h), out_std))
+
+    def expert_leaves(ks, shared_ks=None):
         G, E, f = (cfg.held_experts[1], cfg.num_experts,
                    cfg.ffn_hidden_size)
         f1 = 2 * f if swiglu else f
@@ -145,12 +177,16 @@ def init_hybrid_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             lp.update(moe_fc1_bias=zeros(G, f1), moe_fc2_bias=zeros(G, h))
         if cfg.moe_shared_expert_size:
             fs = cfg.moe_shared_expert_size
-            lp.update(shared_fc1_kernel=nrm(ks[0], (h, fs), std),
-                      shared_fc2_kernel=nrm(ks[2], (fs, h), out_std))
+            k1, k2 = (ks[0], ks[2]) if shared_ks is None else shared_ks
+            # gated like the dense FFN where the experts are: [h, 2, fs]
+            lp.update(shared_fc1_kernel=nrm(
+                k1, (h, 2, fs) if swiglu else (h, fs), std),
+                shared_fc2_kernel=nrm(k2, (fs, h), out_std))
         return lp
 
-    layers = []
-    for i, key in enumerate(jax.random.split(rng, L + 1)[1:]):
+    def layer_leaves(i, key):
+        """Layer ``i``'s tree; an index past the stack gives the form of
+        the layers after the dense ones (the MTP module's block)."""
         ks = jax.random.split(key, 6)
         kind = _kind(cfg, i)
         lp = {"ln1_scale": jnp.ones((h,), dt)}
@@ -170,8 +206,12 @@ def init_hybrid_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             lp.update(mamba_leaves(ks))
         elif kind == "attention":
             lp.update(attention_leaves(ks))
+        elif kind == "mla":
+            more = jax.random.split(jax.random.fold_in(key, 1), 7)
+            lp.update(mla_leaves(more))
         if _has_experts(cfg, i):
-            lp.update(expert_leaves(ks))
+            lp.update(expert_leaves(
+                ks, more[5:] if kind == "mla" else None))
         elif not cfg.mixer_only:
             f = (cfg.dense_ffn_hidden_size if cfg.num_experts
                  else cfg.ffn_hidden_size)
@@ -181,13 +221,24 @@ def init_hybrid_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             if cfg.use_bias:
                 lp.update(fc1_bias=zeros(2, f) if swiglu else zeros(f),
                           fc2_bias=zeros(h))
-        layers.append(lp)
+        return lp
+
+    layers = [layer_leaves(i, key) for i, key in enumerate(
+        jax.random.split(rng, L + 1)[1:])]
 
     params = {
         "embedding": {"word": nrm(rng, (cfg.vocab_size, h), std)},
         "layers": layers,
         "final_ln": {"scale": jnp.ones((h,), dt)},
     }
+    if cfg.mtp_layers:
+        key = jax.random.fold_in(rng, 3)
+        params["mtp"] = {
+            "enorm_scale": jnp.ones((h,), dt),
+            "hnorm_scale": jnp.ones((h,), dt),
+            "eh_proj_kernel": nrm(key, (2 * h, h), std),
+            "layer": layer_leaves(L, jax.random.fold_in(key, 1)),
+            "norm_scale": jnp.ones((h,), dt)}
     if layernorm:
         params["final_ln"]["bias"] = zeros(h)
     if cfg.position_embedding_type == "learned":
@@ -287,6 +338,59 @@ def qk_norm_rope(cfg: TransformerConfig, lp: dict, q, k, rope):
     return q, k
 
 
+def rope_interleaved(t, cos, sin):
+    """Rope on the last axis of ``t`` [b, s, ..., r] in pairs ``(2i,
+    2i+1)``: pair ``i`` turned by the angle whose ``cos`` and ``sin`` are
+    column ``i`` of the tables ([s, r / 2] or wider: ``rope_cos_sin``'s
+    repeat their first half).  float32 inside, ``t``'s dtype out."""
+    r = t.shape[-1]
+    shape = (1, t.shape[1]) + (1,) * (t.ndim - 3) + (r,)
+    cos = jnp.repeat(cos[:, :r // 2], 2, axis=-1).reshape(shape)
+    sin = jnp.repeat(sin[:, :r // 2], 2, axis=-1).reshape(shape)
+    even = jnp.arange(r) % 2 == 0
+    t32 = t.astype(jnp.float32)
+    # each lane's partner in its pair; -sin for the first of a pair
+    partner = jnp.where(even, jnp.roll(t32, -1, -1), jnp.roll(t32, 1, -1))
+    return (t32 * cos + partner * jnp.where(even, -sin, sin)).astype(
+        t.dtype)
+
+
+def mla_attention(cfg: TransformerConfig, lp: dict, u, rope):
+    """Multi-head latent attention on ``u`` [b, s, h] (the module
+    docstring has the equations; ``rope`` is ``rope_cos_sin``'s pair of
+    tables over ``mla_rope_dim``).  The parts of q and of k/v leave
+    their own products (``W_qb``'s and ``W_kvb``'s column sections), so
+    that the kernels read each where its product wrote it and no
+    gradient is put together from parts an activation at a time."""
+    from apex_tpu.ops.flash_attention import flash_attention_mla
+
+    dt = u.dtype
+    b, s, _ = u.shape
+    n, rkv = cfg.num_attention_heads, cfg.mla_kv_rank
+    dn, dr = cfg.mla_nope_dim, cfg.mla_rope_dim
+    eps = cfg.layernorm_epsilon
+    with jax.named_scope("mla_q_latent"):
+        c_q = _head_rms(u @ lp["q_a_kernel"].astype(dt),
+                        lp["q_a_norm_scale"], eps)
+        w = lp["q_b_kernel"].astype(dt)
+        q = (c_q @ w[:, :n * dn]).reshape(b, s, n, dn)
+        q_r = (c_q @ w[:, n * dn:]).reshape(b, s, n, dr)
+    with jax.named_scope("mla_kv_latent"):
+        c_kv = u @ lp["kv_a_kernel"].astype(dt)
+        k_r = c_kv[..., rkv:]
+        c_kv = _head_rms(c_kv[..., :rkv], lp["kv_a_norm_scale"], eps)
+        w = lp["kv_b_kernel"].astype(dt)
+        k = (c_kv @ w[:, :n * dn]).reshape(b, s, n, dn)
+        v = (c_kv @ w[:, n * dn:]).reshape(b, s, n, -1)
+    with jax.named_scope("mla_rope"):
+        q_r = rope_interleaved(q_r, *rope)
+        k_r = rope_interleaved(k_r, *rope)
+    with jax.named_scope("core_attention"):
+        o = flash_attention_mla(q, q_r, k, k_r, v, causal=True)
+    with jax.named_scope("mla_out"):
+        return o.reshape(b, s, -1) @ lp["proj_kernel"].astype(dt)
+
+
 def moe_counters(cfg: TransformerConfig, load) -> dict:
     """One expert layer's counters from its router's per-expert assignment
     counts ``load`` [E]."""
@@ -300,18 +404,26 @@ def moe_counters(cfg: TransformerConfig, load) -> dict:
 def expert_layer(cfg: TransformerConfig, lp: dict, m):
     """The expert layer on ``m`` [b, s, h]: the routed sum of the held
     experts (``transformer_lm._moe_mlp``) and, where the layer has one
-    (``cfg.moe_shared_expert_size``), the shared expert ``relu(m W_1)^2
-    W_2``, which every token passes and no gate weighs, added once.
-    Returns ``(out, load)``, the router's per-expert assignment counts
-    [E] beside the output."""
+    (``cfg.moe_shared_expert_size``), the shared expert, of the experts'
+    own form: ``relu(m W_1)^2 W_2``, or gated, ``(silu(m W_g) * m W_u)
+    W_2`` (``shared_fc1_kernel`` [h, 2, f_s], the dense FFN's pairing).
+    Every token passes it, no gate of the router weighs it, and it is
+    added once.  Returns ``(out, load)``, the router's per-expert
+    assignment counts [E] beside the output."""
     from apex_tpu.models.transformer_lm import _moe_mlp
 
     out, _, load = _moe_mlp(cfg, lp, m, with_load=True)
     if "shared_fc1_kernel" in lp:
         with jax.named_scope("shared_expert"):
-            y = (m @ lp["shared_fc1_kernel"].astype(m.dtype)).astype(
-                jnp.float32)
-            y = jnp.square(jax.nn.relu(y)).astype(m.dtype)
+            w1 = lp["shared_fc1_kernel"].astype(m.dtype)
+            if w1.ndim == 3:
+                from apex_tpu.ops.swiglu import fused_bias_swiglu_paired
+
+                y = fused_bias_swiglu_paired(
+                    jnp.einsum("bsh,hcf->bscf", m, w1), None)
+            else:
+                y = (m @ w1).astype(jnp.float32)
+                y = jnp.square(jax.nn.relu(y)).astype(m.dtype)
             out = out + y @ lp["shared_fc2_kernel"].astype(m.dtype)
     return out, load
 
@@ -344,9 +456,13 @@ def _layer(cfg: TransformerConfig, layer: int, ctx, lp: dict, x, rope):
 
     with jax.named_scope("ln1"):
         u = apply_norm(cfg, x, lp["ln1_scale"], lp.get("ln1_bias"))
-    if _kind(cfg, layer) == "conv":
+    kind = _kind(cfg, layer)
+    if kind == "conv":
         with jax.named_scope("short_conv"):
             y = short_conv(cfg, lp, u)
+    elif kind == "mla":
+        with jax.named_scope("mla_attention"):
+            y = mla_attention(cfg, lp, u, rope)
     else:
         with jax.named_scope("attention"):
             y = _attention(cfg, lp, u, ctx, None, rope, None)
@@ -367,6 +483,55 @@ def _layer(cfg: TransformerConfig, layer: int, ctx, lp: dict, x, rope):
     return ctx.constrain_hidden(x), counters
 
 
+def _remat_policy():
+    return jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED_NAMES)
+
+
+def mtp_hidden(mp: dict, hidden, embedded, cfg: TransformerConfig, ctx,
+               rope):
+    """The multi-token-prediction module ``mp`` (``params["mtp"]``) on
+    the stack's normed output ``hidden`` and the next tokens' embedding
+    ``embedded``, both [b, s, h]: ``[norm_e(embedded) ; norm_h(hidden)]
+    W_eh`` (two products, one a half of ``W_eh``), one more block of the
+    form of the layers after the dense ones, and the module's own output
+    norm.  Returns ``(hidden, counters)`` like a layer."""
+    from apex_tpu.models.transformer_lm import apply_norm
+
+    h = cfg.hidden_size
+    with jax.named_scope("mtp_merge"):
+        w = mp["eh_proj_kernel"].astype(hidden.dtype)
+        x = (apply_norm(cfg, embedded, mp["enorm_scale"], None) @ w[:h]
+             + apply_norm(cfg, hidden, mp["hnorm_scale"], None) @ w[h:])
+    fn = functools.partial(_layer, cfg, cfg.num_layers, ctx)
+    if cfg.remat:
+        fn = jax.checkpoint(fn, policy=_remat_policy())
+    x, counters = fn(mp["layer"], x, rope)
+    with jax.named_scope("mtp_norm"):
+        return apply_norm(cfg, x, mp["norm_scale"], None), counters
+
+
+def mtp_loss(params: dict, hidden, labels, mtp_labels,
+             cfg: TransformerConfig, ctx, head_ce):
+    """The multi-token-prediction term of ``gpt_loss``: the module on the
+    stack's normed output ``hidden`` and the embedding of ``labels`` (the
+    next tokens), then ``head_ce(hidden, labels)``, the model's own head
+    and cross-entropy, against ``mtp_labels``, the tokens two ahead.
+    Returns ``(loss, counters)``.  Its scopes (``mtp`` around all of it,
+    ``mtp_head``) are opened here."""
+    from apex_tpu.models.transformer_lm import embed_tokens, rope_cos_sin
+
+    with jax.named_scope("mtp"):
+        rope = (rope_cos_sin(hidden.shape[1], cfg.kv_channels,
+                             cfg.rope_theta)
+                if cfg.position_embedding_type == "rope" else None)
+        h2, counters = mtp_hidden(
+            params["mtp"], hidden,
+            embed_tokens(params["embedding"], jnp.maximum(labels, 0), cfg,
+                         ctx), cfg, ctx, rope)
+        with jax.named_scope("mtp_head"):
+            return head_ce(h2, mtp_labels), counters
+
+
 def hybrid_backbone(params: dict, hidden, cfg: TransformerConfig, ctx,
                     rope):
     """All layers in turn; returns ``(hidden, counters)``, the expert
@@ -378,8 +543,7 @@ def hybrid_backbone(params: dict, hidden, cfg: TransformerConfig, ctx,
     if (cfg.hidden_dropout or cfg.attention_dropout or cfg.drop_path_rate
             or cfg.attn_mask_type != "causal"):
         raise ValueError("a hybrid stack is causal and takes no dropout")
-    policy = jax.checkpoint_policies.save_only_these_names(
-        *REMAT_SAVED_NAMES)
+    policy = _remat_policy()
     total = {}
     with jax.named_scope("backbone"):
         for i, lp in enumerate(params["layers"]):

@@ -1064,20 +1064,29 @@ def gpt_hidden(params: dict, tokens: jax.Array, cfg: TransformerConfig,
 def gpt_loss(params: dict, tokens: jax.Array, labels: jax.Array,
              cfg: TransformerConfig, ctx: Optional[TPContext] = None,
              *, attention_mask=None, dropout_rng=None,
-             with_counters: bool = False):
+             with_counters: bool = False, mtp_labels=None):
     """Mean next-token CE. Uses the fused xentropy op (GSPMD/single) or the
     vocab-parallel CE (manual TP) — reference post_language_model_processing
     (standalone_transformer_lm.py:1547 → tensor_parallel/cross_entropy.py:23).
     ``attention_mask`` (True = masked) feeds ``attn_mask_type='padding'``
     models; causal masking needs none.  ``with_counters=True`` (hybrid
     stacks with experts) returns ``(loss, counters)``.
+
+    With ``cfg.mtp_layers`` the loss is ``main + cfg.mtp_loss_weight x
+    mtp``: the multi-token-prediction module (models/hybrid.py
+    ``mtp_loss``) reads the stack's normed output and the embedding of
+    ``labels`` (the next tokens), and the SAME head scores it against
+    ``mtp_labels``, the tokens two ahead.  Both terms come out among the
+    counters as ``main_loss`` and ``mtp_loss``, and the module's expert
+    layer adds to the assignment counters.
     """
     ctx = ctx or single_device_ctx()
     h, aux, *counters = gpt_hidden(params, tokens, cfg, ctx,
                                    attention_mask=attention_mask,
                                    dropout_rng=dropout_rng,
                                    with_counters=with_counters)
-    with jax.named_scope("lm_head_ce"):
+
+    def head_ce(h, labels):
         if cfg.fused_head_ce and not ctx.vocab_parallel:
             # fused head+CE: chunk the vocab matmul into the loss
             # (ops/lm_head_ce.py) — the [tokens, vocab] logits are never
@@ -1088,10 +1097,23 @@ def gpt_loss(params: dict, tokens: jax.Array, labels: jax.Array,
             losses = lm_head_cross_entropy(
                 h, head, labels, chunk=cfg.head_ce_chunk, ignore_index=-1)
             n_valid = jnp.maximum(jnp.sum(labels != -1), 1)
-            loss = jnp.sum(losses) / n_valid.astype(jnp.float32)
-        else:
-            loss = lm_cross_entropy(lm_head_logits(params, h, cfg), labels,
-                                    ctx)
+            return jnp.sum(losses) / n_valid.astype(jnp.float32)
+        return lm_cross_entropy(lm_head_logits(params, h, cfg), labels, ctx)
+
+    with jax.named_scope("lm_head_ce"):
+        loss = head_ce(h, labels)
+    if cfg.mtp_layers:
+        if mtp_labels is None:
+            raise ValueError("cfg.mtp_layers: the loss takes mtp_labels, "
+                             "the tokens two ahead")
+        from apex_tpu.models.hybrid import mtp_loss
+
+        mtp_term, more = mtp_loss(params, h, labels, mtp_labels, cfg, ctx,
+                                  head_ce)
+        if with_counters:
+            total = {k: v + more[k] for k, v in counters[0].items()}
+            counters = [dict(total, main_loss=loss, mtp_loss=mtp_term)]
+        loss = loss + cfg.mtp_loss_weight * mtp_term
     if cfg.num_experts and cfg.moe_aux_loss_coeff:
         # Switch load-balance term, mean over layers
         loss = loss + cfg.moe_aux_loss_coeff * aux / cfg.num_layers

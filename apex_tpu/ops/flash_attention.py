@@ -57,6 +57,13 @@ Variants:
   the normalized probabilities.
 - generic additive ``bias`` or full boolean ``mask`` — routed to the XLA
   composition (rare paths in the reference too).
+- :func:`flash_attention_mla` — multi-head latent attention: a head's q
+  and k are ``[128 without position | 64 rotary]``, its v 128 wide, and
+  the rotary key is ONE head for all query heads.  The same forward
+  kernel with a second product in its scores (:func:`_rope_scores`) and a
+  split backward pair of its own grids, causal bands and padded tails as
+  above, no mask, segments or dropout; nothing is padded to a common
+  width and the rotary key is never copied a head.
 
 Backward: custom_vjp with the standard two-kernel scheme — dq accumulates
 over kv blocks, dk/dv over q blocks, both recomputing the probabilities
@@ -85,10 +92,12 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
-from apex_tpu.ops._pallas_utils import LANES as _LANES, on_tpu, out_struct
+from apex_tpu.ops._pallas_utils import (
+    LANES as _LANES, interpret_forced, on_tpu, out_struct)
 
-__all__ = ["causal_work_share", "flash_attention", "flash_attention_packed",
-           "mha_reference", "segment_ids_from_cu_seqlens"]
+__all__ = ["causal_work_share", "flash_attention", "flash_attention_mla",
+           "flash_attention_packed", "mha_reference",
+           "segment_ids_from_cu_seqlens"]
 
 _NEG_INF = -1e30
 
@@ -366,6 +375,9 @@ class _Heads(NamedTuple):
     hpb: int
     d: int
     packed: bool
+    # latent attention (:func:`flash_attention_mla`): a head's score takes
+    # a second product, its rotary part with the one rotary key
+    rope: bool = False
 
     @property
     def width(self):
@@ -490,6 +502,27 @@ def _merge(parts, heads, shape):
     return out
 
 
+def _rope_half(x, f):
+    """Latent attention: ``x`` [rows, 128] holds the rotary parts of two
+    heads side by side, or something that is to be added to them; the
+    lanes of head ``f`` (its parity picks the half) kept, the other
+    head's zeroed."""
+    half = _div(jax.lax.broadcasted_iota(jnp.int32, x.shape, 1),
+                x.shape[1] // 2)
+    return jnp.where(half == _rem(f, 2), x, 0.0)
+
+
+def _rope_scores(qr, kr, f):
+    """Head ``f``'s rotary part of a score rectangle: ``qr`` [rows, 128],
+    its pair's block of rotary queries, against ``kr`` [cols, 128], the
+    one rotary key twice side by side, so that the zeroed half of ``qr``
+    takes the other copy out of the sum (a K = 128 product costs the
+    passes of a K = 64 one)."""
+    return jax.lax.dot_general(
+        _rope_half(qr.astype(jnp.float32), f), kr.astype(jnp.float32),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
                 has_seg, dropout_p, sub, multi, padded, direct, heads,
                 *refs):
@@ -500,6 +533,8 @@ def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
         seg_refs, refs = refs[:2], refs[2:]
     q_ref, k_ref, v_ref = refs[:3]
     kpm_ref, refs = (refs[3], refs[4:]) if has_kpm else (None, refs[3:])
+    if heads.rope:
+        (qr_ref, kr_ref), refs = refs[:2], refs[2:]
     o_ref, lse_ref = refs[:2]
     f, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     hs = range(heads.hpb)
@@ -517,7 +552,10 @@ def _fwd_kernel(scale, causal, sk_real, block_q, block_k, has_kpm,
         k = k_ref[0, cols, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            preferred_element_type=jnp.float32)
+        if heads.rope:
+            s = s + _rope_scores(qr_ref[0, rows, :], kr_ref[0, cols, :], f)
+        s = s * scale
         if has_kpm:
             s = s + kpm_ref[0, :, cols]  # additive [1, cols] broadcast
 
@@ -639,7 +677,8 @@ def _jit_once(driver):
     import inspect
 
     names = list(inspect.signature(driver).parameters)
-    arrays = {"q3", "k3", "v3", "do3", "lse3", "o3", "kpm", "seg", "seed"}
+    arrays = {"q3", "k3", "v3", "do3", "lse3", "o3", "kpm", "seg", "seed",
+              "qr3", "kr3"}
     return jax.jit(driver, static_argnames=[
         n for n in names if n not in arrays])
 
@@ -726,13 +765,17 @@ def _fwd_pallas(q3, k3, v3, kpm, seg, seed, scale, causal, sk_real,
 # ---------------------------------------------------------------------------
 
 
-def _scores_and_dp(q, k, v, do, kpm, scale):
+def _scores_and_dp(q, k, v, do, kpm, scale, rope=None):
     """The backward kernels' first stage for one head over a score
     rectangle: the scaled scores ``q k^T`` (plus the additive key mask)
-    and ``do v^T``, ``q`` and ``do`` the head's :func:`_take`."""
+    and ``do v^T``, ``q`` and ``do`` the head's :func:`_take`; ``rope``
+    (latent attention) is the head's rotary part of the scores."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+        preferred_element_type=jnp.float32)
+    if rope is not None:
+        s = s + rope
+    s = s * scale
     if kpm is not None:
         s = s + kpm
     dp = jax.lax.dot_general(
@@ -752,6 +795,8 @@ def _bwd_tile(refs, has_kpm, has_seg, dropout_p, scale, heads, f, q_start,
         seg_refs, refs = refs[:2], refs[2:]
     q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref = refs[:6]
     kpm_ref, refs = (refs[6], refs[7:]) if has_kpm else (None, refs[6:])
+    if heads.rope:
+        (qr_ref, kr_ref), refs = refs[:2], refs[2:]
     slots = [heads.slot(f, h) for h in range(heads.hpb)]
 
     def products(h, r0, rn, c0, cn, crossed):
@@ -759,10 +804,12 @@ def _bwd_tile(refs, has_kpm, has_seg, dropout_p, scale, heads, f, q_start,
         q = _take(q_ref[0, rows, :].astype(jnp.float32), h, slots[h], heads)
         do = _take(do_ref[0, rows, :].astype(jnp.float32), h, slots[h],
                    heads)
+        rope = (_rope_scores(qr_ref[0, rows, :], kr_ref[0, cols, :], f)
+                if heads.rope else None)
         return (*_scores_and_dp(
             q, k_ref[0, cols, :].astype(jnp.float32),
             v_ref[0, cols, :].astype(jnp.float32), do,
-            kpm_ref[0, :, cols] if has_kpm else None, scale), q, do)
+            kpm_ref[0, :, cols] if has_kpm else None, scale, rope), q, do)
 
     def grads(h, r0, rn, c0, cn, crossed, made):
         """``(p_acc, ds, q, do)``: the probabilities that weigh ``do``
@@ -1300,6 +1347,317 @@ def _flash_bwd(causal, scale, dropout_p, res, do):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA): a head's query and key are [no-position part |
+# rotary part], the value is as wide as the no-position part, and the rotary
+# key is ONE head that all query heads read.
+# ---------------------------------------------------------------------------
+
+
+def _mla_specs(block_q, block_k, at):
+    """The block specs of a latent-attention kernel whose grid points
+    ``at`` maps to ``(batch row, head, q block, kv block)``: of a q-like
+    array ([b, s, n x 128], one head a block), of a k-like one, of the
+    rotary queries ([b, s, n x 64]: a pair of heads a block) and of the
+    rotary key ([b, s, 128]: the one key twice, for every head)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(block, index):
+        return pl.BlockSpec((1, block, _LANES),
+                            lambda *g: index(*at(*g)),
+                            memory_space=pltpu.VMEM)
+
+    return (spec(block_q, lambda b, h, i, j: (b, i, h)),
+            spec(block_k, lambda b, h, i, j: (b, j, h)),
+            spec(block_q, lambda b, h, i, j: (b, i, _div(h, 2))),
+            spec(block_k, lambda b, h, i, j: (b, j, 0)))
+
+
+def _mla_heads(q3):
+    n = q3.shape[2] // _LANES
+    return _Heads(n, n, 1, _LANES, True, rope=True)
+
+
+@_jit_once
+def _mla_fwd_pallas(q3, qr3, k3, kr3, v3, scale, causal, sk_real, block_q,
+                    block_k, interpret):
+    """:func:`_fwd_kernel` with the rotary product in its scores, on
+    ``q3``, ``k3``, ``v3`` [b, s, n x 128], ``qr3`` [b, s, n x 64] and
+    ``kr3`` [b, s, 128]; grid (batch x heads, q, kv).  Returns ``o`` and
+    the logsumexp, both shaped like ``q3``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    heads = _mla_heads(q3)
+    n = heads.nb
+    sqp, skp = q3.shape[1], k3.shape[1]
+    grid = (q3.shape[0] * n, sqp // block_q, skp // block_k)
+    sub = _sub_tile(block_q, block_k) if causal else 0
+    direct = bool(sub) and grid[2] == 1
+    q_spec, k_spec, qr_spec, kr_spec = _mla_specs(
+        block_q, block_k, lambda f, i, j: (_div(f, n), _rem(f, n), i, j))
+    with jax.named_scope("flash_fwd"):
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, scale, causal, sk_real,
+                              block_q, block_k, False, False, 0.0, sub,
+                              grid[1:] != (1, 1), skp != sk_real, direct,
+                              heads),
+            grid=grid,
+            in_specs=[q_spec, k_spec, k_spec, qr_spec, kr_spec],
+            out_specs=[q_spec, q_spec],
+            out_shape=[out_struct(q3.shape, q3.dtype, q3),
+                       out_struct(q3.shape, jnp.float32, q3)],
+            scratch_shapes=[] if direct else [
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((1, block_q, _LANES), jnp.float32),
+                pltpu.VMEM((1, block_q, _LANES), jnp.float32),
+            ],
+            compiler_params=_compiler_params(),
+            interpret=interpret,
+        )(q3, k3, v3, qr3, kr3)
+
+
+def _mla_bwd_dq_kernel(scale, causal, sk_real, padded, block_q, block_k,
+                       sub, multi, heads, *refs):
+    # grid (batch x head pairs, q, 2, kv): a pair's two heads follow one
+    # another inside a q block, so that the pair's block of the rotary
+    # queries' gradient stays where it is while both add their half
+    pair, qi = pl.program_id(0), pl.program_id(1)
+    h2, kj = pl.program_id(2), pl.program_id(3)
+    f = pair * 2 + h2
+    last = kj == pl.num_programs(3) - 1
+    bounded = padded or not sub     # see _fwd_kernel
+    kr_ref = refs[7]
+    products, grads, _, k_ref, outs, _ = _bwd_tile(
+        refs, False, False, 0.0, scale, heads, f, qi * block_q,
+        kj * block_k, sk_real if bounded else None, None, not sub)
+    dq_ref, dqr_ref, dq_acc, dqr_acc = outs
+
+    @pl.when(kj == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when((kj == 0) & (h2 == 0))
+    def _init_pair():
+        dqr_acc[:] = jnp.zeros_like(dqr_acc)
+
+    def _dq(h, r0, rn, c0, cn, crossed, made):
+        ds = grads(h, r0, rn, c0, cn, crossed, made)[1]
+        return tuple(jax.lax.dot_general(
+            ds, ref[0, c0:c0 + cn, :].astype(jnp.float32),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            for ref in (k_ref, kr_ref))
+
+    def _accumulate(r0, rn, c0, cn, crossed, parts):
+        (dq, dqr), = parts
+        dq_acc[r0:r0 + rn, :] += dq
+        dqr_acc[r0:r0 + rn, :] += _rope_half(dqr, f)
+
+    _visit_tile(products, _dq, _accumulate, 1, causal, block_q, block_k,
+                sub, multi, qi, kj, None)
+
+    @pl.when(last)
+    def _finalize():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+    @pl.when(last & (h2 == 1))
+    def _finalize_pair():
+        dqr_ref[0] = dqr_acc[:].astype(dqr_ref.dtype)
+
+
+def _mla_bwd_dkv_kernel(scale, causal, sq_real, sk_real, padded, block_q,
+                        block_k, sub, multi, heads, *refs):
+    # grid (batch, kv, heads, q): a head's dk and dv accumulate over its
+    # q blocks; the rotary key's gradient, the sum over ALL heads, stays
+    # in its block across (heads x q blocks) consecutive steps.  A head
+    # adds to its own half of the doubled key's lanes (the other half of
+    # its rotary queries is zeroed); the wrapper adds the halves
+    bi, kj = pl.program_id(0), pl.program_id(1)
+    hd, qi = pl.program_id(2), pl.program_id(3)
+    f = bi * heads.nb + hd
+    last = qi == pl.num_programs(3) - 1
+    bounded = padded or not sub     # see _fwd_kernel
+    qr_ref = refs[6]
+    products, grads, _, _, outs, _ = _bwd_tile(
+        refs, False, False, 0.0, scale, heads, f, qi * block_q,
+        kj * block_k, sk_real if bounded else None,
+        sq_real if bounded else None, not sub)
+    dk_ref, dv_ref, dkr_ref, dk_acc, dv_acc, dkr_acc = outs
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when((qi == 0) & (hd == 0))
+    def _init_rope():
+        dkr_acc[:] = jnp.zeros_like(dkr_acc)
+
+    def _dkv(h, r0, rn, c0, cn, crossed, made):
+        p_acc, ds, q, do = grads(h, r0, rn, c0, cn, crossed, made)
+        qr = _rope_half(qr_ref[0, r0:r0 + rn, :].astype(jnp.float32), f)
+        return (*_dkv_of(p_acc, ds, q, do), jax.lax.dot_general(
+            ds, qr, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
+
+    def _accumulate(r0, rn, c0, cn, crossed, parts):
+        (dv, dk, dkr), = parts
+        dv_acc[c0:c0 + cn, :] += dv
+        dk_acc[c0:c0 + cn, :] += dk
+        dkr_acc[c0:c0 + cn, :] += dkr
+
+    _visit_tile(products, _dkv, _accumulate, 1, causal, block_q, block_k,
+                sub, multi, qi, kj, None, by="cols")
+
+    @pl.when(last)
+    def _finalize():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(last & (hd == heads.nb - 1))
+    def _finalize_rope():
+        dkr_ref[0] = dkr_acc[:].astype(dkr_ref.dtype)
+
+
+@_jit_once
+def _mla_bwd_pallas(q3, qr3, k3, kr3, v3, do3, lse3, o3, scale, causal,
+                    sq_real, sk_real, block_q, block_k, interpret):
+    """The split backward pair of latent attention (arrays as
+    :func:`_mla_fwd_pallas` takes them, ``do3``, ``lse3`` and ``o3``
+    shaped like ``q3``): ``(dq, dqr, dk, dkr, dv)``, ``dkr`` [b, s, 128]
+    with the even heads' sum in its first half and the odd heads' in its
+    second."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    heads = _mla_heads(q3)
+    n = heads.nb
+    sqp, skp = q3.shape[1], k3.shape[1]
+    sub = _sub_tile(block_q, block_k) if causal else 0
+    multi = (sqp, skp) != (block_q, block_k)
+
+    def call(kernel, grid, at, outs):
+        q_spec, k_spec, qr_spec, kr_spec = _mla_specs(block_q, block_k, at)
+        like = {"q": (q3, q_spec, block_q), "qr": (qr3, qr_spec, block_q),
+                "k": (k3, k_spec, block_k), "kr": (kr3, kr_spec, block_k)}
+        return pl.pallas_call(
+            functools.partial(kernel, block_q, block_k, sub, multi, heads),
+            grid=grid,
+            in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec, q_spec,
+                      qr_spec, kr_spec],
+            out_specs=[like[x][1] for x in outs],
+            out_shape=[out_struct(like[x][0].shape, like[x][0].dtype,
+                                  like[x][0]) for x in outs],
+            scratch_shapes=[pltpu.VMEM((like[x][2], _LANES), jnp.float32)
+                            for x in outs],
+            compiler_params=_compiler_params(),
+            interpret=interpret,
+        )(q3, k3, v3, do3, lse3, o3, qr3, kr3)
+
+    with jax.named_scope("flash_bwd_dq"):
+        dq, dqr = call(
+            functools.partial(_mla_bwd_dq_kernel, scale, causal, sk_real,
+                              skp != sk_real),
+            (q3.shape[0] * n // 2, sqp // block_q, 2, skp // block_k),
+            lambda p, i, h2, j: (_div(p, n // 2),
+                                 _rem(p, n // 2) * 2 + h2, i, j),
+            ("q", "qr"))
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv, dkr = call(
+            functools.partial(_mla_bwd_dkv_kernel, scale, causal, sq_real,
+                              sk_real, (sqp, skp) != (sq_real, sk_real)),
+            (q3.shape[0], skp // block_k, n, sqp // block_q),
+            lambda b, j, h, i: (b, h, i, j), ("k", "k", "kr"))
+    return dq, dqr, dk, dkr, dv
+
+
+def _mla_kernel_arrays(q, qr, k, kr, v):
+    """The five arrays as the kernels read them, padded to whole blocks:
+    the free reshapes [b, s, n x d], and the rotary key twice side by
+    side in one block of 128 lanes (2 MB at s8192, where a copy a head
+    would be 32)."""
+    b, sq, n, _ = q.shape
+    sk = k.shape[1]
+    block_q, block_k = _blocks(sq, sk)
+    sqp = pl.cdiv(sq, block_q) * block_q
+    skp = pl.cdiv(sk, block_k) * block_k
+    return (_pad_to(q.reshape(b, sq, -1), sqp, 1),
+            _pad_to(qr.reshape(b, sq, -1), sqp, 1),
+            _pad_to(k.reshape(b, sk, -1), skp, 1),
+            _pad_to(jnp.concatenate([kr, kr], axis=-1), skp, 1),
+            _pad_to(v.reshape(b, sk, -1), skp, 1)), (block_q, block_k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_mla(q, qr, k, kr, v, causal, scale):
+    return _flash_mla_fwd(q, qr, k, kr, v, causal, scale)[0]
+
+
+def _flash_mla_fwd(q, qr, k, kr, v, causal, scale):
+    b, sq, n, _ = q.shape
+    arrays, blocks = _mla_kernel_arrays(q, qr, k, kr, v)
+    o3, lse3 = _mla_fwd_pallas(*arrays, scale, causal, k.shape[1], *blocks,
+                               interpret=not on_tpu())
+    o = checkpoint_name(_from_kernel(o3, b, n, 1)[:, :sq],
+                        REMAT_SAVED_NAMES[0])
+    lse = checkpoint_name(_lse_from_kernel(lse3, b, n, 1)[:, :sq],
+                          REMAT_SAVED_NAMES[1])
+    return o, (q, qr, k, kr, v, o, lse)
+
+
+def _flash_mla_bwd(causal, scale, res, do):
+    q, qr, k, kr, v, o, lse = res
+    b, sq, n, d = q.shape
+    sk = k.shape[1]
+    arrays, blocks = _mla_kernel_arrays(q, qr, k, kr, v)
+    sqp = arrays[0].shape[1]
+    dq3, dqr3, dk3, dkr3, dv3 = _mla_bwd_pallas(
+        *arrays, _pad_to(do.reshape(b, sq, -1), sqp, 1),
+        _pad_to(_lse_to_kernel(lse, d, 1), sqp, 1),
+        _pad_to(o.reshape(b, sq, -1), sqp, 1), scale, causal, sq, sk,
+        *blocks, interpret=not on_tpu())
+    dkr = dkr3[:, :sk].astype(jnp.float32)
+    half = kr.shape[-1]
+    return (dq3[:, :sq].reshape(q.shape), dqr3[:, :sq].reshape(qr.shape),
+            dk3[:, :sk].reshape(k.shape),
+            (dkr[..., :half] + dkr[..., half:]).astype(kr.dtype),
+            dv3[:, :sk].reshape(v.shape))
+
+
+_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
+
+
+def flash_attention_mla(q, q_rope, k, k_rope, v, *, causal: bool = False,
+                        scale: Optional[float] = None) -> jax.Array:
+    """Attention whose heads score with two parts (multi-head latent
+    attention, arXiv 2405.04434): ``q`` and ``k`` [b, s, n, d] without
+    position, ``q_rope`` [b, s, n, r] and ONE rotary key ``k_rope``
+    [b, s, r] for all heads, ``v`` [b, s, n, dv]: ``softmax((q k^T +
+    q_rope k_rope^T) * scale) v`` [b, s, n, dv], ``scale`` by default
+    ``1 / sqrt(d + r)``.
+
+    On a TPU (or interpreted) with ``d = dv = 128``, ``r = 64`` and an
+    even head count the flash kernels run, forward and split backward:
+    the score of a tile is the sum of two products in one visit, the
+    rotary queries of two heads share a 128-lane block, and the rotary
+    key is read once a K/V block for all heads -- it is never broadcast
+    to the heads and nothing is padded to a common width.  The kernels
+    take causal or full attention and sequences that are no multiple of
+    the block.  Every other shape, and every other platform, runs
+    :func:`mha_reference` on the concatenated parts."""
+    n, d, r = q.shape[2], q.shape[3], q_rope.shape[3]
+    scale = (1.0 / (d + r) ** 0.5) if scale is None else float(scale)
+    kernels = (d == v.shape[3] == _LANES and 2 * r == _LANES
+               and n % 2 == 0 and k.shape[2] == n
+               and (on_tpu() or interpret_forced())
+               and not jax.typeof(q).vma)
+    if kernels:
+        return _flash_mla(q, q_rope, k, k_rope, v, causal, scale)
+    k_rope = jnp.broadcast_to(k_rope[:, :, None, :],
+                              k.shape[:3] + (r,))
+    return mha_reference(jnp.concatenate([q, q_rope], -1),
+                         jnp.concatenate([k, k_rope], -1), v,
+                         causal=causal, scale=scale)
 
 
 def _seed_from_rng(dropout_rng) -> jax.Array:

@@ -802,3 +802,110 @@ class TestHeadsABlock:
         q, k, v = make_qkv(2, 128, 3, 64, seed=7)
         _assert_same(_fwd_and_grads(flash_attention, q, k, v, causal=True),
                      _fwd_and_grads(mha_reference, q, k, v, causal=True))
+
+
+class TestLatentAttention:
+    """``flash_attention_mla``: q/k of a head are [128 without position |
+    64 rotary], v is 128 wide, the rotary key is ONE head for all query
+    heads.  The kernels (interpreted here) against ``mha_reference`` on
+    the expanded tensors: q and k concatenated to 192, the rotary key
+    broadcast to the heads."""
+
+    @staticmethod
+    def _inputs(s, n, seed=0, dtype=jnp.float32, d=128, r=64, dv=128):
+        rng = np.random.RandomState(seed)
+
+        def draw(*shape):
+            return jnp.asarray(rng.randn(*shape), dtype) * 0.5
+
+        return (draw(1, s, n, d), draw(1, s, n, r), draw(1, s, n, d),
+                draw(1, s, r), draw(1, s, n, dv))
+
+    @staticmethod
+    def _expanded(q, qr, k, kr, v, causal):
+        kr = jnp.broadcast_to(kr[:, :, None, :], k.shape[:3] + kr.shape[-1:])
+        return mha_reference(jnp.concatenate([q, qr], -1),
+                             jnp.concatenate([k, kr], -1), v, causal=causal)
+
+    @staticmethod
+    def _all(fn, args, causal):
+        def loss(*args):
+            o = fn(*args, causal)
+            return jnp.sum(o * jnp.cos(o)), o
+
+        (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                       has_aux=True)(*args)
+        return (o, *g)
+
+    @pytest.mark.parametrize("s,n,causal", [
+        (256, 2, False),
+        (256, 2, True),
+        (300, 4, False),        # no multiple of the block: padded rows
+        (300, 2, True),
+        (1024, 2, True),        # one tile a head: the static nest
+        (2048, 2, True),        # 2 x 2 tiles: below / on / above
+        (1024 + 40, 2, True),   # a padded tail inside a sub-tile
+        (1024 + 40, 2, False),
+    ])
+    def test_kernels_match_reference_on_expanded(self, s, n, causal,
+                                                 monkeypatch):
+        from apex_tpu.ops import flash_attention as fa
+
+        monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setattr(
+            fa, "mha_reference", lambda *a, **k: pytest.fail(
+                "the kernels' shape took the reference"))
+        args = self._inputs(s, n, seed=s + n)
+        got = self._all(lambda *a: fa.flash_attention_mla(
+            *a[:5], causal=a[5]), args, causal)
+        want = self._all(self._expanded, args, causal)
+        for a, b, name in zip(got, want,
+                              ("o", "dq", "dq_rope", "dk", "dk_rope", "dv")):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, rtol=1e-4, err_msg=name)
+
+    def test_bf16_output_and_gradient_dtypes(self, monkeypatch):
+        from apex_tpu.ops.flash_attention import flash_attention_mla
+
+        monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
+        args = self._inputs(256, 2, dtype=jnp.bfloat16)
+        got = self._all(lambda *a: flash_attention_mla(
+            *a[:5], causal=a[5]), args, True)
+        want = self._all(self._expanded,
+                         [a.astype(jnp.float32) for a in args], True)
+        for a, b, x in zip(got, want, (args[4],) + args):
+            assert a.dtype == jnp.bfloat16 and a.shape == x.shape
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b), atol=4e-2, rtol=4e-2)
+
+    @pytest.mark.parametrize("shape", [
+        dict(d=32, r=16, dv=32),      # toy widths
+        dict(d=128, r=64, dv=64),     # v narrower than the keys
+        dict(d=128, r=32, dv=128),    # a rotary part that fills no half
+    ])
+    def test_other_shapes_take_the_reference(self, shape, monkeypatch):
+        """Off the kernels' shape, and off the TPU without interpret
+        mode, the same function is ``mha_reference`` on the expanded
+        tensors, which takes unequal q/k and v widths."""
+        from apex_tpu.ops.flash_attention import flash_attention_mla
+
+        monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
+        args = self._inputs(64, 3, **shape)
+        got = self._all(lambda *a: flash_attention_mla(
+            *a[:5], causal=a[5]), args, True)
+        want = self._all(self._expanded, args, True)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5, rtol=1e-5)
+
+    def test_off_tpu_default_is_the_reference(self, monkeypatch):
+        from apex_tpu.ops import flash_attention as fa
+
+        monkeypatch.delenv("APEX_TPU_PALLAS_INTERPRET", raising=False)
+        monkeypatch.setattr(fa, "_flash_mla", lambda *a: pytest.fail(
+            "kernels off the TPU without interpret mode"))
+        args = self._inputs(128, 2)
+        got = fa.flash_attention_mla(*args, causal=True)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(self._expanded(*args, True)),
+            atol=1e-6)

@@ -211,3 +211,82 @@ def test_the_scan_kernels_are_named_under_ssd_scan(monkeypatch):
     assert fwd and all("rematted_computation" in n for n in fwd)
     assert bwd and all("transpose(" in n
                        and "rematted_computation" not in n for n in bwd)
+
+
+MLA = {"mla_attention", "mla_q_latent", "mla_kv_latent", "mla_rope",
+       "mla_out"}
+MTP = {"mtp", "mtp_merge", "mtp_head"}
+
+
+@pytest.mark.parametrize("route", ["kernels", "reference"])
+def test_the_latent_stack_and_the_mtp_module_are_scoped(route, monkeypatch):
+    """The DeepSeek-V3 layer (one dense layer, one expert layer, the MTP
+    module; at the kernels' head widths, 128 + 64 and v 128, or at toy
+    widths, where ``mha_reference`` runs): every product and kernel has a
+    word; the latent block's parts and the module's are there forward,
+    recomputed and backward; the flash kernels keep their names and the
+    forward one is not recomputed; the module's block carries ``mtp``
+    OUTSIDE its ``mla_attention``, which is what lets the benchmark read
+    ``mtp_ms`` and ``mla_attention_ms`` as everything under a word."""
+    from apex_tpu.models.config import joyai_llm_flash
+
+    monkeypatch.setenv("APEX_TPU_PALLAS_INTERPRET", "1")
+    wide = route == "kernels"
+    cfg = joyai_llm_flash(
+        hidden_size=128, num_hidden_layers=2, num_attention_heads=2,
+        q_lora_rank=64, kv_lora_rank=32,
+        qk_nope_head_dim=128 if wide else 32,
+        qk_rope_head_dim=64 if wide else 16,
+        v_head_dim=128 if wide else 32, intermediate_size=256,
+        moe_intermediate_size=128, first_k_dense_replace=1,
+        n_routed_experts=16, n_shared_experts=1, num_experts_per_tok=4,
+        routed_scaling_factor=2.5, vocab_size=512,
+        num_nextn_predict_layers=1, experts_held=(4, 4),
+        fused_head_ce=True, remat=True)
+    init, step = make_gpt_train_step(cfg, fused_adam(lr=1e-4), "O2")
+    state = jax.eval_shape(init, jax.random.key_data(jax.random.key(0)))
+    ids = np.zeros((B, S), np.int32)
+    text = step.lower(state, ids, ids, ids).compile().as_text()
+
+    known = (ALL | HYBRID | MLA | MTP | HYBRID_KERNELS_FWD
+             | HYBRID_KERNELS_BWD | {"shared_expert", "mtp_norm"})
+    seen = {"forward": set(), "recompute": set(), "backward": set()}
+    unscoped_work, nested = [], set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? ([\w\-]+)\(", line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if not m or not op:
+            continue
+        words = _words(op.group(1)) & known
+        if "rematted_computation" in op.group(1):
+            seen["recompute"] |= words
+        elif "transpose(" in op.group(1):
+            seen["backward"] |= words
+        elif "jvp(" in op.group(1):
+            seen["forward"] |= words
+        if "mtp" in words:
+            nested |= words
+        if m.group(2) in ("dot", "convolution", "custom-call") and not words:
+            unscoped_work.append(line.strip()[:200])
+    assert not unscoped_work
+    block = MLA | {"core_attention", "ln1", "ln2", "dense_ffn", "router",
+                   "expert_ffn", "shared_expert", "moe_dispatch",
+                   "moe_combine"}
+    assert block | MTP | {"gmm_fwd", "lm_head_ce"} <= seen["forward"]
+    assert block | MTP | {"gmm_dx", "gmm_dw"} <= seen["backward"]
+    # the backward needs the output projection's input again, not its
+    # output
+    assert (MLA - {"mla_out"} | {"shared_expert", "router", "gmm_fwd",
+                                 "mtp"} <= seen["recompute"])
+    assert "attention" not in set().union(*seen.values())
+    # the module's own block and head, under its word
+    assert MLA | {"router", "expert_ffn", "shared_expert", "mtp_head",
+                  "mtp_merge", "mtp_norm"} <= nested
+    assert "lm_head_ce" not in nested and "dense_ffn" not in nested
+    kernels = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    if wide:
+        assert "flash_fwd" in seen["forward"]
+        assert {"flash_bwd_dq", "flash_bwd_dkv"} <= seen["backward"]
+        assert "flash_fwd" not in seen["recompute"]
+    else:
+        assert not kernels & set().union(*seen.values())

@@ -459,11 +459,11 @@ def test_o2_keeps_the_time_constants_float32():
     (dict(activation="relu2"), "hybrid"),
     (dict(position_embedding_type="none"), "hybrid"),
     (dict(layer_types=("moe",), num_layers=1,
-          num_experts=4, moe_routing="ragged", activation="swiglu",
+          num_experts=4, moe_routing="ragged", activation="gelu",
           moe_shared_expert_size=8), "shared expert"),
 ], ids=["experts_need_moe_layer", "heads_fill_groups", "no_conv_mixer",
         "moe_needs_experts", "relu2_is_hybrid", "no_positions_is_hybrid",
-        "shared_needs_relu2"])
+        "shared_needs_relu2_or_swiglu"])
 def test_config_refuses(kw, match):
     with pytest.raises(ValueError, match=match):
         TransformerConfig(**kw)

@@ -416,6 +416,85 @@ def test_nemotron_h_step_compiles_at_published_widths(
             + m.temp_size_in_bytes - m.alias_size_in_bytes) < fits_in
 
 
+def _joyai_cell():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "joyai_llm_flash_ep32.json")) as f:
+        return json.load(f)
+
+
+def test_joyai_llm_flash_step_compiles_at_published_widths(one_chip,
+                                                           as_tpu):
+    """The benchmark's JoyAI-LLM-Flash cell as it is timed: published
+    widths (hidden 2048, 32 heads of q/k 128 + 64 and v 128 through
+    latents of 1536 and 512, dense FFN 7168, experts of 768 beside a gated
+    shared expert, 8 of 256 a token with 8 held), layers 0-4 and the MTP
+    module at b1 x s8192, three inputs.  The whole O2 train step compiles
+    for the described v5e with the grouped kernels and the three flash
+    kernels in: a block runs the forward kernel ONCE (its output and
+    logsumexp are kept across the checkpoint) and each backward kernel
+    once, six blocks; and nothing activation-sized is padded or broadcast
+    around them: no bfloat16 array of q's or k's 32 x 192 lanes or of 32
+    x 256 exists in the step."""
+    import re
+
+    from apex_tpu.models.config import joyai_llm_flash
+    from apex_tpu.models.gpt import make_gpt_train_step
+    from apex_tpu.optimizers import fused_adam
+
+    config = _joyai_cell()
+    cfg = joyai_llm_flash(**config["program"]["model_config_kwargs"])
+    init, step = make_gpt_train_step(cfg, fused_adam(lr=1e-4), "O2")
+    state = _like(jax.eval_shape(
+        init, jax.random.key_data(jax.random.key(0))), one_chip)
+    assert sum(x.size for x in jax.tree_util.tree_leaves(
+        state.master_params)) == config["state_bytes"]["parameters"]
+    ids = _spec((1, 8192), jnp.int32, one_chip)
+    compiled = step.lower(state, ids, ids, ids).compile()
+    text = compiled.as_text()
+    for scope in ("gmm_fwd", "gmm_dx", "gmm_dw"):
+        assert _kernels(text, scope), scope
+    for scope in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert len(_kernels(text, scope)) == 6, scope
+    # q or k at 192 a head (6144 lanes), either padded to 256 (8192 lanes
+    # of q; kv_b's own product is split in two of 4096), the rotary key a
+    # head (2048 lanes from a [.., 64] source is q_rope's own width: its
+    # count is the rotary queries' alone)
+    for lanes in (32 * 192, 32 * 256):
+        assert not re.search(rf"bf16\[1,8192,{lanes}\]", text), lanes
+        assert not re.search(rf"bf16\[8192,{lanes}\]", text), lanes
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) < 13e9
+
+
+@pytest.mark.slow
+def test_joyai_llm_flash_reference_fits_the_check(one_chip):
+    """The check's float32 reference of the JoyAI-LLM-Flash cell, loss
+    and gradients at b1 x s8192 in blocks (benchmark/reference/
+    joyai_llm_flash.py), compiles for the described v5e, and what it
+    takes beside its arguments and its gradients leaves room for the
+    other float32 trees that benchmark/reference/train.py holds at its
+    peak (6.25 trees of 492 M parameters: 12.3 GB of 15.75; 1.83 GB read,
+    PR 37).  Marked slow: the compile alone is 100-200 s here."""
+    from benchmark.reference import joyai_llm_flash as model
+    from benchmark.reference import transformer as T
+
+    config = _joyai_cell()
+    params = _like(jax.eval_shape(
+        lambda k: model.init_params(k, config), jax.random.key(0)),
+        one_chip)
+    ids = _spec((1, 8192), jnp.int32, one_chip)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda p, batch: model.loss(p, batch, config, T.Precision()))
+    ).lower(params, (ids, ids, ids)).compile()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 2.5e9, m.temp_size_in_bytes
+
+
 def _score_buffers(text, scope, elements):
     """The float32 shapes ``[.., 128, 128]`` of ``elements`` elements or
     more that an instruction under ``scope`` (any, if empty) produces or
