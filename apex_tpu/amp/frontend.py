@@ -419,6 +419,12 @@ def make_train_step(
             metrics["aux"] = aux
         return new_state, metrics
 
+    # the start-up ledger hears this step's tracing, lowering and compile
+    # or cache load (observability.startup_summary); placed last so that
+    # no line a traced function runs on moves
+    from apex_tpu.observability.device import install_recompile_tracker
+
+    install_recompile_tracker()
     return init_fn, step_fn
 
 

@@ -38,10 +38,13 @@ The flight-recorder & diagnostics layer (ISSUE 4) builds on those:
   automatically by ``record_step_metrics`` / ``record_scaler_step`` /
   span observations.
 - :mod:`apex_tpu.observability.device` — runtime accounting: the
-  ``jax.monitoring``-based recompilation tracker
-  (``compile.{count,ms}`` per :func:`compile_label`) and HBM gauges
-  from ``device.memory_stats()`` (``hbm.{bytes_in_use,peak_bytes}``),
-  attached to BENCH JSON by ``bench.py``.
+  ``jax.monitoring``-based start-up ledger, always on (tracing,
+  lowering, compiles and persistent-cache loads by function and by
+  time: :func:`startup_summary`; ``compile.{count,ms}`` per
+  :func:`compile_label` count compiles only, ``compile.load.*`` the
+  loads) and HBM gauges from ``device.memory_stats()``
+  (``hbm.{bytes_in_use,peak_bytes}``), attached to BENCH JSON by
+  ``bench.py``.
 
 Everything is host-side at step boundaries: no host callbacks, nothing
 traced into jit bodies — device values enter telemetry only through the
@@ -54,6 +57,7 @@ from apex_tpu.observability.device import (  # noqa: F401
     recompile_tracker,
     runtime_summary,
     sample_device_memory,
+    startup_summary,
 )
 from apex_tpu.observability.metrics import (  # noqa: F401
     SCHEMA_VERSION,
@@ -106,4 +110,5 @@ __all__ = [
     "shutdown",
     "sketch",
     "span",
+    "startup_summary",
 ]

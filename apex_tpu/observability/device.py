@@ -1,16 +1,32 @@
-"""Runtime accounting: recompilation tracking + device-memory gauges.
+"""Runtime accounting: the start-up ledger + device-memory gauges.
 
 Two failure modes fail dark without this module:
 
-- **Silent retraces.**  A shape or static-arg change recompiles inside
-  the steady-state loop; the step "gets slow" with no signal.  JAX
-  emits ``jax.monitoring`` duration events on every backend compile
-  (``/jax/core/compile/backend_compile_duration``), so
-  :class:`RecompileTracker` listens there and accounts every compile as
-  ``compile.{count,ms}`` — overall and per *function label* (the
-  :func:`compile_label` context names whatever region triggered it:
-  ``StepTimer`` labels its warmup, the serving engine its
-  prefill/decode compiles, ``make_ddp_train_step`` its step).
+- **Silent retraces, and set-up nobody can explain.**  A shape or
+  static-arg change recompiles inside the steady-state loop, and a
+  program's start-up spends tens of seconds nobody can split.  JAX
+  times each phase that turns a Python function into an executable
+  with ``jax.monitoring`` spans: tracing to a jaxpr
+  (``jaxpr_trace_duration``), lowering to MLIR
+  (``jaxpr_to_mlir_module_duration``) and the backend step
+  (``backend_compile_duration``), which either compiles or loads the
+  executable from the persistent compilation cache: JAX reports a
+  ``/jax/compilation_cache/cache_hits`` event on the compiling thread
+  inside that span when it loads.  :class:`RecompileTracker` hears
+  all of them and keeps the **start-up ledger**: one row a function
+  (JAX's ``fun_name``, or the innermost :func:`compile_label` where
+  one is set: ``StepTimer`` labels its warmup, the serving engine its
+  prefill/decode compiles, ``make_ddp_train_step`` its step) with its
+  trace, lower, compile and load seconds and counts, and a bounded
+  list of the spans on ``time.perf_counter()``.  Every instant is
+  booked once: spans nest (a jitted ``jnp`` function's trace inside
+  the step's, a Pallas kernel traced while its caller lowers), and an
+  instant belongs to the innermost open span of the highest phase
+  (backend over lowering over tracing).  :func:`startup_summary`
+  gives the rows and the seconds of each phase between two instants.
+  Into a configured registry it mirrors ``compile.{count,ms}`` (misses
+  only) overall and per label, ``compile.load.{count,ms}`` and
+  ``compile.trace_lower.ms``.
 - **HBM creep.**  Fragmentation or a cache that grows per request eats
   headroom until an OOM with no history.
   :func:`sample_device_memory` reads
@@ -20,18 +36,19 @@ Two failure modes fail dark without this module:
   the serving engine, so the JSONL stream and the trace timeline carry
   a memory time series next to the step times.
 
-The tracker is intentionally usable WITHOUT a configured registry:
-``bench.py`` installs it standalone and attaches
-:func:`runtime_summary` to the BENCH JSON line, so recompile counts and
-HBM peaks ride every published measurement.  The ``jax.monitoring``
-listener is registered once per process and costs nothing between
-compile events; when neither a tracker nor a registry exists it returns
-immediately.
+The tracker needs no configured registry and is always on: importing
+this module installs it (``make_train_step`` imports it), so set-up is
+on the ledger from the first step built; ``bench.py`` attaches
+:func:`runtime_summary` to the BENCH JSON line.  JAX fires these
+events only where it traces, lowers or compiles: a warm jitted call
+fires none, so the listeners cost nothing in steady state.
 """
 
 from __future__ import annotations
 
+import collections
 import threading
+import time
 from typing import Dict, Optional
 
 __all__ = [
@@ -42,13 +59,23 @@ __all__ = [
     "recompile_tracker",
     "runtime_summary",
     "sample_device_memory",
+    "startup_summary",
 ]
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_RANK = {"trace": 0, "lower": 1, "backend": 2}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                 "/jax/compilation_cache/cache_misses": "misses"}
+SPAN_LIMIT = 1 << 16        # the ordered list keeps the newest spans
 
 _TRACKER: Optional["RecompileTracker"] = None
 _LISTENER_REGISTERED = False
 _LABELS = threading.local()
+_OPEN = threading.local()       # .stack: this thread's open spans
 
 
 class compile_label:
@@ -82,76 +109,258 @@ def current_compile_label() -> Optional[str]:
     return stack[-1] if stack else None
 
 
-class RecompileTracker:
-    """Per-process compile accounting fed by ``jax.monitoring``.
+def _row_key(fun_name: Optional[str]) -> str:
+    """JAX's name of the function: ``jit(f)``, as lowering and the
+    backend name it, is ``f``, as tracing does."""
+    name = fun_name or "unlabeled"
+    if name.startswith("jit(") and name.endswith(")"):
+        name = name[4:-1]
+    return name
 
-    Keeps its own ``{label: {count, ms}}`` ledger (so ``bench.py`` can
-    read it with telemetry off) and mirrors into the live registry's
-    ``compile.count`` / ``compile.ms`` counters (+ per-label
-    ``compile.<label>.{count,ms}``) when one is configured.  ``ms``
-    counters are integer milliseconds (counters are ints)."""
+
+class _Open:
+    """A span whose start JAX has reported and whose end it has not."""
+
+    __slots__ = ("phase", "start", "inner", "hit")
+
+    def __init__(self, phase: str, start: float):
+        self.phase, self.start = phase, start
+        self.inner = 0.0        # seconds inside it booked to other spans
+        self.hit = False        # a backend span that loaded from the cache
+
+
+def _new_row() -> dict:
+    return {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+            "load_s": 0.0, "n_compiled": 0, "n_loaded": 0}
+
+
+def _add(row: dict, phase: str, booked_s: float) -> None:
+    row[phase + "_s"] += booked_s
+    if phase in ("compile", "load"):
+        row["n_compiled" if phase == "compile" else "n_loaded"] += 1
+
+
+def _union_s(intervals) -> float:
+    total, reach = 0.0, float("-inf")
+    for start, end in sorted(intervals):
+        if end > reach:
+            total += end - max(start, reach)
+            reach = end
+    return total
+
+
+class RecompileTracker:
+    """Per-process start-up ledger fed by ``jax.monitoring``.
+
+    ``rows`` is ``{function: {trace_s, lower_s, compile_s, load_s,
+    n_compiled, n_loaded}}`` over the process's life; ``spans`` the
+    newest :data:`SPAN_LIMIT` spans in the order they ended, each
+    ``(start, end, phase, key, booked_s)`` on ``time.perf_counter()``
+    with ``phase`` one of ``trace``, ``lower``, ``compile``, ``load``.
+    Mirrors into the live registry when one is configured: ``compile.
+    {count,ms}`` and ``compile.<label>.{count,ms}`` for compiles
+    (persistent-cache misses), ``compile.load.{count,ms}`` for loads,
+    ``compile.trace_lower.ms``.  ``ms`` counters are integer
+    milliseconds (counters are ints)."""
 
     def __init__(self):
         self._lock = threading.Lock()
-        self.by_label: Dict[str, Dict[str, float]] = {}
+        self.rows: Dict[str, dict] = {}          # guarded-by: self._lock
+        self.spans = collections.deque(maxlen=SPAN_LIMIT)  # guarded-by: self._lock
+        self.n_spans = 0                         # guarded-by: self._lock
+        self.cache = {"hits": 0, "misses": 0}    # guarded-by: self._lock
+        self.n_events = 0                        # guarded-by: self._lock
+        self.callback_s = 0.0                    # guarded-by: self._lock
+        self._trace_lower_s = 0.0                # guarded-by: self._lock
 
+    # -- what the listeners call ------------------------------------------
     def on_compile(self, dur_s: float, label: Optional[str]) -> None:
+        """Book one compile of ``dur_s`` to ``label``."""
         label = label or "unlabeled"
+        self._book("compile", label, label, dur_s, dur_s)
+
+    def _book(self, phase: str, key: str, label: str, dur_s: float,
+              booked_s: float, span: Optional[tuple] = None) -> None:
+        trace_lower_ms = 0
         with self._lock:
-            row = self.by_label.setdefault(label, {"count": 0, "ms": 0.0})
-            row["count"] += 1
-            row["ms"] += dur_s * 1e3
+            if span is not None:
+                self.spans.append(span)
+                self.n_spans += 1
+            _add(self.rows.setdefault(key, _new_row()), phase, booked_s)
+            if phase in ("trace", "lower"):
+                before = int(self._trace_lower_s * 1e3)
+                self._trace_lower_s += booked_s
+                trace_lower_ms = int(self._trace_lower_s * 1e3) - before
         from apex_tpu.observability import metrics as _metrics
 
         reg = _metrics.registry()
-        if reg is not None:
-            ms = int(round(dur_s * 1e3))
+        if reg is None:
+            return
+        ms = int(round(dur_s * 1e3))
+        if phase == "compile":
             reg.counter("compile.count").inc()
             reg.counter("compile.ms").inc(ms)
             reg.counter(f"compile.{label}.count").inc()
             reg.counter(f"compile.{label}.ms").inc(ms)
             reg.event("compile", label=label, ms=round(dur_s * 1e3, 3))
+        elif phase == "load":
+            reg.counter("compile.load.count").inc()
+            reg.counter("compile.load.ms").inc(ms)
+        elif trace_lower_ms:
+            reg.counter("compile.trace_lower.ms").inc(trace_lower_ms)
 
-    def total_count(self) -> int:
-        """Locked total compile count — the jax.monitoring listener
-        mutates ``by_label`` from compile threads, so readers on the
-        telemetry path must not iterate it bare."""
+    def _span_started(self, phase: str, start_wall: float) -> None:
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        stack.append(_Open(phase, start_wall))
+
+    def _cache_event(self, kind: str) -> None:
+        for span in reversed(getattr(_OPEN, "stack", None) or ()):
+            if span.phase == "backend":
+                span.hit = span.hit or kind == "hits"
+                break
         with self._lock:
-            return sum(v["count"] for v in self.by_label.values())
+            self.cache[kind] += 1
+
+    def _span_ended(self, phase: str, start_wall: float, end_wall: float,
+                    fun_name: Optional[str]) -> None:
+        stack = getattr(_OPEN, "stack", None) or []
+        span = None
+        for i in range(len(stack) - 1, -1, -1):
+            if stack[i].phase == phase and stack[i].start == start_wall:
+                span = stack[i]
+                del stack[i:]
+                break
+        dur = end_wall - start_wall
+        inner = span.inner if span is not None else 0.0
+        # the innermost open span of the highest phase owns an instant
+        if any(_RANK[s.phase] > _RANK[phase] for s in stack):
+            booked, covered = 0.0, inner
+        else:
+            booked, covered = max(dur - inner, 0.0), dur
+        if stack:
+            stack[-1].inner += covered
+        if phase == "backend":
+            phase = "load" if span is not None and span.hit else "compile"
+        label = current_compile_label()
+        key = label or _row_key(fun_name)
+        offset = time.perf_counter() - time.time()
+        self._book(phase, key, label or "unlabeled", dur, booked,
+                   (start_wall + offset, end_wall + offset, phase, key,
+                    booked))
+
+    def _charge(self, t0: float) -> None:
+        with self._lock:
+            self.n_events += 1
+            self.callback_s += time.perf_counter() - t0
+
+    # -- readers ----------------------------------------------------------
+    def total_count(self) -> int:
+        """Locked count of backend spans, compiles and loads: the
+        jax.monitoring listeners mutate ``rows`` from compile threads,
+        so readers on the telemetry path must not iterate it bare."""
+        with self._lock:
+            return sum(r["n_compiled"] + r["n_loaded"]
+                       for r in self.rows.values())
 
     def summary(self) -> dict:
         with self._lock:
-            by_label = {k: {"count": v["count"],
-                            "ms": round(v["ms"], 3)}
-                        for k, v in self.by_label.items()}
+            rows = {k: dict(v) for k, v in self.rows.items()}
+            trace_lower_s = self._trace_lower_s
+        by_label = {k: {"count": v["n_compiled"],
+                        "ms": round(v["compile_s"] * 1e3, 3),
+                        "load_count": v["n_loaded"],
+                        "load_ms": round(v["load_s"] * 1e3, 3)}
+                    for k, v in rows.items()
+                    if v["n_compiled"] or v["n_loaded"]}
         return {
             "count": sum(v["count"] for v in by_label.values()),
             "ms": round(sum(v["ms"] for v in by_label.values()), 3),
+            "load": {
+                "count": sum(v["load_count"] for v in by_label.values()),
+                "ms": round(sum(v["load_ms"] for v in by_label.values()),
+                            3)},
+            "trace_lower": {"ms": round(trace_lower_s * 1e3, 3)},
             "by_label": by_label,
         }
 
+    def startup(self, since: Optional[float] = None,
+                until: Optional[float] = None) -> dict:
+        """The ledger between two ``perf_counter`` instants: spans that
+        ended by ``until``, cut at ``since``.  Each phase's seconds are
+        the union of its spans' time less that of the higher phases
+        (compile, load, lower, trace), so none is counted twice and
+        the four add up to the time any span was open."""
+        lo = float("-inf") if since is None else since
+        hi = float("inf") if until is None else until
+        with self._lock:
+            spans = [s for s in self.spans if s[1] <= hi and s[1] > lo]
+            out = {"dropped": self.n_spans - len(self.spans),
+                   "cache": dict(self.cache),
+                   "callbacks": {"count": self.n_events,
+                                 "s": self.callback_s}}
+        rows: Dict[str, dict] = {}
+        for _, _, phase, key, booked in spans:
+            _add(rows.setdefault(key, _new_row()), phase, booked)
+        covered, reached = [], 0.0
+        for phase in ("compile", "load", "lower", "trace"):
+            covered += [(max(s[0], lo), s[1]) for s in spans
+                        if s[2] == phase]
+            union = _union_s(covered)
+            out[phase + "_s"] = union - reached
+            reached = union
+        out["rows"] = rows
+        out["n_spans"] = len(spans)
+        return out
+
     def reset(self) -> None:
         with self._lock:
-            self.by_label.clear()
+            self.rows.clear()
+            self.spans.clear()
+            self.n_spans = 0
+            self.cache = {"hits": 0, "misses": 0}
+            self._trace_lower_s = 0.0
 
 
-def _on_monitoring_event(name: str, dur_s: float, **kw) -> None:
-    # called for EVERY jax duration event; keep the miss path tiny
-    if name != _COMPILE_EVENT:
+# The three listeners.  JAX calls them for every event of their kind;
+# the fast path (another event, or no tracker) is a dict lookup.
+def _on_span_start(name: str, value: float, **kw) -> None:
+    tracker, phase = _TRACKER, _PHASES.get(name)
+    if tracker is None or phase is None:
         return
-    tracker = _TRACKER
-    if tracker is None:
+    t0 = time.perf_counter()
+    tracker._span_started(phase, value)
+    tracker._charge(t0)
+
+
+def _on_span_end(name: str, start: float, end: float, **kw) -> None:
+    tracker, phase = _TRACKER, _PHASES.get(name)
+    if tracker is None or phase is None:
         return
-    tracker.on_compile(dur_s, current_compile_label())
+    t0 = time.perf_counter()
+    tracker._span_ended(phase, start, end, kw.get("fun_name"))
+    tracker._charge(t0)
+
+
+def _on_event(name: str, **kw) -> None:
+    tracker, kind = _TRACKER, _CACHE_EVENTS.get(name)
+    if tracker is None or kind is None:
+        return
+    t0 = time.perf_counter()
+    tracker._cache_event(kind)
+    tracker._charge(t0)
 
 
 def install_recompile_tracker() -> Optional[RecompileTracker]:
     """Install (or return the existing) process-wide tracker.
 
-    Registers the ``jax.monitoring`` listener on first call; there is
-    no unregister API, so the listener stays and fast-paths out when
-    the tracker is later discarded.  Returns None when jax.monitoring
-    is unavailable (the tracker degrades to absent, never raises)."""
+    Registers the ``jax.monitoring`` listeners on first call (a span's
+    start arrives as a scalar event, its end as a time span, a cache
+    hit or miss as an event); there is no unregister API, so they stay
+    and fast-path out when the tracker is later discarded.  Returns
+    None when jax.monitoring is unavailable (the tracker degrades to
+    absent, never raises)."""
     global _TRACKER, _LISTENER_REGISTERED
     if _TRACKER is not None:
         return _TRACKER
@@ -160,8 +369,9 @@ def install_recompile_tracker() -> Optional[RecompileTracker]:
             from jax import monitoring
         except Exception:   # pragma: no cover - jax without monitoring
             return None
-        monitoring.register_event_duration_secs_listener(
-            _on_monitoring_event)
+        monitoring.register_scalar_listener(_on_span_start)
+        monitoring.register_event_time_span_listener(_on_span_end)
+        monitoring.register_event_listener(_on_event)
         _LISTENER_REGISTERED = True
     _TRACKER = RecompileTracker()
     return _TRACKER
@@ -169,6 +379,19 @@ def install_recompile_tracker() -> Optional[RecompileTracker]:
 
 def recompile_tracker() -> Optional[RecompileTracker]:
     return _TRACKER
+
+
+def startup_summary(until: Optional[float] = None,
+                    since: Optional[float] = None) -> Optional[dict]:
+    """The start-up ledger up to the ``time.perf_counter()`` instant
+    ``until`` (spans that ended by then; all of them by default), cut
+    at ``since``: ``trace_s``, ``lower_s``, ``compile_s``, ``load_s``
+    (each instant once), ``rows`` by function, ``cache`` hits and
+    misses, ``callbacks`` (how many calls the listeners took and their
+    seconds: the ledger's own cost), ``n_spans`` and ``dropped`` (spans
+    the bounded list let go).  None without a tracker."""
+    tracker = _TRACKER
+    return None if tracker is None else tracker.startup(since, until)
 
 
 def sample_device_memory(emit: bool = True) -> Optional[dict]:
@@ -220,8 +443,9 @@ def sample_device_memory(emit: bool = True) -> Optional[dict]:
 
 def runtime_summary() -> dict:
     """The accounting block ``bench.py`` attaches to the BENCH JSON
-    line: compile counts/ms (per label) + HBM usage when the platform
-    reports it.  Works with or without a configured registry."""
+    line: compile counts/ms (misses, per label), ``load`` counts/ms and
+    ``trace_lower`` ms + HBM usage when the platform reports it.  Works
+    with or without a configured registry."""
     out: dict = {}
     tracker = _TRACKER
     if tracker is not None:
@@ -230,3 +454,6 @@ def runtime_summary() -> dict:
     if mem is not None:
         out["hbm"] = mem
     return out
+
+
+install_recompile_tracker()

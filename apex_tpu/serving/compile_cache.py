@@ -35,10 +35,14 @@ DYNAMIC arguments only — ``static_argnames`` are baked in at lowering
 routes its call sites accordingly (``ServingEngine._cc``).
 
 Telemetry: ``serving.compile_cache.{hits,misses}`` counters and the
-``serving.compile_cache.load_ms`` histogram; misses additionally land
-in the existing ``compile.ms`` ledger via ``jax.monitoring`` (loads do
-not compile, which is exactly what makes cold vs warm start visible —
-``tools/telemetry_report.py compile_cache_summary`` reads both sides).
+``serving.compile_cache.load_ms`` histogram.  A miss lowers and
+compiles through XLA, which the start-up ledger hears
+(``observability/device.py``): in ``compile.{count,ms}`` where XLA
+compiled, in ``compile.load.{count,ms}`` where JAX's own persistent
+compilation cache, if one is set, served the executable.  A hit here
+deserializes the executable and reaches neither — which is what
+makes cold vs warm start visible
+(``tools/telemetry_report.py compile_cache_summary`` reads all three).
 ``docs/serving.md`` has the operator runbook (cache dir lifecycle,
 priming, invalidation).
 """

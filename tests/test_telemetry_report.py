@@ -704,6 +704,32 @@ def test_compile_cache_summary_from_stream(report, tmp_path):
     assert "worker READY ms last 1800" in text
 
 
+def test_compile_cache_summary_reads_loads_beside_compiles(report,
+                                                          tmp_path):
+    """``compile.*`` counts the backend steps that compiled,
+    ``compile.load.*`` those JAX's persistent cache served: the view
+    reads both, and prints the loads' line only where there are any."""
+    f = tmp_path / "cc.jsonl"
+    f.write_text(
+        '{"schema_version":3,"t":1,"type":"counter",'
+        '"name":"serving.compile_cache.misses","value":2}\n'
+        '{"schema_version":3,"t":2,"type":"counter",'
+        '"name":"compile.count","value":1}\n'
+        '{"schema_version":3,"t":3,"type":"counter",'
+        '"name":"compile.ms","value":900.0}\n'
+        '{"schema_version":3,"t":4,"type":"counter",'
+        '"name":"compile.load.count","value":4}\n'
+        '{"schema_version":3,"t":5,"type":"counter",'
+        '"name":"compile.load.ms","value":36.0}\n')
+    summ = report.summarize(report.load_records([str(f)]))
+    cc = report.compile_cache_summary(summ)
+    assert (cc["compile_count"], cc["compile_ms_total"]) == (1, 900.0)
+    assert (cc["load_count"], cc["load_ms_total"]) == (4, 36.0)
+    out = io.StringIO()
+    report.print_report(summ, out=out)
+    assert "persistent-cache loads 4 -> 36 ms total" in out.getvalue()
+
+
 def test_compile_cache_summary_ready_only_and_absent(report):
     """A stream holding only worker.ready_ms (no-cache worker) still
     gets the READY row; a cache-free, READY-free stream hides the

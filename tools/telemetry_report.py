@@ -552,8 +552,10 @@ def compile_cache_summary(summary: dict) -> Optional[dict]:
     (``serving.compile_cache.{hits,misses}``), the cache-load wall
     p50/p95 (``serving.compile_cache.load_ms`` — what a warm start
     pays per executable) against the cumulative XLA compile ledger
-    (``compile.count`` / ``compile.ms``, PR 4's jax.monitoring
-    mirror — what every miss costs), warmup-ladder runs, and the
+    (``compile.count`` / ``compile.ms``: backend steps that compiled,
+    what every miss costs; ``compile.load.count`` / ``compile.load.ms``:
+    those JAX's persistent cache served — the start-up ledger's
+    jax.monitoring mirror), warmup-ladder runs, and the
     worker READY wall (``worker.ready_ms`` gauge — one sample per
     worker process, so count ≈ workers in the stream).  None when the
     stream carries no compile-cache or READY series (engines without
@@ -575,6 +577,8 @@ def compile_cache_summary(summary: dict) -> Optional[dict]:
                      "count": len(load)} if load else None),
         "compile_count": counters.get("compile.count", 0.0),
         "compile_ms_total": counters.get("compile.ms", 0.0),
+        "load_count": counters.get("compile.load.count", 0.0),
+        "load_ms_total": counters.get("compile.load.ms", 0.0),
         "warmups": summary["events"].get(
             "serving.compile_cache.warmup", 0),
         "ready_ms": ({"count": len(ready), "last": ready[-1],
@@ -855,7 +859,10 @@ def print_report(summary: dict, out=None) -> None:
         if cc["compile_count"]:
             print(f"  XLA compiles {cc['compile_count']:g} -> "
                   f"{cc['compile_ms_total']:g} ms total (what each "
-                  "miss costs; loads bypass this ledger)", file=out)
+                  "miss costs)", file=out)
+        if cc["load_count"]:
+            print(f"  XLA persistent-cache loads {cc['load_count']:g} -> "
+                  f"{cc['load_ms_total']:g} ms total", file=out)
         if cc["ready_ms"]:
             r = cc["ready_ms"]
             print(f"  worker READY ms last {r['last']:g}  min "
