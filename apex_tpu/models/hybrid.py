@@ -72,9 +72,10 @@ __all__ = ["init_hybrid_params", "hybrid_backbone", "short_conv",
            "MOE_COUNTERS"]
 
 # the expert layers' counters, summed over the layers: assignments on held
-# experts and in all, the largest and the mean count over held experts
+# experts and in all, the largest and the mean count over held experts, and
+# the sorted rows the routing's live chunks covered
 MOE_COUNTERS = ("moe_assignments_held", "moe_assignments",
-                "moe_held_load_max", "moe_held_load_mean")
+                "moe_held_load_max", "moe_held_load_mean", "moe_routed_rows")
 
 
 def _kind(cfg: TransformerConfig, layer: int) -> str:
@@ -391,14 +392,24 @@ def mla_attention(cfg: TransformerConfig, lp: dict, u, rope):
         return o.reshape(b, s, -1) @ lp["proj_kernel"].astype(dt)
 
 
-def moe_counters(cfg: TransformerConfig, load) -> dict:
+def moe_counters(cfg: TransformerConfig, load, tokens: int) -> dict:
     """One expert layer's counters from its router's per-expert assignment
-    counts ``load`` [E]."""
+    counts ``load`` [E] over ``tokens`` tokens.  The routed rows are those
+    of the chunks (``transformer/moe.py`` ``routing_chunk_rows``) that start
+    before the last held row: ``ceil(held / chunk) · chunk``, or the whole
+    buffer where it is one chunk."""
+    from apex_tpu.transformer.moe import routing_chunk_rows
+
     first, count = cfg.held_experts
     held = load[first:first + count].astype(jnp.float32)
+    n = tokens * cfg.moe_top_k
+    chunk = routing_chunk_rows(n, load.shape[0], count)
+    total = jnp.sum(held)
+    routed = (jnp.ceil(total / chunk) * chunk if chunk < n
+              else jnp.float32(n))
     return dict(zip(MOE_COUNTERS, (
-        jnp.sum(held), jnp.sum(load.astype(jnp.float32)), jnp.max(held),
-        jnp.mean(held))))
+        total, jnp.sum(load.astype(jnp.float32)), jnp.max(held),
+        jnp.mean(held), routed)))
 
 
 def expert_layer(cfg: TransformerConfig, lp: dict, m):
@@ -442,7 +453,7 @@ def _mixer_layer(cfg: TransformerConfig, layer: int, ctx, lp: dict, x,
     elif kind == "moe":
         with jax.named_scope("mlp"):
             y, load = expert_layer(cfg, lp, u)
-        counters = moe_counters(cfg, load)
+        counters = moe_counters(cfg, load, u.shape[0] * u.shape[1])
     else:
         with jax.named_scope("attention"):
             y = _attention(cfg, lp, u, ctx, None, rope, None)
@@ -474,7 +485,7 @@ def _layer(cfg: TransformerConfig, layer: int, ctx, lp: dict, x, rope):
     if _has_experts(cfg, layer):
         with jax.named_scope("mlp"):
             f, load = expert_layer(cfg, lp, m)
-        counters = moe_counters(cfg, load)
+        counters = moe_counters(cfg, load, m.shape[0] * m.shape[1])
     else:
         with jax.named_scope("dense_ffn"):
             f = _mlp(cfg, lp, m, ctx)

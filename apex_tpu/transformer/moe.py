@@ -80,10 +80,11 @@ from apex_tpu.observability import metrics as _telemetry
 # shared with the ring collective-matmuls so byte/axis accounting
 # cannot drift between the TP and EP overlap paths
 from apex_tpu.ops.collective_matmul import _mesh_axis, _nbytes
+from apex_tpu.ops.grouped_matmul import _BLOCK_ROWS as _GMM_BLOCK_ROWS
 from apex_tpu.ops.grouped_matmul import grouped_matmul, group_ids
 
-__all__ = ["init_moe_params", "switch_moe_mlp", "MoEOutput",
-           "MOE_ROUTINGS", "MOE_ROUTERS"]
+__all__ = ["init_moe_params", "switch_moe_mlp", "routing_chunk_rows",
+           "MoEOutput", "MOE_ROUTINGS", "MOE_ROUTERS"]
 
 MOE_ROUTINGS = ("capacity", "ragged")
 MOE_ROUTERS = ("softmax", "sigmoid")
@@ -413,25 +414,40 @@ _compressed_ring_gather.defvjp(_crg_fwd, _crg_bwd)
 # ---------------------------------------------------------------------------
 
 
-# rows of the sorted buffer that one ``lax.cond`` covers when only some
-# experts are held (a multiple of the grouped kernels' 512-row blocks)
-_CHUNK_ROWS = 16384
-
-
 def _float0(x):
     return np.zeros(x.shape, jax.dtypes.float0)
 
 
-def _live_chunks(rows, n, chunk, out_shape):
-    """``sum_c rows(lo_c, args, order, offsets)`` [T, h] over the chunks
-    ``lo_c = c · chunk`` of the ``n`` sorted rows that start before the
-    last held row (``offsets[-1]``); the others are skipped, forward and
-    backward.  A ``lax.scan`` of ``lax.cond``s with a hand-written VJP:
-    autodiff's transpose of a skipped ``cond`` would write a zero
-    gradient as large as the expert slabs for every skipped chunk and
-    add them all up; here the cotangents are accumulated in the scan's
-    carry by the live chunks alone (each recomputes its forward, as the
-    layer's remat would)."""
+def _dispatch(x2, gates, order, lo, chunk, top_k):
+    """``(slot, tok, xs, gs)`` of sorted rows ``lo .. lo + chunk``: their
+    assignment slots, their tokens, the tokens' rows of ``x2`` and the
+    slots' gates."""
+    slot = jax.lax.dynamic_slice(order, (lo,), (chunk,))
+    with jax.named_scope("moe_dispatch"):
+        tok = slot // top_k
+        xs = x2[tok]
+    with jax.named_scope("moe_combine"):
+        return slot, tok, xs, gates.reshape(-1)[slot]
+
+
+def _gated(gs, h2):
+    """The rows' expert outputs ``h2`` weighed by their gates, float32."""
+    with jax.named_scope("moe_combine"):
+        return gs[:, None] * h2.astype(jnp.float32)
+
+
+def _live_chunks(ffn, n, chunk, top_k):
+    """``run(x2, gates, w, order, offsets)`` [T, h] float32: the gated
+    expert outputs ``ffn(lo, xs, w, offsets)`` of the ``n`` sorted rows,
+    walked in chunks ``lo_c = c · chunk``; a chunk that starts at or past
+    the last held row (``offsets[-1]``) is skipped, forward and backward.
+    A ``lax.scan`` of ``lax.cond``s with a hand-written VJP: autodiff's
+    transpose of a skipped ``cond`` would write a zero gradient as large as
+    the expert slabs for every skipped chunk and add them all up.  A live
+    chunk costs its own rows: the combine scatter-adds them into the scan's
+    carried output, and in the backward the dispatch's transpose
+    scatter-adds into the carried gradient of ``x2`` (each live chunk
+    recomputes its forward, as the layer's remat would)."""
     starts = np.arange(0, n, chunk, dtype=np.int32)    # no tracer: closed over
 
     def scan_live(step, carry, offsets):
@@ -442,23 +458,41 @@ def _live_chunks(rows, n, chunk, out_shape):
         return jax.lax.scan(body, carry, starts)[0]
 
     @jax.custom_vjp
-    def run(args, order, offsets):
-        return scan_live(
-            lambda out, lo: out + rows(lo, args, order, offsets),
-            jnp.zeros(out_shape, jnp.float32), offsets)
+    def run(x2, gates, w, order, offsets):
+        def step(out, lo):
+            _, tok, xs, gs = _dispatch(x2, gates, order, lo, chunk, top_k)
+            y = _gated(gs, ffn(lo, xs, w, offsets))
+            with jax.named_scope("moe_combine"):
+                return out.at[tok].add(y)
 
-    def fwd(args, order, offsets):
-        return run(args, order, offsets), (args, order, offsets)
+        return scan_live(step, jnp.zeros(x2.shape, jnp.float32), offsets)
+
+    def fwd(x2, gates, w, order, offsets):
+        return run(x2, gates, w, order, offsets), (x2, gates, w, order,
+                                                   offsets)
 
     def bwd(res, g):
-        args, order, offsets = res
+        x2, gates, w, order, offsets = res
 
         def step(acc, lo):
-            pull = jax.vjp(lambda a: rows(lo, a, order, offsets), args)[1]
-            return jax.tree_util.tree_map(jnp.add, acc, pull(g)[0])
+            dx2, dgates, dw = acc
+            slot, tok, xs, gs = _dispatch(x2, gates, order, lo, chunk, top_k)
+            with jax.named_scope("moe_combine"):
+                g_rows = g[tok]
+            pull = jax.vjp(lambda xs, gs, w: _gated(gs, ffn(lo, xs, w,
+                                                            offsets)),
+                           xs, gs, w)[1]
+            dxs, dgs, dw_c = pull(g_rows)
+            with jax.named_scope("moe_dispatch"):
+                dx2 = dx2.at[tok].add(dxs)
+            with jax.named_scope("moe_combine"):
+                dgates = dgates.at[slot].add(dgs)
+            return dx2, dgates, jax.tree_util.tree_map(jnp.add, dw, dw_c)
 
-        zeros = jax.tree_util.tree_map(jnp.zeros_like, args)
-        return (scan_live(step, zeros, offsets), _float0(order),
+        zeros = (jnp.zeros_like(x2), jnp.zeros(gates.size, gates.dtype),
+                 jax.tree_util.tree_map(jnp.zeros_like, w))
+        dx2, dgates, dw = scan_live(step, zeros, offsets)
+        return (dx2, dgates.reshape(gates.shape), dw, _float0(order),
                 _float0(offsets))
 
     run.defvjp(fwd, bwd)
@@ -479,15 +513,14 @@ def _ragged_local(params, x2, choice, gates, activation, gmm_backend,
     ``T·k`` buffer, and what the absent experts would have added is left
     out of the result.  No token is dropped whatever the imbalance, but
     the work follows the rows that are held: the buffer goes through in
-    chunks of ``_CHUNK_ROWS`` sorted rows (:func:`_live_chunks`), a chunk
-    that starts past the last held row is skipped whole (gather, grouped
-    products, activation, scatter), and inside a chunk the grouped
+    chunks of :func:`routing_chunk_rows` sorted rows (:func:`_live_chunks`),
+    a chunk that starts past the last held row is skipped whole (gather,
+    grouped products, activation, scatter), and inside a chunk the grouped
     products cover the held rows alone.  Returns ``(out [T, h], load
     [E])``."""
     e_n = params["router"].shape[-1]
-    t_n, h = x2.shape
     top_k = choice.shape[-1]
-    n = t_n * top_k
+    n = x2.shape[0] * top_k
     first, g_n = held if held is not None else (0, e_n)
     with jax.named_scope("moe_dispatch"):
         fe = choice.reshape(-1)
@@ -499,35 +532,45 @@ def _ragged_local(params, x2, choice, gates, activation, gmm_backend,
         offsets = jnp.concatenate(
             [jnp.zeros(1, jnp.int32),
              jnp.cumsum(load[first:first + g_n], dtype=jnp.int32)])
-    chunked = (held is not None and n > _CHUNK_ROWS
-               and n % _CHUNK_ROWS == 0)
-    chunk = _CHUNK_ROWS if chunked else n
+    chunk = routing_chunk_rows(n, e_n, g_n)
 
-    def rows(lo, args, order, offsets):
-        """What sorted rows ``lo .. lo + chunk`` add to the output."""
-        x2, gates, w = args
-        slot = jax.lax.dynamic_slice(order, (lo,), (chunk,))
-        with jax.named_scope("moe_dispatch"):
-            tok = slot // top_k
-            xs = x2[tok]
+    def ffn(lo, xs, w, offsets):
+        """The grouped products of sorted rows ``lo .. lo + chunk``; a row
+        past the window is zero by their contract, whatever its gate."""
         with jax.named_scope("expert_ffn"):
-            h2 = _grouped_ffn(
+            return _grouped_ffn(
                 xs, jnp.clip(offsets - lo, 0, chunk), w["fc1"],
                 w.get("fc1_bias"), w["fc2"], w.get("fc2_bias"),
-                activation, x2.dtype, gmm_backend)
-        with jax.named_scope("moe_combine"):
-            # a row past the window is zero by the grouped products'
-            # contract, whatever its gate
-            return jnp.zeros((t_n, h), jnp.float32).at[tok].add(
-                gates.reshape(-1)[slot][:, None] * h2.astype(jnp.float32))
+                activation, xs.dtype, gmm_backend)
 
-    args = (x2, gates, {k: v for k, v in params.items()
-                        if k.startswith("fc")})
-    if chunked:
-        out = _live_chunks(rows, n, chunk, (t_n, h))(args, order, offsets)
+    w = {k: v for k, v in params.items() if k.startswith("fc")}
+    if chunk < n:
+        out = _live_chunks(ffn, n, chunk, top_k)(x2, gates, w, order,
+                                                 offsets)
     else:
-        out = rows(0, args, order, offsets)
+        _, tok, xs, gs = _dispatch(x2, gates, order, 0, n, top_k)
+        y = _gated(gs, ffn(0, xs, w, offsets))
+        with jax.named_scope("moe_combine"):
+            out = jnp.zeros(x2.shape, jnp.float32).at[tok].add(y)
     return out.astype(x2.dtype), load
+
+
+def routing_chunk_rows(n: int, e_n: int, g_n: int) -> int:
+    """Rows of the sorted routing buffer (``n = T·k`` assignments) that one
+    chunk of the held-experts path covers, from the static shape alone:
+    twice the rows that ``g_n`` held experts of ``e_n`` receive under
+    balanced routing (``n·g_n/e_n``; the factor covers the router's drift
+    towards the held experts), rounded up to the grouped kernels' row
+    block and then to a divisor of ``n``.  ``n`` itself, one chunk, where
+    every expert is held or no smaller such divisor exists.  Held rows
+    past the first chunk run in further chunks: nothing is dropped."""
+    if g_n >= e_n:
+        return n
+    block = _GMM_BLOCK_ROWS
+    for rows in range(block * -(-2 * n * g_n // (e_n * block)), n, block):
+        if n % rows == 0:
+            return rows
+    return n
 
 
 def _ep_abstract_mesh():

@@ -23,6 +23,7 @@ from apex_tpu.models import hybrid                              # noqa: E402
 from apex_tpu.models.config import joyai_llm_flash              # noqa: E402
 from apex_tpu.models.transformer_lm import (                    # noqa: E402
     gpt_loss, init_gpt_params, rope_cos_sin)
+from apex_tpu.transformer import moe                            # noqa: E402
 from benchmark.reference import joyai_llm_flash as ref          # noqa: E402
 from benchmark.reference import optim                           # noqa: E402
 from benchmark.reference import transformer as T                # noqa: E402
@@ -156,6 +157,38 @@ def test_program_matches_reference_float32(fused_head, toy):
     assert abs(float(counters["mtp_loss"]) - float(mtp)) < 1e-5
     # two expert layers and the module's: 3 x tokens x 4 assignments
     assert float(counters["moe_assignments"]) == 3 * B * S * 4
+
+
+def test_the_chunk_comes_from_the_cell_shape():
+    """The cell's buffer, 8192 x 8 = 65,536 rows of which 8 of 256 experts
+    get 2,048 when balanced, goes in sixteen chunks of 4,096."""
+    from test_lfm2_moe import _cell_shape
+
+    n, e_n, g_n = _cell_shape("joyai_llm_flash_ep32_train_b1s8192")
+    assert (n, e_n, g_n) == (65536, 256, 8)
+    assert moe.routing_chunk_rows(n, e_n, g_n) == 4096
+
+
+def test_program_in_chunks_of_64_matches_reference(toy, monkeypatch):
+    """Each expert layer's 320-row buffer walked in chunks of 64 (the held
+    experts get 80 rows a layer when balanced, so held rows pass the first
+    chunk): loss and every leaf's gradient as in one chunk, to 1e-5
+    relative; the routed rows are whole chunks that cover the held rows,
+    more than one a layer somewhere."""
+    monkeypatch.setattr(moe, "routing_chunk_rows", lambda n, e_n, g_n: 64)
+    cfg, params, batch, want, want_g = toy
+    pcfg = _program_cfg(cfg)
+    got, got_g = jax.value_and_grad(_program_loss(pcfg, batch))(params)
+    assert abs(float(got) - float(want)) / float(want) < 1e-5
+    for (name, g), (_, w) in zip(_leaves(got_g), _leaves(want_g)):
+        if not name.endswith("['router_bias']"):
+            assert _rel(g, w) < 1e-5, name
+    _, counters = gpt_loss(params, batch[0], batch[1], pcfg,
+                           mtp_labels=batch[2], with_counters=True)
+    held = float(counters["moe_assignments_held"])
+    routed = float(counters["moe_routed_rows"])
+    assert routed % 64 == 0 and held <= routed < held + 3 * 64
+    assert routed > 3 * 64
 
 
 def test_kernel_route_at_the_published_head_widths(monkeypatch):

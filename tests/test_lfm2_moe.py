@@ -5,6 +5,8 @@ the same pattern as the benchmark's cell (1 dense + 4 expert layers, one
 attention layer in the period), seeded random weights, float32 compute.
 """
 
+import json
+import math
 import os
 import sys
 
@@ -77,12 +79,20 @@ def _leaves(tree):
             jax.tree_util.tree_flatten_with_path(tree)[0]]
 
 
+def _chunk_of(monkeypatch, rows):
+    """Walk every sorted buffer in chunks of ``rows`` rows (the layer and
+    its counters both ask ``moe.routing_chunk_rows``)."""
+    monkeypatch.setattr(moe, "routing_chunk_rows", lambda n, e_n, g_n: rows)
+
+
 @pytest.fixture(params=[None, 64], ids=["one_chunk", "chunks_of_64"])
 def chunked(request, monkeypatch):
-    """The sorted buffer in one piece (``B·S·4`` = 256 rows are fewer than
-    a chunk) and in chunks of 64 rows, each under its ``lax.cond``."""
+    """The sorted buffer in one piece (the toy's ``B·S·4`` = 256 rows are
+    fewer than the chunk its shape gives) and in chunks of 64 rows, each
+    under its ``lax.cond``."""
     if request.param:
-        monkeypatch.setattr(moe, "_CHUNK_ROWS", request.param)
+        _chunk_of(monkeypatch, request.param)
+    return request.param
 
 
 def test_trees_match():
@@ -348,4 +358,128 @@ def test_counters_come_out_of_the_train_step(chunked):
     assert float(m["moe_held_load_max"]) >= float(m["moe_held_load_mean"])
     assert np.isclose(4 * float(m["moe_held_load_mean"]),
                       float(m["moe_assignments_held"]))
+    # the live chunks' rows: the whole buffer of each of the four layers
+    # in one chunk, else whole chunks of 64 that cover the held rows
+    routed, held = float(m["moe_routed_rows"]), float(
+        m["moe_assignments_held"])
+    if chunked is None:
+        assert routed == 4 * B * S * 4
+    else:
+        assert routed % chunked == 0 and held <= routed < held + 4 * chunked
     assert all(np.isfinite(losses)) and not bool(m["overflow"])
+
+
+def _cell_shape(name):
+    """``(n, E, G)`` of a benchmark cell's expert layers: the rows of the
+    routing buffer (``T·k``), the router's width and the experts held."""
+    def read(*parts):
+        with open(os.path.join(ROOT, "benchmark", *parts)) as f:
+            return json.load(f)
+
+    cell = read("workloads", name + ".json")
+    cfg = read("configs", cell["config"] + ".json")
+    held = cfg["deployment"]
+    return (cell["batch"] * cell["seq"] * cfg["num_experts_per_tok"],
+            held["num_experts_published"], held["experts_held"][1])
+
+
+def test_the_chunk_comes_from_the_shape():
+    """The cell's buffer, 2 x 8192 x 4 = 65,536 rows of which 8 of 64
+    experts get 8,192 when balanced, goes in chunks of twice that; every
+    expert held, or a buffer within the chunk, is one chunk; the chunk is a
+    multiple of 512 rows that divides the buffer, else the whole of it."""
+    n, e_n, g_n = _cell_shape("lfm2_24b_a2b_ep8_train_b2s8192")
+    assert (n, e_n, g_n) == (65536, 64, 8)
+    assert moe.routing_chunk_rows(n, e_n, g_n) == 16384
+    assert moe.routing_chunk_rows(n, e_n, e_n) == n
+    assert moe.routing_chunk_rows(B * S * 4, 64, 8) == B * S * 4
+    # 1,920 rows wanted: 2,048 does not divide 7,680, 2,560 does
+    assert moe.routing_chunk_rows(7680, 64, 8) == 2560
+    # 896 wanted, and no multiple of 512 below 3,584 divides it
+    assert moe.routing_chunk_rows(3584, 64, 8) == 3584
+
+
+def test_routed_rows_count_the_live_chunks(monkeypatch):
+    """One layer's ``moe_routed_rows`` is ``ceil(held / chunk) · chunk``
+    with the chunk its shape gives, the whole buffer where that is one
+    chunk."""
+    from apex_tpu.models.hybrid import moe_counters
+
+    pcfg = _program_cfg(_toy(experts=64, held=(8, 8)))
+    load = jnp.zeros(64, jnp.int32).at[8:16].set(
+        jnp.array([90, 0, 7, 30, 1, 0, 12, 5]))       # 145 held rows
+    tokens = 64                                        # 256 rows: one chunk
+    assert float(moe_counters(pcfg, load, tokens)["moe_routed_rows"]) == 256
+    _chunk_of(monkeypatch, 64)
+    assert float(moe_counters(pcfg, load, tokens)["moe_routed_rows"]) == 192
+    _chunk_of(monkeypatch, 32)
+    assert float(moe_counters(pcfg, load, tokens)["moe_routed_rows"]) == 160
+    assert float(moe_counters(pcfg, load * 0, tokens)["moe_routed_rows"]) == 0
+
+
+def _crowded(live):
+    """The toy expert layer with a bias of +10 on ``live - 1`` held
+    experts: each is among every token's four, so that the held rows of
+    the 256-row buffer pass ``live - 1`` chunks of 64."""
+    lp, x = _expert_layer(2)
+    lp["router_bias"] = lp["router_bias"].at[
+        jnp.array([3, 5][:live - 1])].set(10.0)
+    return lp, x
+
+
+def _share_and_grads(lp, x, backend):
+    w = jax.random.normal(jax.random.key(9), x.shape, jnp.float32)
+    out = _share(lp, x, 0, 8, backend)
+    grads = jax.grad(lambda lp, x: jnp.vdot(
+        _share(lp, x, 0, 8, backend).out, w), argnums=(0, 1))(lp, x)
+    return out, grads, w
+
+
+@pytest.mark.parametrize("backend", ["reference", "kernel"])
+@pytest.mark.parametrize("live", [2, 3])
+def test_held_rows_past_one_chunk_match_the_reference(backend, live,
+                                                      monkeypatch):
+    """Held rows in two and in three live chunks of 64: the output and the
+    gradients of the input, the router and the held experts are the
+    reference's for the same share; no row is dropped."""
+    _chunk_of(monkeypatch, 64)
+    lp, x = _crowded(live)
+    out, got, w = _share_and_grads(lp, x, backend)
+    assert math.ceil(float(jnp.sum(out.expert_load[:8])) / 64) == live
+    tokens = x.shape[0] * x.shape[1]
+    cfg = _toy(experts=64, held=(0, 8))
+
+    def want(lp, x):
+        held = {**lp, "moe_fc1": lp["moe_fc1"][:8],
+                "moe_fc2": lp["moe_fc2"][:8]}
+        return ref.expert_ffn(x.reshape(tokens, -1), held, cfg,
+                              F32).reshape(x.shape)
+
+    np.testing.assert_allclose(out.out, want(lp, x), rtol=1e-4, atol=1e-5)
+    exp = jax.grad(lambda lp, x: jnp.vdot(want(lp, x), w),
+                   argnums=(0, 1))(lp, x)
+    for name in ("router_kernel", "moe_fc1", "moe_fc2"):
+        assert _rel(got[0][name], exp[0][name]) < 1e-5, name
+    assert _rel(got[1], exp[1]) < 1e-5
+
+
+@pytest.mark.parametrize("backend", ["reference", "kernel"])
+def test_one_chunk_and_three_chunks_agree(backend, monkeypatch):
+    """The same held rows walked as one 256-row chunk and as three live
+    chunks of 64 (and one skipped) give the same output and the same
+    router gradients to the last bit at float32: every chunk scatter-adds
+    into the carried output in the buffer's order.  The gradients of the
+    input and of the held experts pass through the grouped products'
+    transposes, which the CPU compiles for 64 rows otherwise than for 256:
+    they agree to float32 rounding (measured 6e-8 and 7.2e-7 at most, on
+    entries of order 1)."""
+    lp, x = _crowded(3)
+    one = _share_and_grads(lp, x, backend)[:2]
+    _chunk_of(monkeypatch, 64)
+    many = _share_and_grads(lp, x, backend)[:2]
+    for (name, a), (_, b) in zip(_leaves(one), _leaves(many)):
+        if "router" in name or name.startswith("[0]"):
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        else:
+            np.testing.assert_allclose(a, b, rtol=0, atol=2e-6,
+                                       err_msg=name)

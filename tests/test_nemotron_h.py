@@ -6,6 +6,7 @@ CPU: the benchmark cell's pattern (``EMEMEM*``, 4 of 16 experts held),
 seeded random weights, float32 compute.
 """
 
+import math
 import os
 import sys
 
@@ -402,6 +403,52 @@ def test_every_share_adds_the_same_shared_expert():
                                atol=1e-5)
 
 
+def test_the_chunk_comes_from_the_cell_shape():
+    """The cell's buffer, 8192 x 6 = 49,152 rows of which 8 of 128 experts
+    get 3,072 when balanced, goes in eight chunks of 6,144."""
+    from test_lfm2_moe import _cell_shape
+
+    n, e_n, g_n = _cell_shape("nemotron_twotower_30b_a3b_ep16_train_b1s8192")
+    assert (n, e_n, g_n) == (49152, 128, 8)
+    assert moe.routing_chunk_rows(n, e_n, g_n) == 6144
+
+
+@pytest.mark.parametrize("chunk", [None, 80], ids=["one_chunk",
+                                                   "chunks_of_80"])
+def test_a_crowded_share_matches_the_reference(chunk, monkeypatch):
+    """A bias of +10 on held experts 1 and 4 puts both among every token's
+    six: the share's 186 held rows fill three chunks of 80 of the 480-row
+    buffer.  Walked in one chunk or in those, the layer (routed
+    part and shared expert) and the gradients of the input, the router,
+    the held experts and the shared expert are the reference's."""
+    if chunk:
+        monkeypatch.setattr(moe, "routing_chunk_rows",
+                            lambda n, e_n, g_n: chunk)
+    lp, x = _expert_layer(1)
+    lp["router_bias"] = lp["router_bias"].at[jnp.array([1, 4])].set(10.0)
+    cfg = _toy("E", experts=32, held=(0, 8))
+    w = jax.random.normal(jax.random.key(9), x.shape, jnp.float32)
+
+    def mine(lp, x):
+        return hybrid.expert_layer(_share_cfg(0, 8), _share_lp(lp, 0, 8),
+                                   x)
+
+    def want(lp, x):
+        return ref.expert_layer(x, _share_lp(lp, 0, 8), cfg, F32)
+
+    out, load = mine(lp, x)
+    assert math.ceil(float(jnp.sum(load[:8])) / 80) == 3
+    np.testing.assert_allclose(out, want(lp, x), rtol=1e-4, atol=1e-5)
+    got = jax.grad(lambda lp, x: jnp.vdot(mine(lp, x)[0], w),
+                   argnums=(0, 1))(lp, x)
+    exp = jax.grad(lambda lp, x: jnp.vdot(want(lp, x), w),
+                   argnums=(0, 1))(lp, x)
+    for name in ("router_kernel", "moe_fc1", "moe_fc2",
+                 "shared_fc1_kernel", "shared_fc2_kernel"):
+        assert _rel(got[0][name], exp[0][name]) < 1e-5, name
+    assert _rel(got[1], exp[1]) < 1e-5
+
+
 def test_counters_come_out_of_the_train_step():
     """The O2 step's own outputs carry the expert layers' counters, summed
     over the three expert layers; the losses are finite."""
@@ -423,6 +470,8 @@ def test_counters_come_out_of_the_train_step():
     assert 0 < float(m["moe_assignments_held"]) < float(m["moe_assignments"])
     assert np.isclose(4 * float(m["moe_held_load_mean"]),
                       float(m["moe_assignments_held"]))
+    # each layer's 480-row buffer is one chunk at the toy's shape
+    assert float(m["moe_routed_rows"]) == 3 * B * S * 6
     assert all(np.isfinite(losses)) and not bool(m["overflow"])
 
 
